@@ -36,13 +36,9 @@ parser.add_argument("--quick", action="store_true",
 args = parser.parse_args()
 
 if args.cpu:
-    from bench import force_cpu
-    force_cpu()
     import jax
-    try:
-        jax.config.update("jax_num_cpu_devices", 8)
-    except Exception:
-        pass
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", 8)
 
 import numpy as np
 

@@ -15,18 +15,11 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-if "--cpu" in sys.argv:
+if "--cpu" in sys.argv:  # 8-device virtual CPU mesh
     sys.argv.remove("--cpu")
-    import os
-    import sys as _sys
-    _sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import force_cpu
-    force_cpu()
-    try:
-        jax.config.update("jax_num_cpu_devices", 8)
-    except Exception:
-        pass
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", 8)
 
 import numpy as np
 

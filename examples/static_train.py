@@ -10,14 +10,10 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))  # repo checkout; unnecessary if installed
 
-if "--cpu" in sys.argv:
+if "--cpu" in sys.argv:  # run on the CPU backend (e.g. no chip attached)
     sys.argv.remove("--cpu")
-    import os
-    import sys as _sys
-    _sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import force_cpu
-    force_cpu()
+    import jax
+    jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 

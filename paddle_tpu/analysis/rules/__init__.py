@@ -16,7 +16,6 @@ from .jit_capture import JitConstantCapture
 from .kvtier_access import KvtierBlessedAccess
 from .pallas import PallasHazards
 from .serving_lock import EngineLockDiscipline, PageMigrationLock
-from .subprocess_chip import ChipKillOnTimeout
 from .weight_swap import WeightSwapLock
 
 ALL_RULES = [
@@ -25,7 +24,6 @@ ALL_RULES = [
     PallasHazards(),
     JitConstantCapture(),
     DistSpecPassthrough(),
-    ChipKillOnTimeout(),
     EngineLockDiscipline(),
     PageMigrationLock(),
     EnvKnobRegistry(),
@@ -39,7 +37,7 @@ RULES_BY_ID = {r.id: r for r in ALL_RULES}
 
 __all__ = ["ALL_RULES", "RULES_BY_ID", "AutogradBypass",
            "ThreadGradState", "PallasHazards", "JitConstantCapture",
-           "DistSpecPassthrough", "ChipKillOnTimeout",
+           "DistSpecPassthrough",
            "EngineLockDiscipline", "PageMigrationLock",
            "EnvKnobRegistry", "ServingRawSleep", "FleetProcessSpawn",
            "KvtierBlessedAccess", "WeightSwapLock"]

@@ -25,3 +25,24 @@ def current_axis_env() -> set:
     for names in _axis_stack:
         out.update(names)
     return out
+
+
+# The mesh a GSPMD stepper is tracing its step for. A `pallas_call` has
+# no partitioning rule, so code that dispatches a kernel (ops/pallas/
+# flash_attention.py) consults this to run the kernel per shard inside
+# `jax.shard_map` instead of handing Mosaic a sharded operand.
+_mesh_stack: list = []
+
+
+@contextlib.contextmanager
+def mesh_env(mesh):
+    _mesh_stack.append(mesh)
+    try:
+        yield
+    finally:
+        _mesh_stack.pop()
+
+
+def current_mesh_env():
+    """The innermost stepper mesh, or None outside a mesh-traced step."""
+    return _mesh_stack[-1] if _mesh_stack else None

@@ -66,7 +66,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ...core import random as _random
 from ...core.tensor import Tensor
 from ...nn.layer import Layer, LayerList
-from .._axis import axis_env
+from .._axis import axis_env, mesh_env
 
 
 class LayerDesc:
@@ -472,30 +472,33 @@ def _pipeline_train_step(pp: PipelineParallel, opt, inputs: Tensor,
                              tuple(labels._data.shape[1:])), ns(mb_spec))
 
     put = lambda sh: (lambda x: _dpg(x, sh))
-    (loss_v, new_pre, new_post, new_blk, new_pre_st, new_post_st,
-     new_blk_st) = fn(
-        _dpg(key, rep),
-        [put(sh)(p._data) for sh, (_, p) in zip(pre_sh, pre_named)],
-        [put(sh)(p._data) for sh, (_, p) in zip(post_sh, post_named)],
-        [put(sh)(a) for sh, a in zip(blk_sh, blk_stacked)],
-        # states follow their param's spec (pp/sharding/TP dims) so
-        # ZeRO-sharded embed/head moments never materialize whole
-        [jax.tree.map(
-            lambda leaf, sp=sh.spec: _dpg(
-                leaf, ns(_prepost_state_spec(sp, leaf.shape))), st)
-         for sh, st in zip(pre_sh, pre_states)],
-        [jax.tree.map(
-            lambda leaf, sp=sh.spec: _dpg(
-                leaf, ns(_prepost_state_spec(sp, leaf.shape))), st)
-         for sh, st in zip(post_sh, post_states)],
-        [jax.tree.map(
-            lambda leaf, sp=sh.spec: _dpg(
-                leaf, ns(_pp_state_spec(sp, leaf.shape, zstage,
-                                        sharding_degree))), st)
-         for sh, st in zip(blk_sh, blk_state_list)],
-        _dpg(jnp.asarray(opt.get_lr(), jnp.float32), rep),
-        _dpg(jnp.asarray(opt._step_count, jnp.int32), rep),
-        micro_in, micro_lab)
+    # the first call traces the step: kernels dispatched inside it must
+    # know the mesh (pallas_call has no partitioning rule)
+    with mesh_env(mesh):
+        (loss_v, new_pre, new_post, new_blk, new_pre_st, new_post_st,
+         new_blk_st) = fn(
+            _dpg(key, rep),
+            [put(sh)(p._data) for sh, (_, p) in zip(pre_sh, pre_named)],
+            [put(sh)(p._data) for sh, (_, p) in zip(post_sh, post_named)],
+            [put(sh)(a) for sh, a in zip(blk_sh, blk_stacked)],
+            # states follow their param's spec (pp/sharding/TP dims) so
+            # ZeRO-sharded embed/head moments never materialize whole
+            [jax.tree.map(
+                lambda leaf, sp=sh.spec: _dpg(
+                    leaf, ns(_prepost_state_spec(sp, leaf.shape))), st)
+             for sh, st in zip(pre_sh, pre_states)],
+            [jax.tree.map(
+                lambda leaf, sp=sh.spec: _dpg(
+                    leaf, ns(_prepost_state_spec(sp, leaf.shape))), st)
+             for sh, st in zip(post_sh, post_states)],
+            [jax.tree.map(
+                lambda leaf, sp=sh.spec: _dpg(
+                    leaf, ns(_pp_state_spec(sp, leaf.shape, zstage,
+                                            sharding_degree))), st)
+             for sh, st in zip(blk_sh, blk_state_list)],
+            _dpg(jnp.asarray(opt.get_lr(), jnp.float32), rep),
+            _dpg(jnp.asarray(opt._step_count, jnp.int32), rep),
+            micro_in, micro_lab)
 
     for (n, p), arr in zip(pre_named, new_pre):
         p._inplace_update(arr)

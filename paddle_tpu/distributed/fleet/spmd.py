@@ -37,6 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ...core import random as _random
 from ...core.tensor import Tensor
 from ...nn.layer import Layer
+from .._axis import mesh_env
 
 
 def _add_sharding(spec, shape, sharding_degree, axis="sharding"):
@@ -512,16 +513,19 @@ class SPMDTrainer:
         batch_arrays = [
             device_put_global(t._data, _batch_sharding(t._data.ndim))
             for t in inputs + labels]
-        out = fn(
-            key,
-            [p._data for _, p in self._train_named],
-            [p._data for _, p in self._frozen_named],
-            [b._data for _, b in self._buf_named],
-            states,
-            gacc,
-            lr,
-            step_i,
-            *batch_arrays)
+        # the first call traces the step: kernels dispatched inside it
+        # must know the mesh (pallas_call has no partitioning rule)
+        with mesh_env(self.mesh):
+            out = fn(
+                key,
+                [p._data for _, p in self._train_named],
+                [p._data for _, p in self._frozen_named],
+                [b._data for _, b in self._buf_named],
+                states,
+                gacc,
+                lr,
+                step_i,
+                *batch_arrays)
         if not do_update:
             loss_v, new_buf, new_gacc = out
             self._gacc = list(new_gacc)

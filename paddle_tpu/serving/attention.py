@@ -14,12 +14,13 @@ Two paths, selected by ``PADDLE_TPU_PAGED_KERNEL``:
   packed query TOKENS, each grid cell resolving its own lane's
   (page_table row, context_len, absolute position), so decode lanes
   (q=1), prefill chunks, and speculative-verify bursts (q=k+1) all run
-  through the same program. Validated in INTERPRET MODE ONLY this round
-  (CLAUDE.md: no first-time Mosaic compiles while the chip grant is
-  wedged). It streams pages with an online-softmax accumulator — the
-  structure the real kernel needs — but reads the whole page pool per
-  grid cell, which a Mosaic build must replace with per-page DMA to
-  respect the O(block) VMEM invariant before it can be compile-gated.
+  through the same program. INTERPRET MODE, CPU ONLY: the knob raises on
+  any other backend. The chip's compiler refuses the kernel as written
+  ("the last two dimensions of your block shape are divisible by 8 and
+  128" — the (1, P) page-table block; tests/test_aot_tpu_compile.py
+  keeps the refusal as a strict xfail) and it reads the whole page pool
+  per grid cell, which a Mosaic build must replace with per-page DMA to
+  respect the O(block) VMEM invariant (ROADMAP S4).
 
 :func:`ragged_paged_attention` is the token-packed entry point
 (PAPERS.md "Ragged Paged Attention"): ``q [T, H, D]`` carries the
@@ -69,6 +70,22 @@ def quantize_q8(x):
     return codes, s
 
 
+def _kernel_requested() -> bool:
+    """True when ``PADDLE_TPU_PAGED_KERNEL=1`` selects the interpret-mode
+    kernel. It exists to test the kernel's structure on the CPU; on a
+    chip it would quietly run interpreted, so there the knob raises."""
+    if os.environ.get("PADDLE_TPU_PAGED_KERNEL") != "1":
+        return False
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            "PADDLE_TPU_PAGED_KERNEL=1 selects an interpret-mode Pallas "
+            f"kernel that only runs on the cpu backend (this is "
+            f"{backend!r}); unset it — the gather path is the "
+            "accelerator path until the kernel compiles under Mosaic")
+    return True
+
+
 def paged_attention(q, k_pages, v_pages, page_table, context_lens,
                     q_offsets, *, scale, window=None, spmd=False):
     """q [B,S,H,D]; k_pages/v_pages [NP, page_size, KV, D];
@@ -81,7 +98,7 @@ def paged_attention(q, k_pages, v_pages, page_table, context_lens,
     partitioning rule, so tracing the kernel into a mesh program
     would be silent wrongness; the engine logs + counts the fallback.
     """
-    if not spmd and os.environ.get("PADDLE_TPU_PAGED_KERNEL") == "1":
+    if not spmd and _kernel_requested():
         # rectangular [B, S] is the degenerate ragged batch: row b is a
         # lane of query_len S — expand per token and run the ONE kernel
         b, s, nh, d = q.shape
@@ -141,7 +158,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table,
     lane, pos = _token_lanes(query_lens, q_offsets, t)
     pt_tok = page_table[lane]
     cl_tok = context_lens[lane].astype(jnp.int32)
-    if not spmd and os.environ.get("PADDLE_TPU_PAGED_KERNEL") == "1":
+    if not spmd and _kernel_requested():
         return _ragged_attention_kernel(q, k_pages, v_pages, pt_tok,
                                         cl_tok, pos, scale=scale,
                                         window=window)

@@ -240,6 +240,11 @@ class ServingEngine:
             tp_degree=self.tp_degree)
         self.max_pages_per_seq = math.ceil(
             self.max_seq_len / self.cache.page_size)
+        # where the pools actually live (advertised in /healthz): a
+        # process-backed fleet worker defaults to cpu, and a router
+        # must be able to see that without touching jax itself
+        self.platform = next(iter(
+            self.cache.k_pages[0].devices())).platform
         # -- speculative decoding (round 12) -------------------------------
         self.draft = draft_model
         if draft_model is not None:
@@ -1429,8 +1434,8 @@ class ServingEngine:
         lanes (q=1), speculative-verify lanes (q=k+1), and the prefill
         chunk ride a single compiled program over the
         ``ragged_paged_attention`` lane layout — one dispatch + one
-        host fetch per step, the relay fixed-cost win (FEASIBILITY.md:
-        per-dispatch overhead ~0.79 of a small step). Per-token
+        host fetch per step (FEASIBILITY.md: per-dispatch overhead
+        ~0.79 of a small CPU step; not measured on a chip). Per-token
         counter-RNG keys are IDENTICAL to the bucketed path's
         ((seed, token-index) is schedule-independent), so streams are
         token-exact vs it even though preemption ORDER may differ —

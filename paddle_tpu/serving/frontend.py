@@ -293,6 +293,7 @@ class ServingFrontend:
             return {"status": self._state,
                     "role": self.role,
                     "pid": os.getpid(),
+                    "platform": getattr(eng, "platform", None),
                     "started_unix": self.started_unix,
                     "waiting": eng.scheduler.queue_depth(),
                     "live": len(eng.scheduler.live_requests()),

@@ -1,7 +1,7 @@
 """graftlint (paddle_tpu.analysis, ISSUE 6): every rule gets a
 bad/good fixture pair — the bad snippet reproduces the ORIGINAL bug
 shape the rule encodes (round-11 grad-mode interleaving, verbatim
-dist_spec return, incident-#3 timeout kill, ...) — plus suppression/
+dist_spec return, ...) — plus suppression/
 baseline mechanics, the env-knob registry sync check, and a whole-tree
 self-check asserting the repo is clean modulo the checked-in baseline
 (the same invariant tools/lint.sh gates ahead of tier-1 pytest).
@@ -38,14 +38,14 @@ def rule_ids(findings):
 # rule registry sanity
 
 class TestRegistry:
-    def test_thirteen_rules_with_ids_and_docs(self):
-        assert len(ALL_RULES) == 13
+    def test_twelve_rules_with_ids_and_docs(self):
+        assert len(ALL_RULES) == 12
         for r in ALL_RULES:
             assert r.id and r.description
         assert set(RULES_BY_ID) == {
             "autograd-bypass", "thread-grad-state", "pallas-hazards",
             "jit-constant-capture", "dist-spec-passthrough",
-            "chip-kill-on-timeout", "engine-lock-discipline",
+            "engine-lock-discipline",
             "page-migration-lock", "env-knob-registry",
             "serving-raw-sleep", "fleet-process-spawn",
             "kvtier-blessed-access", "weight-swap-lock"}
@@ -436,71 +436,6 @@ class TestDistSpecPassthrough:
     def test_composed_spec_passes(self):
         assert lint(_DIST_GOOD, "paddle_tpu/distributed/foo.py",
                     "dist-spec-passthrough") == []
-
-
-# ---------------------------------------------------------------------------
-# 6. chip-kill-on-timeout — the incident-#3 shape must flag
-
-_CHIP_BAD = '''
-    """Drives on-chip TPU snippets from subprocesses."""
-    import subprocess
-
-    def run_snippet(code):
-        return subprocess.run(["python", "-c", code], timeout=600)
-'''
-
-_CHIP_KILL_BAD = '''
-    """Chip smoke harness."""
-    import subprocess
-
-    def run_snippet(p):
-        p.kill()
-'''
-
-_CHIP_GOOD = '''
-    """Drives on-chip TPU snippets from subprocesses."""
-    import subprocess
-
-    def run_snippet(code):
-        p = subprocess.Popen(["python", "-c", code])
-        try:
-            out, err = p.communicate(timeout=600)
-        except subprocess.TimeoutExpired:
-            p.terminate()   # SIGTERM with grace, never SIGKILL
-        return p
-'''
-
-
-class TestChipKillOnTimeout:
-    def test_incident3_run_timeout_flags(self):
-        fs = lint(_CHIP_BAD, "tools/chip_thing.py",
-                  "chip-kill-on-timeout")
-        assert len(fs) == 1 and "incident #3" in fs[0].message
-
-    def test_sigkill_flags(self):
-        fs = lint(_CHIP_KILL_BAD, "tools/chip_thing.py",
-                  "chip-kill-on-timeout")
-        assert len(fs) == 1 and "SIGKILL" in fs[0].message
-
-    def test_sigterm_grace_pattern_passes(self):
-        assert lint(_CHIP_GOOD, "tools/chip_thing.py",
-                    "chip-kill-on-timeout") == []
-
-    def test_probe_functions_exempt(self):
-        src = _CHIP_BAD.replace("def run_snippet", "def probe_chip")
-        assert lint(src, "tools/chip_thing.py",
-                    "chip-kill-on-timeout") == []
-
-    def test_non_chip_file_out_of_scope(self):
-        src = '''
-            """Runs documentation helpers."""
-            import subprocess
-
-            def run_helper(code):
-                return subprocess.run(["python", "-c", code], timeout=9)
-        '''
-        assert lint(src, "tools/docs_helper.py",
-                    "chip-kill-on-timeout") == []
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,208 @@
+"""The chip's compiler, without the chip: the kernels and attention of
+the two hot paths compile for a DESCRIBED TPU v5e (2x2) at the shapes
+chip_smoke.py runs — what Mosaic or XLA:TPU refuses here costs no chip
+time (on-chip-measurement guide §2.3). Nothing executes; a compile that
+passes is not a chip run.
+
+Code that asks ``jax.default_backend()`` still sees the CPU here, so the
+cases call the kernels directly, or steer the dispatch from the test
+(``_on_tpu`` patched) — never through an option of the program.
+"""
+import os
+import sys
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs to /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import (Mesh, NamedSharding,  # noqa: E402
+                          PartitionSpec as P, SingleDeviceSharding)
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+import chip_smoke  # noqa: E402  (the shapes under test are the smoke's)
+
+from paddle_tpu.ops.pallas import flash_attention as fa  # noqa: E402
+from paddle_tpu.ops.pallas._fa_kernel import (fa_backward,  # noqa: E402
+                                              fa_forward)
+
+SZ = chip_smoke.REAL
+B, S, H, D = SZ.train_batch, SZ.train_seq, SZ.heads, SZ.hidden // SZ.heads
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e:2x2, with the persistent compile cache off around
+    the module: an entry compiled for a described device cannot be read
+    back and would warn on the next run."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or it cannot describe a v5e
+        pytest.skip(f"TPU topology cannot be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def one(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *avals):
+    return jax.jit(fn).lower(*avals).compile()
+
+
+def _kernels(compiled):
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def _fwd_bwd(q, k, v, g):
+    out, lse = fa_forward(q, k, v, causal=True, return_lse=True)
+    return fa_backward(q, k, v, out, lse, g, causal=True)
+
+
+class TestFlashAttentionCompiles:
+    def test_device_is_the_v5e_jax_reports(self, topo):
+        from paddle_tpu.utils.chip_specs import chip_spec
+        assert len(topo.devices) == 4
+        assert chip_spec(topo.devices[0].device_kind).bf16_flops == 197e12
+
+    def test_forward_at_train_shape(self, one):
+        q = _sds((B, S, H, D), BF16, one)
+        c = _compile(lambda q, k, v: fa_forward(
+            q, k, v, causal=True, return_lse=True), q, q, q)
+        assert _kernels(c) == 1
+
+    def test_forward_backward_at_train_shape(self, one):
+        q = _sds((B, S, H, D), BF16, one)
+        assert _kernels(_compile(_fwd_bwd, q, q, q, q)) == 3
+
+    def test_forward_backward_gqa(self, one):
+        q = _sds((B, 2048, H, D), BF16, one)
+        kv = _sds((B, 2048, H // 4, D), BF16, one)
+        assert _kernels(_compile(_fwd_bwd, q, kv, kv, q)) == 3
+
+    def test_forward_packed_segments(self, one):
+        q = _sds((B, S, H, D), BF16, one)
+        seg = _sds((B, S), jnp.int32, one)
+        c = _compile(lambda q, k, v, s: fa_forward(
+            q, k, v, causal=True, q_seg=s, kv_seg=s), q, q, q, seg)
+        assert _kernels(c) == 1
+
+    def test_forward_cross_length(self, one):
+        q = _sds((B, 128, H, D), BF16, one)
+        kv = _sds((B, 2048, H // 4, D), BF16, one)
+        c = _compile(lambda q, k, v: fa_forward(q, k, v, causal=True),
+                     q, kv, kv)
+        assert _kernels(c) == 1
+
+    def test_forward_masked_stream(self, one):
+        q = _sds((B, S, H, D), BF16, one)
+        m = _sds((B, 1, S, S), jnp.float32, one)
+        c = _compile(lambda q, k, v, m: fa_forward(q, k, v, mask=m),
+                     q, q, q, m)
+        assert _kernels(c) == 1
+
+
+class TestShardedFlashAttention:
+    """The fleet stepper's layout on the 2x2 mesh (sharding 2 x mp 2):
+    batch over the data axes, heads over mp."""
+
+    @pytest.fixture
+    def sharded(self, topo, monkeypatch):
+        monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+        mesh = Mesh(np.array(topo.devices).reshape(1, 1, 2, 1, 2),
+                    ("dp", "pp", "sharding", "sep", "mp"))
+        q = _sds((SZ.fleet_batch, S, H, D), BF16, NamedSharding(
+            mesh, P(("dp", "sharding"), None, "mp", None)))
+
+        def grads(q, k, v):
+            return jax.grad(lambda *a: fa._flash_core_ext(
+                *a, None, None, None, True, None).astype(
+                    jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+        return mesh, q, grads
+
+    def test_kernel_runs_per_shard_under_the_stepper_mesh(self, sharded):
+        from paddle_tpu.distributed._axis import mesh_env
+        mesh, q, grads = sharded
+        fa.reset_dispatch_stats()
+        with mesh_env(mesh):
+            c = _compile(grads, q, q, q)
+        assert _kernels(c) == 3
+        assert fa.dispatch_stats() == {"pallas": 1, "fallback": 0}
+        # each chip works on its own shard: no collective is needed
+        assert "all-gather" not in c.as_text()
+
+    def test_unwrapped_sharded_operands_are_refused(self, sharded):
+        """Why the dispatch wraps: without the stepper's mesh_env the
+        same call hands Mosaic a sharded operand."""
+        _, q, grads = sharded
+        with pytest.raises(NotImplementedError,
+                           match="cannot be automatically partitioned"):
+            _compile(grads, q, q, q)
+
+
+class TestServingAttention:
+    def _operands(self, sharding):
+        t = SZ.max_batch + SZ.prefill_chunk          # the mixed capacity
+        lanes = SZ.max_batch + 1
+        pages = -(-SZ.max_seq_len // SZ.page_size)
+        pool = _sds((SZ.num_pages, SZ.page_size, H, D), BF16, sharding)
+        i32 = lambda *s: _sds(s, jnp.int32, sharding)  # noqa: E731
+        return t, lanes, pages, pool, i32
+
+    def test_ragged_gather_path_at_pool_geometry(self, one):
+        from paddle_tpu.serving.attention import ragged_paged_attention
+        t, lanes, pages, pool, i32 = self._operands(one)
+        c = _compile(
+            lambda q, kp, vp, pt, cl, ql, qo: ragged_paged_attention(
+                q, kp, vp, pt, cl, ql, qo, scale=D ** -0.5),
+            _sds((t, H, D), BF16, one), pool, pool, i32(lanes, pages),
+            i32(lanes), i32(lanes), i32(lanes))
+        assert _kernels(c) == 0      # a gather, not a kernel (ROADMAP S4)
+
+    @pytest.mark.xfail(strict=True, raises=ValueError,
+                       reason="Mosaic refuses the ragged paged kernel as "
+                              "written: (1, P) page-table block not "
+                              "divisible by (8, 128); S4 flips this")
+    def test_ragged_pallas_kernel_compiles_under_mosaic(self, one,
+                                                        monkeypatch):
+        from jax.experimental import pallas as pl
+
+        from paddle_tpu.serving.attention import _ragged_attention_kernel
+        real = pl.pallas_call
+        monkeypatch.setattr(
+            pl, "pallas_call",
+            lambda *a, **kw: real(*a, **{**kw, "interpret": False}))
+        t, lanes, pages, pool, i32 = self._operands(one)
+        _compile(
+            lambda q, kp, vp, pt, cl, pos: _ragged_attention_kernel(
+                q, kp, vp, pt, cl, pos, scale=D ** -0.5),
+            _sds((t, H, D), BF16, one), pool, pool, i32(t, pages), i32(t),
+            i32(t))
+
+
+def test_paged_kernel_knob_raises_off_cpu(monkeypatch):
+    """PADDLE_TPU_PAGED_KERNEL=1 would run an interpreted kernel on a
+    chip: anywhere but the cpu backend it raises."""
+    from paddle_tpu.serving import attention
+    monkeypatch.setenv("PADDLE_TPU_PAGED_KERNEL", "1")
+    assert attention._kernel_requested() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="only runs on the cpu"):
+        attention._kernel_requested()

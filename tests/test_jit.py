@@ -121,8 +121,8 @@ class TestJitSaveLoad:
 
 class TestNativeArtifact:
     """jit.save emits the C++-loadable triple (.mlir/.pdpjrt.txt/.pdparams.bin)
-    consumed by native/pjrt_loader.cpp (execution itself is covered on-chip
-    in test_tpu_chip.py)."""
+    consumed by native/pjrt_loader.cpp (executing it needs a PJRT plugin
+    and a device; not covered here)."""
 
     def test_native_artifact_files(self, tmp_path):
         import json

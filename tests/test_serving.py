@@ -828,8 +828,8 @@ class TestServingSweep:
 class TestServingReplay:
     def test_bench_serving_smoke_subprocess(self):
         """End-to-end Poisson replay through the repo-root driver
-        (slow: excluded from tier-1; chip_capture runs it via
-        tools/serving_smoke.sh)."""
+        (slow: excluded from tier-1; tools/serving_smoke.sh runs
+        it)."""
         import json
         import subprocess
         import sys

@@ -496,7 +496,7 @@ class TestValidation:
 
 
 # ---------------------------------------------------------------------------
-# long replay over sockets (slow tier; chip_capture runs the smoke)
+# long replay over sockets (slow tier; tools/serving_server_smoke.sh)
 
 
 @pytest.mark.slow

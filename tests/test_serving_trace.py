@@ -1,5 +1,5 @@
 """paddle_tpu.serving.trace — serving-wide request tracing + the
-engine flight recorder (ISSUE 9): span taxonomy and caps, coalesced
+engine flight recorder (ISSUE 9): span catalogue and caps, coalesced
 decode runs, finish-log phase breakdown, flight-recorder dump on loop
 failure (with the failing step's batch composition), /debug/trace +
 /debug/flight over HTTP, router-merged cross-replica stitching, and
@@ -148,7 +148,7 @@ class TestTraceUnits:
 
 
 # ---------------------------------------------------------------------------
-# engine level: span taxonomy, phases, caps
+# engine level: span catalogue, phases, caps
 
 
 class TestEngineSpans:

@@ -8,9 +8,7 @@
 # tests/test_serving_chaos.py.
 #
 # CPU-only by construction (the fuzz driver forces jax_platforms=cpu
-# itself), so the timeout guard is safe — no chip work to wedge
-# (CLAUDE.md chip hygiene: kill-on-timeout is only forbidden for chip
-# subprocesses).
+# itself).
 set -o pipefail
 cd "$(dirname "$0")/.."
 timeout -k 10 300 python tools/chaos_fuzz.py --smoke

@@ -10,8 +10,8 @@
 # pushed through the same deployer and the measured acceptance rate
 # must improve while emitted tokens stay bit-identical.
 #
-# CPU-only by construction (the harness forces jax_platforms=cpu), so
-# the timeout guard is safe — no chip work to wedge.  Never banks:
+# CPU-only by construction (the harness forces jax_platforms=cpu).
+# Never banks:
 # BENCH_serving_deploy.json is written only by full (non-smoke) runs
 # on a quiet VM.
 set -o pipefail

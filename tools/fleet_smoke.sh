@@ -9,8 +9,8 @@
 # TTFT p99, shed rate, page conservation, and ZERO leaked processes.
 #
 # CPU-only by construction (the harness forces jax_platforms=cpu and
-# workers force it in their own interpreters), so the timeout guard is
-# safe — no chip work to wedge.  If the timeout ever fires, the
+# workers force it in their own interpreters).  If the timeout ever
+# fires, the
 # workers' parent-death watchdog self-reaps them within seconds, so
 # even the hard-kill path leaves no orphans (round-4 addenda).
 set -o pipefail

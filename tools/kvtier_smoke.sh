@@ -8,9 +8,9 @@
 # classes (spill→restore bit-exactness per cache_dtype, best-effort
 # degradation under every tier fault point, cross-tier conservation).
 #
-# CPU-only by construction (bench smoke mode never probes the chip;
-# the tests run on the suite's virtual CPU mesh), so the timeout guard
-# is safe — no chip work to wedge.  The conftest BENCH snapshot guard
+# CPU-only by construction (bench smoke mode selects the CPU mesh; the
+# tests run on the suite's virtual CPU mesh).  The conftest BENCH
+# snapshot guard
 # is a pytest fixture and does not cover this entry point, so the
 # script snapshots BENCH_serving_kvtier.json itself and restores it on
 # exit — re-banking stays a deliberate quiet-VM act (round-12

@@ -5,10 +5,9 @@
 # two-point marginal), token-exactness asserted across the two, and
 # the ragged engine's compiled step-program-class count asserted <= 2.
 #
-# CPU-only by construction (`--smoke` skips the device probe and
-# forces the CPU mesh; the unified ragged Pallas kernel stays behind
-# PADDLE_TPU_PAGED_KERNEL and is interpret-mode only), so the timeout
-# guard is safe — no chip work to wedge.  Never banks:
+# CPU-only by construction (`--smoke` selects the CPU mesh; the
+# unified ragged Pallas kernel stays behind PADDLE_TPU_PAGED_KERNEL and
+# is interpret-mode, CPU only).  Never banks:
 # BENCH_serving_ragged.json is written only by full (non-smoke) runs
 # on a quiet VM.
 set -o pipefail

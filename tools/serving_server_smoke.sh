@@ -5,8 +5,7 @@
 # streaming, thread-per-request load generator — and banks the JSON
 # artifact.
 #
-# Wedge-proofing (CLAUDE.md chip hygiene): --smoke forces the CPU mesh
-# (no device probe at all), the paged-attention Pallas stub stays
+# --smoke selects the CPU mesh, the paged-attention Pallas stub stays
 # interpret-gated (PADDLE_TPU_PAGED_KERNEL unset), and every socket has
 # a timeout, so this script is bounded and never touches the chip.
 #

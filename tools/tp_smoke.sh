@@ -6,10 +6,9 @@
 # the degrees — the by-construction contract (only non-contracting
 # dims shard; collectives are pure data movement) checked end to end.
 #
-# CPU-only by construction (`--tp` forces the CPU mesh via
-# --xla_force_host_platform_device_count=8 and skips the device
-# probe; pallas_call has no GSPMD rule so the SPMD step pins the jnp
-# gather path), so the timeout guard is safe — no chip work to wedge.
+# CPU-only by construction (`--tp` selects the CPU mesh via
+# --xla_force_host_platform_device_count=8; pallas_call has no GSPMD
+# rule so the SPMD step pins the jnp gather path).
 # Never banks: BENCH_serving_tp.json is written only by full
 # (non-smoke) runs on a quiet VM.
 set -o pipefail
