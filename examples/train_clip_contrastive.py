@@ -93,9 +93,12 @@ def train_mesh_global(steps=8):
                                 group=g)
         return jax.lax.pmean(loss._data.reshape(()), "dp")[None]
 
-    fm = jax.shard_map(body, mesh=mesh,
-                       in_specs=(Pspec("dp"), Pspec("dp"), Pspec(None)),
-                       out_specs=Pspec("dp"))
+    # jitted: a bare shard_map runs its body op by op, and compiles every
+    # one of those ops again on every call
+    fm = jax.jit(jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(Pspec("dp"), Pspec("dp"), Pspec(None)),
+        out_specs=Pspec("dp")))
 
     def global_loss(img_f, txt_f, scale):
         with axis_env("dp"):

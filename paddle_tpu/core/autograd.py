@@ -188,8 +188,12 @@ def _mj_bwd(fn, args, multi, cots):
 def _is_stable(fn) -> bool:
     if getattr(fn, "_pt_stable", False):
         return True
-    return getattr(fn, "__closure__", None) is None and \
-        getattr(fn, "__name__", "<lambda>") != "<lambda>"
+    if getattr(fn, "__closure__", None) is not None:
+        return False
+    # a def nested in a function is a new object on every call of the
+    # outer one, whether or not it closes over anything
+    qualname = getattr(fn, "__qualname__", "<lambda>")
+    return "<lambda>" not in qualname and "<locals>" not in qualname
 
 
 def mark_stable(fn):

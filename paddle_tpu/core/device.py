@@ -124,6 +124,19 @@ def get_jax_device():
     return get_place().jax_device
 
 
+def committed(arr):
+    """`arr`, committed to the device(s) it is on. What jax.random and
+    jnp.zeros return is uncommitted; what a compiled step returns is
+    committed (its batch, from to_tensor, is). State fed back from step 0
+    then has other jit cache keys than it had at step 0, and every
+    program compiles again at step 1 — so the compiled steppers let
+    state enter step 0 as it will enter every later step."""
+    if isinstance(arr, jax.Array) and not isinstance(arr, jax.core.Tracer) \
+            and not arr.committed:
+        return jax.device_put(arr, arr.sharding)
+    return arr
+
+
 def device_count(kind: str | None = None) -> int:
     kind = kind or get_place().kind
     total = 0

@@ -86,10 +86,16 @@ def reduce_scatter(x, group=None, axis=0):
                                         tiled=True)
 
         def fwd(a):
-            return f(a), None
+            return f(a), a[:0]  # no data: carries the input's type
 
-        def bwd(_, g):
-            return (jax.lax.all_gather(g, ax, axis=axis, tiled=True),)
+        def bwd(like, g):
+            full = jax.lax.all_gather(g, ax, axis=axis, tiled=True)
+            if ax in jax.typeof(g).vma and ax not in jax.typeof(like).vma:
+                # check_vma typed the input unvarying over `ax` (a
+                # replicated in_spec), so its cotangent must be too. Every
+                # rank holds the same gathered value: pmax says so exactly
+                full = jax.lax.pmax(full, ax)
+            return (full,)
 
         f.defvjp(fwd, bwd)
         return apply(f, x, name="sp_reduce_scatter")

@@ -21,6 +21,7 @@ import numpy as np
 
 from ..core import random as _random
 from ..core.autograd import no_grad
+from ..core.device import committed
 from ..core.tensor import Tensor, to_tensor
 from ..io import DataLoader
 from ..metric import Metric
@@ -207,10 +208,10 @@ class _JitStepper:
             loss_v, out_arrays, new_buf, new_params, new_states = \
                 self._jit(
                     key,
-                    [t._data for _, t in train_p],
-                    [t._data for _, t in frozen_p],
-                    [t._data for _, t in bufs],
-                    states,
+                    [committed(t._data) for _, t in train_p],
+                    [committed(t._data) for _, t in frozen_p],
+                    [committed(t._data) for _, t in bufs],
+                    jax.tree_util.tree_map(committed, states),
                     jnp.asarray(opt.get_lr(), jnp.float32),
                     jnp.asarray(opt._step_count, jnp.int32),
                     *[t._data for t in inputs + labels])

@@ -606,12 +606,24 @@ class DataLoader:
                     else:
                         results[i] = payload
                 yield self.collate_fn(results.pop(want))
+            # every batch is delivered and every worker has its None in
+            # the queue: let each run to it, so that one the others
+            # outran still runs worker_init_fn (the reference runs it in
+            # every worker it starts) before it leaves
+            for p in procs:
+                p.join(timeout=5)
         finally:
             for p in procs:
                 if p.is_alive():
                     p.terminate()
             for p in procs:
                 p.join(timeout=5)
+                if p.is_alive():
+                    # under load a worker can outlive SIGTERM's 5 s: one
+                    # that still lives would make a segment AFTER the sweep
+                    # below, and nothing would ever unlink it
+                    p.kill()
+                    p.join(timeout=30)
             # release undelivered shm segments — the workers unregistered
             # them from their resource_tracker, so nothing else will ever
             # unlink a leaked one (early break / error / a terminate()

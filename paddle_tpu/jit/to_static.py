@@ -9,7 +9,8 @@ import jax
 import jax.numpy as jnp
 
 from ..core import random as _random
-from ..core.autograd import apply, is_grad_enabled
+from ..core.autograd import apply, is_grad_enabled, mark_stable
+from ..core.device import committed
 from ..core.tensor import GraphBreakError as _GraphBreakError
 from ..core.tensor import Tensor
 from ..nn.layer import Layer
@@ -206,8 +207,12 @@ class StaticFunction:
 
         in_tensors, rebuild_in, static_sig = _tree_flatten_tensors(
             (args, kwargs))
+        # train()/eval() select another program (dropout, BatchNorm,
+        # aux heads): the mode of every sublayer is part of the key
         cache_key = (static_sig, len(named_params), len(named_buffers),
-                     tuple((li, n) for li, n, _ in named_params))
+                     tuple((li, n) for li, n, _ in named_params),
+                     tuple(sub.training for layer in layers
+                           for sub in layer.sublayers(include_self=True)))
 
         jit_entry = self._jit_cache.get(cache_key)
         if jit_entry is None:
@@ -219,11 +224,19 @@ class StaticFunction:
         key = _random.next_key()
         param_tensors = [p for _, _, p in named_params]
         buffer_tensors = [b for _, _, b in named_buffers]
+        # else call 1 (fed the program's outputs, and what an optimizer
+        # made of its gradients) compiles the forward and backward again
+        for t in param_tensors + buffer_tensors:
+            t._data = committed(t._data)
 
         try:
+            # the tape node keeps the buffers AS THEY ENTER the program
+            # (detached views): the live ones are rebound to the program's
+            # outputs below, and backward() must neither see those values
+            # nor trip its modified-in-place check on them
             outs = apply(jit_fn, Tensor(key),
-                         *buffer_tensors, *param_tensors, *in_tensors,
-                         name="to_static")
+                         *[b.detach() for b in buffer_tensors],
+                         *param_tensors, *in_tensors, name="to_static")
         except (jax.errors.ConcretizationTypeError,
                 jax.errors.TracerBoolConversionError,
                 jax.errors.TracerArrayConversionError,
@@ -279,7 +292,8 @@ class StaticFunction:
                 for t, arr in saved:
                     t._data = arr
 
-        return jax.jit(pure), n_out_holder
+        # built once per cache entry; apply() micro-jits it by identity
+        return mark_stable(jax.jit(pure)), n_out_holder
 
     def rollback(self):
         return self._fn

@@ -909,14 +909,27 @@ def _nll_impl(input, label, weight, ignore_index, reduction):
     return loss
 
 
+# the two BCE bodies are module-level: one function object, so apply()'s
+# micro-jit compiles each once, not once a call
+def _bce(a, b):
+    a = jnp.clip(a, 1e-12, 1 - 1e-12)
+    return -(b * jnp.log(a) + (1 - b) * jnp.log(1 - a))
+
+
+def _bce_logits(a, b, *pw):
+    max_val = jnp.clip(-a, 0, None)
+    if pw:
+        log_w = (pw[0] - 1) * b + 1
+        return (1 - b) * a + log_w * (
+            jnp.log(jnp.exp(-max_val) + jnp.exp(-a - max_val)) + max_val)
+    return (1 - b) * a + max_val + jnp.log(
+        jnp.exp(-max_val) + jnp.exp(-a - max_val))
+
+
 def binary_cross_entropy(input, label, weight=None, reduction="mean",
                          name=None):
     input, label = ensure_tensor(input), ensure_tensor(label)
-
-    def f(a, b):
-        a = jnp.clip(a, 1e-12, 1 - 1e-12)
-        return -(b * jnp.log(a) + (1 - b) * jnp.log(1 - a))
-    loss = apply(f, input, label, name="bce")
+    loss = apply(_bce, input, label, name="bce")
     if weight is not None:
         loss = loss * ensure_tensor(weight)
     return _reduce_loss(loss, reduction)
@@ -926,19 +939,8 @@ def binary_cross_entropy_with_logits(logit, label, weight=None,
                                      reduction="mean", pos_weight=None,
                                      name=None):
     logit, label = ensure_tensor(logit), ensure_tensor(label)
-
-    def f(a, b, *pw):
-        max_val = jnp.clip(-a, 0, None)
-        if pw:
-            log_w = (pw[0] - 1) * b + 1
-            loss = (1 - b) * a + log_w * (
-                jnp.log(jnp.exp(-max_val) + jnp.exp(-a - max_val)) + max_val)
-        else:
-            loss = (1 - b) * a + max_val + jnp.log(
-                jnp.exp(-max_val) + jnp.exp(-a - max_val))
-        return loss
     args = [ensure_tensor(pos_weight)] if pos_weight is not None else []
-    loss = apply(f, logit, label, *args, name="bce_logits")
+    loss = apply(_bce_logits, logit, label, *args, name="bce_logits")
     if weight is not None:
         loss = loss * ensure_tensor(weight)
     return _reduce_loss(loss, reduction)

@@ -6,10 +6,12 @@ max_unpool are vectorized gathers/scatters.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-from ...core.autograd import apply
+from ...core.autograd import apply, mark_stable
 from ...core.random import next_key
 from ...core.tensor import Tensor
 from ...ops._base import ensure_tensor
@@ -223,18 +225,13 @@ def conv3d_transpose(x, weight, bias=None, stride=1, padding=0,
                              ("NCDHW", "OIDHW", "NCDHW"), output_size)
 
 
-def ctc_loss(log_probs, labels, input_lengths, label_lengths, blank=0,
-             reduction="mean", norm_by_times=False, name=None):
-    """CTC loss via the log-space alpha (forward) recursion as a
-    lax.scan over time (reference: warpctc-backed paddle ctc_loss;
-    log_probs [T, B, C] logits — softmax applied internally like the
-    reference, labels [B, L])."""
-    lp = ensure_tensor(log_probs)
-    lab = ensure_tensor(labels)._data.astype(jnp.int32)
-    il = ensure_tensor(input_lengths)._data.astype(jnp.int32)
-    ll = ensure_tensor(label_lengths)._data.astype(jnp.int32)
-
-    def f(logits):
+@functools.lru_cache(maxsize=None)
+def _ctc_loss_fn(blank, reduction, norm_by_times):
+    """The pure CTC loss of one configuration, built once: apply()'s
+    micro-jit keys on the function's identity, and a closure made in
+    every ctc_loss() call had its alpha scan compiled by XLA again on
+    every call, forward and backward."""
+    def f(logits, lab, il, ll):
         T, B, C = logits.shape
         logp = jax.nn.log_softmax(logits, axis=-1)
         L = lab.shape[1]
@@ -286,7 +283,20 @@ def ctc_loss(log_probs, labels, input_lengths, label_lengths, blank=0,
         if reduction == "sum":
             return jnp.sum(loss)
         return loss
-    return apply(f, lp, name="ctc_loss")
+    return mark_stable(f)
+
+
+def ctc_loss(log_probs, labels, input_lengths, label_lengths, blank=0,
+             reduction="mean", norm_by_times=False, name=None):
+    """CTC loss via the log-space alpha (forward) recursion as a
+    lax.scan over time (reference: warpctc-backed paddle ctc_loss;
+    log_probs [T, B, C] logits — softmax applied internally like the
+    reference, labels [B, L])."""
+    lp = ensure_tensor(log_probs)
+    lab, il, ll = (Tensor(ensure_tensor(t)._data.astype(jnp.int32))
+                   for t in (labels, input_lengths, label_lengths))
+    return apply(_ctc_loss_fn(blank, reduction, bool(norm_by_times)),
+                 lp, lab, il, ll, name="ctc_loss")
 
 
 def dice_loss(input, label, epsilon=1e-5, name=None):

@@ -9,16 +9,64 @@ launches. Multi-layer and bidirectional variants compose the scan.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 
-from ..core.autograd import apply
-from ..core.tensor import Tensor
+from ..core.autograd import apply, mark_stable
 from . import functional as F
 from . import initializer as I
 from .layer import Layer, LayerList
+
+
+# The pure functions handed to apply() are built ONCE per configuration
+# and shared by every layer of that configuration: apply()'s micro-jit
+# (and lax.scan's compiled loop) key on the function's identity, so a
+# lambda made in forward() would be traced — and, around a scan, compiled
+# by XLA — again on every call.
+
+@functools.lru_cache(maxsize=None)
+def _cell_fn(mode, hidden, activation="tanh"):
+    """One step `(x, *state, wi, wh, bi, bh) -> new state` of a cell."""
+    if mode == "LSTM":
+        def f(x, h, c, wi, wh, bi, bh):
+            return LSTMCell._step(x, h, c, wi, wh, bi, bh, hidden)
+    elif mode == "GRU":
+        def f(x, h, wi, wh, bi, bh):
+            return GRUCell._step(x, h, wi, wh, bi, bh, hidden)
+    else:
+        act = jnp.tanh if activation == "tanh" else jax.nn.relu
+
+        def f(x, h, wi, wh, bi, bh):
+            return act(x @ wi.T + bi + h @ wh.T + bh)
+    return mark_stable(f)
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_fn(mode, hidden, activation, reverse):
+    """`(wi, wh, bi, bh, x[B,T,C]) -> (y[B,T,H], h, c)`: the cell's step
+    under lax.scan over T, from a zero state."""
+    cell = _cell_fn(mode, hidden, activation)
+    is_lstm = mode == "LSTM"
+
+    def f(wi, wh, bi, bh, xa):
+        h0 = jnp.zeros((xa.shape[0], hidden), xa.dtype)
+
+        def step(carry, xt):
+            if is_lstm:
+                h, c = cell(xt, carry[0], carry[1], wi, wh, bi, bh)
+                return (h, c), h
+            h = cell(xt, carry, wi, wh, bi, bh)
+            return h, h
+
+        carry, ys = jax.lax.scan(step, (h0, h0) if is_lstm else h0,
+                                 jnp.moveaxis(xa, 1, 0),  # [T, B, C]
+                                 reverse=reverse)
+        final_h, final_c = carry if is_lstm else (carry, carry)
+        return jnp.moveaxis(ys, 0, 1), final_h, final_c
+    return mark_stable(f)
 
 
 class RNNCellBase(Layer):
@@ -51,10 +99,8 @@ class SimpleRNNCell(RNNCellBase):
     def forward(self, inputs, states=None):
         h = states if states is not None else \
             self.get_initial_states(inputs)
-        act = jnp.tanh if self.activation == "tanh" else jax.nn.relu
         out = apply(
-            lambda x, hp, wi, wh, bi, bh: act(
-                x @ wi.T + bi + hp @ wh.T + bh),
+            _cell_fn("RNN", self.hidden_size, self.activation),
             inputs, h, self.weight_ih, self.weight_hh, self.bias_ih,
             self.bias_hh, name="rnn_cell")
         return out, out
@@ -97,11 +143,9 @@ class LSTMCell(RNNCellBase):
             c = self.get_initial_states(inputs)
         else:
             h, c = states
-        hid = self.hidden_size
         h_new, c_new = apply(
-            lambda x, hp, cp, wi, wh, bi, bh: LSTMCell._step(
-                x, hp, cp, wi, wh, bi, bh, hid),
-            inputs, h, c, self.weight_ih, self.weight_hh, self.bias_ih,
+            _cell_fn("LSTM", self.hidden_size), inputs, h, c,
+            self.weight_ih, self.weight_hh, self.bias_ih,
             self.bias_hh, name="lstm_cell")
         return h_new, (h_new, c_new)
 
@@ -139,11 +183,9 @@ class GRUCell(RNNCellBase):
     def forward(self, inputs, states=None):
         h = states if states is not None else \
             self.get_initial_states(inputs)
-        hid = self.hidden_size
         h_new = apply(
-            lambda x, hp, wi, wh, bi, bh: GRUCell._step(x, hp, wi, wh, bi,
-                                                        bh, hid),
-            inputs, h, self.weight_ih, self.weight_hh, self.bias_ih,
+            _cell_fn("GRU", self.hidden_size), inputs, h,
+            self.weight_ih, self.weight_hh, self.bias_ih,
             self.bias_hh, name="gru_cell")
         return h_new, h_new
 
@@ -176,44 +218,10 @@ class _RNNBase(Layer):
 
     def _scan_direction(self, cell, x, reverse):
         """x: [B, T, C] → outputs [B, T, H] via lax.scan over T."""
-        named = list(cell.named_parameters())
-        is_lstm = self.MODE == "LSTM"
-        hid = self.hidden_size
-
-        def pure(params, xa):
-            saved = [(p, p._data) for _, p in named]
-            for (_, p), arr in zip(named, params):
-                p._data = arr
-            try:
-                b = xa.shape[0]
-                h0 = jnp.zeros((b, hid), xa.dtype)
-                carry0 = (h0, h0) if is_lstm else h0
-
-                def step(carry, xt):
-                    if is_lstm:
-                        _, new_states = cell(Tensor(xt),
-                                             (Tensor(carry[0]),
-                                              Tensor(carry[1])))
-                        h_new = new_states[0]._data
-                        return ((h_new, new_states[1]._data), h_new)
-                    out, new_h = cell(Tensor(xt), Tensor(carry))
-                    return new_h._data, out._data
-
-                xs = jnp.moveaxis(xa, 1, 0)  # [T, B, C]
-                if reverse:
-                    xs = jnp.flip(xs, 0)
-                carry, ys = jax.lax.scan(step, carry0, xs)
-                if reverse:
-                    ys = jnp.flip(ys, 0)
-                final_h = carry[0] if is_lstm else carry
-                final_c = carry[1] if is_lstm else carry
-                return jnp.moveaxis(ys, 0, 1), final_h, final_c
-            finally:
-                for p, arr in saved:
-                    p._data = arr
-
-        outs = apply(lambda *arrs: pure(list(arrs[:-1]), arrs[-1]),
-                     *[p for _, p in named], x, name=f"{self.MODE}_scan")
+        fn = _scan_fn(self.MODE, self.hidden_size,
+                      getattr(cell, "activation", "tanh"), reverse)
+        outs = apply(fn, cell.weight_ih, cell.weight_hh, cell.bias_ih,
+                     cell.bias_hh, x, name=f"{self.MODE}_scan")
         return outs  # (y, h, c)
 
     def forward(self, inputs, initial_states=None, sequence_length=None):

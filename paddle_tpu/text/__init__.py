@@ -72,6 +72,16 @@ def _viterbi_jax(potentials, lengths, trans, include_bos_eos_tag):
     return scores, paths.astype(jnp.int64)
 
 
+# one function object each (not a lambda a call), so that apply()'s
+# micro-jit holds the two scans compiled instead of compiling them again
+def _viterbi_bos_eos(p, tr, ln):
+    return _viterbi_jax(p, ln, tr, True)
+
+
+def _viterbi_plain(p, tr, ln):
+    return _viterbi_jax(p, ln, tr, False)
+
+
 def viterbi_decode(potentials, transition_params, lengths,
                    include_bos_eos_tag=True, name=None):
     potentials = to_tensor(potentials) if not isinstance(potentials, Tensor) \
@@ -81,7 +91,7 @@ def viterbi_decode(potentials, transition_params, lengths,
     lengths = to_tensor(lengths) if not isinstance(lengths, Tensor) \
         else lengths
     return apply(
-        lambda p, tr, ln: _viterbi_jax(p, ln, tr, include_bos_eos_tag),
+        _viterbi_bos_eos if include_bos_eos_tag else _viterbi_plain,
         potentials, transition_params, lengths, name="viterbi_decode")
 
 

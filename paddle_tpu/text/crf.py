@@ -29,6 +29,44 @@ from ..nn.layer import Layer
 __all__ = ["LinearChainCrf", "LinearChainCrfLoss"]
 
 
+# Module-level, so that each is ONE function object for the life of the
+# process: apply() keys its micro-jit (and lax.scan its compiled loop) on
+# the function's identity, and a def nested in the method would be a new
+# object — a new trace and a new XLA compile — on every call.
+def _gold_score(em, lab, ln, trans, start, stop):
+    b, t, n = em.shape
+    pos = jnp.arange(t)
+    valid = pos[None, :] < ln[:, None]                 # [B,T]
+    em_score = jnp.take_along_axis(
+        em, lab[..., None], axis=2)[..., 0]            # [B,T]
+    em_score = jnp.where(valid, em_score, 0.0).sum(-1)
+    tr = trans[lab[:, :-1], lab[:, 1:]]                # [B,T-1]
+    tr_valid = pos[None, 1:] < ln[:, None]
+    tr_score = jnp.where(tr_valid, tr, 0.0).sum(-1)
+    last = jnp.take_along_axis(
+        lab, (ln - 1)[:, None], axis=1)[:, 0]
+    return (em_score + tr_score + start[lab[:, 0]]
+            + stop[last])
+
+
+def _log_partition(em, ln, trans, start, stop):
+    b, t, n = em.shape
+    alpha0 = start[None, :] + em[:, 0]                 # [B,N]
+
+    def step(alpha, inputs):
+        em_t, pos = inputs
+        nxt = jax.nn.logsumexp(
+            alpha[:, :, None] + trans[None], axis=1) + em_t
+        keep = (pos < ln)[:, None]
+        return jnp.where(keep, nxt, alpha), None
+
+    alpha, _ = jax.lax.scan(
+        step, alpha0,
+        (jnp.swapaxes(em[:, 1:], 0, 1),
+         jnp.arange(1, t)))
+    return jax.nn.logsumexp(alpha + stop[None, :], axis=-1)
+
+
 class LinearChainCrf(Layer):
     """Holds the learnable transition scores.
 
@@ -51,47 +89,15 @@ class LinearChainCrf(Layer):
         emissions = _ensure(emissions)
         labels = _ensure(labels).detach()
         lengths = _ensure(lengths).detach()
-
-        def f(em, lab, ln, trans, start, stop):
-            b, t, n = em.shape
-            pos = jnp.arange(t)
-            valid = pos[None, :] < ln[:, None]                 # [B,T]
-            em_score = jnp.take_along_axis(
-                em, lab[..., None], axis=2)[..., 0]            # [B,T]
-            em_score = jnp.where(valid, em_score, 0.0).sum(-1)
-            tr = trans[lab[:, :-1], lab[:, 1:]]                # [B,T-1]
-            tr_valid = pos[None, 1:] < ln[:, None]
-            tr_score = jnp.where(tr_valid, tr, 0.0).sum(-1)
-            last = jnp.take_along_axis(
-                lab, (ln - 1)[:, None], axis=1)[:, 0]
-            return (em_score + tr_score + start[lab[:, 0]]
-                    + stop[last])
-        return apply(f, emissions, labels, lengths, self.transitions,
-                     self.start_scores, self.stop_scores,
+        return apply(_gold_score, emissions, labels, lengths,
+                     self.transitions, self.start_scores, self.stop_scores,
                      name="crf_gold_score")
 
     def log_partition(self, emissions, lengths):
         """log Z via the forward algorithm: [B,T,N],[B] -> [B]."""
         emissions = _ensure(emissions)
         lengths = _ensure(lengths).detach()
-
-        def f(em, ln, trans, start, stop):
-            b, t, n = em.shape
-            alpha0 = start[None, :] + em[:, 0]                 # [B,N]
-
-            def step(alpha, inputs):
-                em_t, pos = inputs
-                nxt = jax.nn.logsumexp(
-                    alpha[:, :, None] + trans[None], axis=1) + em_t
-                keep = (pos < ln)[:, None]
-                return jnp.where(keep, nxt, alpha), None
-
-            alpha, _ = jax.lax.scan(
-                step, alpha0,
-                (jnp.swapaxes(em[:, 1:], 0, 1),
-                 jnp.arange(1, t)))
-            return jax.nn.logsumexp(alpha + stop[None, :], axis=-1)
-        return apply(f, emissions, lengths, self.transitions,
+        return apply(_log_partition, emissions, lengths, self.transitions,
                      self.start_scores, self.stop_scores,
                      name="crf_log_partition")
 

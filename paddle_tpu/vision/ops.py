@@ -19,11 +19,13 @@ returned count).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.autograd import apply as _apply
+from ..core.autograd import apply as _apply, mark_stable
 from ..core.tensor import Tensor
 from ..nn.layer import Layer
 from ..ops._base import ensure_tensor
@@ -644,49 +646,15 @@ __all__ += ["prior_box", "matrix_nms", "psroi_pool", "read_file",
             "decode_jpeg"]
 
 
-def yolo_loss(x, gt_box, gt_label, anchors, anchor_mask, class_num,
-              ignore_thresh, downsample_ratio, gt_score=None,
-              use_label_smooth=True, name=None, scale_x_y=1.0):
-    """YOLOv3 training loss (reference paddle.vision.ops.yolo_loss /
-    phi yolov3_loss kernel — upstream unverified; formulas follow the
-    YOLOv3 paper + the reference kernel structure):
-
-    - x: [N, A*(5+class_num), H, W] raw head output (A = len(anchor_mask));
-    - gt_box [N, B, 4] normalized (cx, cy, w, h), gt_label [N, B],
-      gt_score [N, B] (mixup weight, default 1);
-    - per-gt responsibility: best wh-IoU over ALL anchors; the gt is
-      assigned only if that anchor belongs to this head's anchor_mask,
-      at cell (floor(cx*W), floor(cy*H));
-    - sigmoid-CE for x/y/objectness/class, L1 for w/h, box weight
-      (2 − w·h)·score; negatives whose best IoU with any gt exceeds
-      `ignore_thresh` are ignored; label smoothing moves targets to
-      (1−δ, δ), δ = min(1/class_num, 1/40).
-
-    TPU-native: everything is dense [N, A, H, W] target maps built by a
-    lax.fori_loop of per-gt scatters (deterministic last-writer, B is
-    small) + one fused elementwise loss — no dynamic shapes. Returns
-    the per-sample loss [N]."""
-    x = ensure_tensor(x)
-    gt_box, gt_label = ensure_tensor(gt_box), ensure_tensor(gt_label)
-    args = [x, gt_box, gt_label]
-    if gt_score is not None:
-        args.append(ensure_tensor(gt_score))
-    anchors = [float(a) for a in anchors]
-    amask = [int(a) for a in anchor_mask]
+@functools.lru_cache(maxsize=None)
+def _yolo_loss_fn(anchors, amask, class_num, ignore_thresh,
+                  downsample_ratio, delta, sx):
+    """The pure loss of one head configuration, built once: apply()'s
+    micro-jit keys on the function's identity, and a closure made in
+    every yolo_loss() call would have its fori_loop compiled by XLA
+    again on every call (forward and backward)."""
     A = len(amask)
     n_anchors = len(anchors) // 2
-    N, C, H, W = x.shape
-    if C != A * (5 + class_num):
-        raise ValueError(f"x channels {C} != len(anchor_mask)*(5+cls) "
-                         f"= {A * (5 + class_num)}")
-    B = gt_box.shape[1]
-    in_w, in_h = W * downsample_ratio, H * downsample_ratio
-    aw_all = jnp.asarray(anchors[0::2], jnp.float32) / in_w   # normalized
-    ah_all = jnp.asarray(anchors[1::2], jnp.float32) / in_h
-    aw = aw_all[jnp.asarray(amask)]
-    ah = ah_all[jnp.asarray(amask)]
-    delta = min(1.0 / class_num, 1.0 / 40.0) if use_label_smooth else 0.0
-    sx = float(scale_x_y)
 
     def bce(logit, label):
         # sigmoid cross entropy with logits, stable form
@@ -694,6 +662,13 @@ def yolo_loss(x, gt_box, gt_label, anchors, anchor_mask, class_num,
             jnp.log1p(jnp.exp(-jnp.abs(logit)))
 
     def f(xa, gb, gl, *rest):
+        N, _, H, W = xa.shape
+        B = gb.shape[1]
+        in_w, in_h = W * downsample_ratio, H * downsample_ratio
+        aw_all = jnp.asarray(anchors[0::2], jnp.float32) / in_w  # normalized
+        ah_all = jnp.asarray(anchors[1::2], jnp.float32) / in_h
+        aw = aw_all[jnp.asarray(amask)]
+        ah = ah_all[jnp.asarray(amask)]
         gs = rest[0] if rest else jnp.ones((N, B), jnp.float32)
         xa = xa.reshape(N, A, 5 + class_num, H, W).astype(jnp.float32)
         tx, ty, tw, th = xa[:, :, 0], xa[:, :, 1], xa[:, :, 2], xa[:, :, 3]
@@ -799,6 +774,46 @@ def yolo_loss(x, gt_box, gt_label, anchors, anchor_mask, class_num,
                       + jnp.sum(loss_cls, axis=(1, 2, 3, 4)))
         return per_sample
 
+    return mark_stable(f)
+
+
+def yolo_loss(x, gt_box, gt_label, anchors, anchor_mask, class_num,
+              ignore_thresh, downsample_ratio, gt_score=None,
+              use_label_smooth=True, name=None, scale_x_y=1.0):
+    """YOLOv3 training loss (reference paddle.vision.ops.yolo_loss /
+    phi yolov3_loss kernel — upstream unverified; formulas follow the
+    YOLOv3 paper + the reference kernel structure):
+
+    - x: [N, A*(5+class_num), H, W] raw head output (A = len(anchor_mask));
+    - gt_box [N, B, 4] normalized (cx, cy, w, h), gt_label [N, B],
+      gt_score [N, B] (mixup weight, default 1);
+    - per-gt responsibility: best wh-IoU over ALL anchors; the gt is
+      assigned only if that anchor belongs to this head's anchor_mask,
+      at cell (floor(cx*W), floor(cy*H));
+    - sigmoid-CE for x/y/objectness/class, L1 for w/h, box weight
+      (2 − w·h)·score; negatives whose best IoU with any gt exceeds
+      `ignore_thresh` are ignored; label smoothing moves targets to
+      (1−δ, δ), δ = min(1/class_num, 1/40).
+
+    TPU-native: everything is dense [N, A, H, W] target maps built by a
+    lax.fori_loop of per-gt scatters (deterministic last-writer, B is
+    small) + one fused elementwise loss — no dynamic shapes. Returns
+    the per-sample loss [N]."""
+    x = ensure_tensor(x)
+    gt_box, gt_label = ensure_tensor(gt_box), ensure_tensor(gt_label)
+    args = [x, gt_box, gt_label]
+    if gt_score is not None:
+        args.append(ensure_tensor(gt_score))
+    A = len(anchor_mask)
+    C = x.shape[1]
+    if C != A * (5 + class_num):
+        raise ValueError(f"x channels {C} != len(anchor_mask)*(5+cls) "
+                         f"= {A * (5 + class_num)}")
+    delta = min(1.0 / class_num, 1.0 / 40.0) if use_label_smooth else 0.0
+    f = _yolo_loss_fn(tuple(float(a) for a in anchors),
+                      tuple(int(a) for a in anchor_mask), int(class_num),
+                      float(ignore_thresh), int(downsample_ratio), delta,
+                      float(scale_x_y))
     return _apply(f, *args, name="yolo_loss")
 
 

@@ -10,6 +10,7 @@ reached only by `python chip_smoke.py` through the builder's chip tool.
 import glob
 import importlib.util
 import os
+import signal
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
@@ -49,6 +50,34 @@ def pytest_configure(config):
 
 
 _REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+
+# One time limit a test (set-up and tear-down included), so that a hang
+# costs one test and not the run's tail. Every blocking call in tests/
+# (subprocess, join, wait, get, result) has a shorter deadline of its own.
+_TEST_LIMIT_S = 300
+
+
+@pytest.fixture(autouse=True)
+def _per_test_time_limit(request):
+    """SIGALRM on the main thread, where pytest and xdist's workers run
+    the tests. The `slow` replays (not in tier-1) are long by definition
+    and keep only their subprocess deadlines."""
+    if request.node.get_closest_marker("slow"):
+        yield
+        return
+    limit = _TEST_LIMIT_S
+
+    def on_alarm(signum, frame):
+        pytest.fail(f"{request.node.nodeid} ran over the per-test limit "
+                    f"of {limit} s", pytrace=False)
+
+    old_handler = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, old_handler)
 
 
 @pytest.fixture(autouse=True)
@@ -97,6 +126,24 @@ def _bench_artifact_guard(request):
         for p in glob.glob(pattern):
             if p not in snap:
                 os.unlink(p)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _fleet_state_stays_in_its_file():
+    """A file that ends with the fleet initialised (test_sequence_parallel,
+    test_pipeline, ...) left `Model.fit` of whatever file the worker got
+    next on the SPMD trainer, and its eager `evaluate` then failed on
+    mesh-placed weights: which files share a worker differs a run."""
+    yield
+    import sys
+    fleet = sys.modules.get("paddle_tpu.distributed.fleet.fleet")
+    if fleet is not None and fleet._state.initialized:
+        from paddle_tpu.distributed.fleet.topology import \
+            set_hybrid_communicate_group
+        fleet._state.initialized = False
+        fleet._state.strategy = None
+        fleet._state.hcg = None
+        set_hybrid_communicate_group(None)
 
 
 def pytest_terminal_summary(terminalreporter, exitstatus, config):

@@ -923,7 +923,7 @@ class TestWholeTree:
         p = subprocess.run(
             [sys.executable, os.path.join("tools", "lint.py"),
              "--json", "paddle_tpu/analysis"],
-            cwd=ROOT, capture_output=True, text=True)
+            cwd=ROOT, capture_output=True, text=True, timeout=120)
         assert p.returncode == 0, p.stderr[-2000:]
         out = json.loads(p.stdout)
         assert out["findings"] == []

@@ -28,7 +28,7 @@ class TestLinalgNamespace:
             "    assert hasattr(P.linalg, n), n\n"
             "print('ok')\n")
         p = subprocess.run([sys.executable, "-c", code],
-                           capture_output=True, text=True, timeout=300)
+                           capture_output=True, text=True, timeout=280)
         assert p.returncode == 0, p.stderr[-1500:]
         assert "ok" in p.stdout
 

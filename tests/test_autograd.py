@@ -414,3 +414,79 @@ class TestGradModeThreadLocal:
         x = t([1.5])
         (x * x).sum().backward()
         np.testing.assert_allclose(x.grad.numpy(), [3.0], rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# An eager training loop reaches a steady state: after the first two
+# steps (step 1 still compiles what the optimizer's new state reaches)
+# no call compiles anything. A function handed to apply(), or a scan
+# body, that is a new object on every call would be compiled by XLA on
+# every call — YOLOv3.get_loss did so six times a step, the BiGRU-CRF
+# eight times.
+
+def _yolov3_head_loop():
+    """YOLOv3's own heads, get_loss, backward and Adam, eagerly, on fixed
+    neck features (the whole net's first two eager steps alone are ~600
+    per-op compiles; the recompile a call was in get_loss)."""
+    from paddle_tpu.optimizer import Adam
+    from paddle_tpu.vision.models.yolov3 import YOLOv3, YOLOv3Config
+
+    P.seed(0)
+    m = YOLOv3(YOLOv3Config.tiny())
+    m.train()
+    heads = (m.head5, m.head4, m.head3)
+    opt = Adam(3e-3, parameters=[p for h in heads for p in h.parameters()])
+    rng = np.random.default_rng(0)
+    feats = [t(rng.standard_normal((1, h.weight.shape[1], s, s)), sg=True)
+             for h, s in zip(heads, (2, 4, 8))]
+    gb = t([[[0.375, 0.5, 0.5, 0.5]]], sg=True)
+    gl = P.to_tensor(np.array([[1]], np.int32))
+
+    def step():
+        loss = m.get_loss([h(f) for h, f in zip(heads, feats)], gb, gl)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+    return step
+
+
+def _bigru_crf_loop():
+    from paddle_tpu import nn
+    from paddle_tpu.optimizer import Adam
+    from paddle_tpu.text import LinearChainCrf, LinearChainCrfLoss
+
+    P.seed(4)
+    emb, gru = nn.Embedding(40, 32), nn.GRU(32, 16, direction="bidirect")
+    proj, crf = nn.Linear(32, 3), LinearChainCrf(3)
+    loss_fn = LinearChainCrfLoss(crf)
+    opt = Adam(5e-3, parameters=[p for layer in (emb, gru, proj, crf)
+                                 for p in layer.parameters()])
+    rng = np.random.default_rng(0)
+    lengths = P.to_tensor(np.full((16,), 12, np.int64))
+
+    def step():
+        ids = P.to_tensor(rng.integers(0, 40, (16, 12)).astype(np.int64))
+        tags = P.to_tensor(rng.integers(0, 3, (16, 12)).astype(np.int64))
+        loss = loss_fn(proj(gru(emb(ids))[0]), lengths, tags)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+    return step
+
+
+@pytest.mark.parametrize("make_loop", [_yolov3_head_loop, _bigru_crf_loop],
+                         ids=["yolov3", "bigru_crf"])
+def test_eager_loop_compiles_nothing_after_step_1(make_loop, caplog):
+    import logging
+
+    import jax
+
+    step = make_loop()
+    step()
+    step()
+    with jax.log_compiles(), caplog.at_level(logging.WARNING, logger="jax"):
+        step()
+        step()
+    compiled = [r.getMessage() for r in caplog.records
+                if r.getMessage().startswith("Finished XLA compilation")]
+    assert compiled == []

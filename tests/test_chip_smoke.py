@@ -17,7 +17,7 @@ _SMOKE = os.path.join(_REPO, "chip_smoke.py")
 def _run(*args):
     return subprocess.run([sys.executable, *args], cwd=_REPO,
                           env=dict(os.environ, JAX_PLATFORMS="cpu"),
-                          capture_output=True, text=True, timeout=300)
+                          capture_output=True, text=True, timeout=280)
 
 
 def _json_lines(text):

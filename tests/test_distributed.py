@@ -88,8 +88,8 @@ class TestCollectiveAPI:
             dist.all_reduce(t, group=g)
             return t._data
 
-        f = jax.shard_map(body, mesh=mesh, in_specs=Pspec("mp"),
-                          out_specs=Pspec("mp"))
+        f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=Pspec("mp"),
+                          out_specs=Pspec("mp")))
         with axis_env("mp"):
             out = f(jnp.arange(4.0))
         assert np.allclose(np.asarray(out), [6, 6, 6, 6])
@@ -291,10 +291,10 @@ class TestTensorParallel:
             out = mp_ops._mp_allreduce(out, g)
             return out._data
 
-        f = jax.shard_map(
+        f = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(Pspec(), Pspec(None, "mp"), Pspec("mp", None)),
-            out_specs=Pspec())
+            out_specs=Pspec()))
         with axis_env("mp"):
             out = np.asarray(f(x, w1, w2))
         ref = np.maximum(x @ w1, 0) @ w2

@@ -12,7 +12,7 @@ import pytest
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run_example(name, args=(), timeout=420, extra_env=None):
+def _run_example(name, args=(), timeout=240, extra_env=None):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env.update(extra_env or {})
@@ -39,7 +39,7 @@ class TestExamples:
     def test_fleet_hybrid_train(self):
         out = _run_example(
             "fleet_hybrid_train.py", args=("--cpu", "--steps", "3", "--quick"),
-            timeout=540,
+            timeout=280,
             extra_env={"XLA_FLAGS":
                        "--xla_force_host_platform_device_count=8"})
         assert "hybrid-parallel training parity OK" in out
@@ -50,16 +50,16 @@ class TestExamples:
 
     def test_train_clip_contrastive_mesh(self):
         out = _run_example("train_clip_contrastive.py",
-                           args=("--cpu", "--mesh"), timeout=540)
+                           args=("--cpu", "--mesh"), timeout=280)
         assert "global-batch(mesh dp=4)" in out
         assert "CLIP contrastive training OK" in out
 
     def test_asr_whisper(self):
-        out = _run_example("asr_whisper.py", args=("--cpu", "--steps", "80"),
-                           timeout=600)
+        out = _run_example("asr_whisper.py", args=("--cpu", "--steps", "30"),
+                           timeout=280)
         assert "ASR training OK" in out
 
     def test_ner_bigru_crf(self):
         out = _run_example("ner_bigru_crf.py", args=("--cpu", "--steps", "50"),
-                           timeout=600)
+                           timeout=280)
         assert "NER training OK" in out

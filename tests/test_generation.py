@@ -20,6 +20,19 @@ def tiny_model(seed=0, **kw):
     return m
 
 
+def _full_context_rollout(m, ids, n):
+    """Greedy tokens by re-running the whole prefix every step — at ONE
+    padded length (n growing lengths were n sets of per-op compiles):
+    the model is causal, so the zero tail is invisible to row s-1, whose
+    logits pick token s."""
+    b, p = ids.shape
+    cur = np.concatenate([ids, np.zeros((b, n), np.int32)], axis=1)
+    for s in range(p, p + n):
+        logits = np.asarray(m(P.to_tensor(cur))._data)
+        cur[:, s] = logits[:, s - 1].argmax(-1)
+    return cur[:, p:]
+
+
 class TestGenerate:
     def test_greedy_matches_full_context_recompute(self):
         """The cached decode must produce the same tokens as the naive
@@ -31,16 +44,8 @@ class TestGenerate:
         got = np.asarray(m.generate(P.to_tensor(ids),
                                     max_new_tokens=6)._data)
 
-        # oracle: full forward each step, argmax of last logits
-        cur = ids.copy()
-        oracle = []
-        for _ in range(6):
-            logits = np.asarray(m(P.to_tensor(cur))._data)
-            nxt = logits[:, -1].argmax(-1).astype(np.int32)
-            oracle.append(nxt)
-            cur = np.concatenate([cur, nxt[:, None]], axis=1)
-        oracle = np.stack(oracle, axis=1)
-        np.testing.assert_array_equal(got, oracle)
+        # oracle: full forward each step, argmax of the last real logits
+        np.testing.assert_array_equal(got, _full_context_rollout(m, ids, 6))
 
     def test_gqa_cached_decode(self):
         m = tiny_model(num_key_value_heads=2)
@@ -48,12 +53,7 @@ class TestGenerate:
             np.int32)
         got = np.asarray(m.generate(P.to_tensor(ids),
                                     max_new_tokens=4)._data)
-        cur = ids.copy()
-        for i in range(4):
-            logits = np.asarray(m(P.to_tensor(cur))._data)
-            nxt = logits[:, -1].argmax(-1).astype(np.int32)
-            assert got[0, i] == nxt[0], i
-            cur = np.concatenate([cur, nxt[:, None]], axis=1)
+        np.testing.assert_array_equal(got, _full_context_rollout(m, ids, 4))
 
     def test_eos_freezes_row(self):
         m = tiny_model()
@@ -108,12 +108,7 @@ class TestGPTGenerate:
             np.int32)
         got = np.asarray(m.generate(P.to_tensor(ids),
                                     max_new_tokens=5)._data)
-        cur = ids.copy()
-        for i in range(5):
-            logits = np.asarray(m(P.to_tensor(cur))._data)
-            nxt = logits[:, -1].argmax(-1).astype(np.int32)
-            np.testing.assert_array_equal(got[:, i], nxt)
-            cur = np.concatenate([cur, nxt[:, None]], axis=1)
+        np.testing.assert_array_equal(got, _full_context_rollout(m, ids, 5))
 
 
 class TestGenerateCacheInvalidation:

@@ -98,9 +98,13 @@ class TestMoELlamaTraining:
         crit = LlamaPretrainingCriterion(cfg, model=m)
         opt = P.optimizer.AdamW(5e-3, parameters=m.parameters())
         ids = _batch(cfg)
+        # the subject is the family, not the eager path: the loss is one
+        # traced program (`to_static`) and backward() differentiates that
+        # one program — not one XLA compile an op
+        loss_of = P.jit.to_static(lambda ids: crit(m(ids), ids))
         losses = []
         for _ in range(8):
-            loss = crit(m(ids), ids)
+            loss = loss_of(ids)
             loss.backward()
             gate_w = m.llama.layers[0].mlp.gate.weight
             assert gate_w.grad is not None

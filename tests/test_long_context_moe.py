@@ -36,9 +36,9 @@ class TestUlysses:
                                     P.Tensor(va), group=g, causal=causal)
             return out._data
 
-        f = jax.shard_map(body, mesh=mesh,
+        f = jax.jit(jax.shard_map(body, mesh=mesh,
                           in_specs=Pspec(None, "sep"),
-                          out_specs=Pspec(None, "sep"))
+                          out_specs=Pspec(None, "sep")))
         with axis_env("sep"):
             out = np.asarray(f(jnp.asarray(q), jnp.asarray(k),
                                jnp.asarray(v)))
@@ -65,9 +65,9 @@ class TestSepGQA:
                                     P.Tensor(va), group=g, causal=causal)
             return out._data
 
-        f = jax.shard_map(body, mesh=mesh,
+        f = jax.jit(jax.shard_map(body, mesh=mesh,
                           in_specs=Pspec(None, "sep"),
-                          out_specs=Pspec(None, "sep"))
+                          out_specs=Pspec(None, "sep")))
         with axis_env("sep"):
             out = np.asarray(f(jnp.asarray(q), jnp.asarray(k),
                                jnp.asarray(v)))
@@ -90,9 +90,9 @@ class TestSepGQA:
                                         causal=True)
                 return out._data
 
-            f = jax.shard_map(body, mesh=mesh,
+            f = jax.jit(jax.shard_map(body, mesh=mesh,
                               in_specs=Pspec(None, "sep"),
-                              out_specs=Pspec(None, "sep"))
+                              out_specs=Pspec(None, "sep")))
             with axis_env("sep"):
                 return (f(qa, ka, va) ** 2).sum()
 
@@ -124,9 +124,9 @@ class TestSepGQA:
                                        causal=causal)
             return out._data
 
-        f = jax.shard_map(body, mesh=mesh,
+        f = jax.jit(jax.shard_map(body, mesh=mesh,
                           in_specs=Pspec(None, "sep"),
-                          out_specs=Pspec(None, "sep"))
+                          out_specs=Pspec(None, "sep")))
         with axis_env("sep"):
             out = np.asarray(f(jnp.asarray(q), jnp.asarray(k),
                                jnp.asarray(v)))
@@ -149,9 +149,9 @@ class TestRingAttention:
                                        causal=causal)
             return out._data
 
-        f = jax.shard_map(body, mesh=mesh,
+        f = jax.jit(jax.shard_map(body, mesh=mesh,
                           in_specs=Pspec(None, "sep"),
-                          out_specs=Pspec(None, "sep"))
+                          out_specs=Pspec(None, "sep")))
         with axis_env("sep"):
             out = np.asarray(f(jnp.asarray(q), jnp.asarray(k),
                                jnp.asarray(v)))
@@ -169,9 +169,9 @@ class TestRingAttention:
             def body(q_, k_, v_):
                 return _ring_attention_core(q_, k_, v_, "sep", n, True,
                                             None)
-            f = jax.shard_map(body, mesh=mesh,
+            f = jax.jit(jax.shard_map(body, mesh=mesh,
                               in_specs=Pspec(None, "sep"),
-                              out_specs=Pspec(None, "sep"))
+                              out_specs=Pspec(None, "sep")))
             return jnp.sum(f(qa, ka, va) ** 2)
 
         def dense_loss(qa, ka, va):
@@ -207,10 +207,14 @@ class TestMoE:
         tgt = P.randn([4, 6, 8])
         x = P.randn([4, 6, 8])
         opt = P.optimizer.Adam(0.01, parameters=moe.parameters())
+        # the subject is the family, not the eager path: the loss is one
+        # traced program (`to_static`) and backward() differentiates that
+        # one program — not one XLA compile an op
+        loss_of = P.jit.to_static(
+            lambda x: ((moe(x) - tgt) ** 2).mean() + 0.01 * moe.l_aux)
         losses = []
         for _ in range(30):
-            out = moe(x)
-            loss = ((out - tgt) ** 2).mean() + 0.01 * moe.l_aux
+            loss = loss_of(x)
             loss.backward()
             opt.step()
             opt.clear_grad()
@@ -325,11 +329,11 @@ class TestRingWithPallasKernel:
                                         jnp.asarray(v), causal=causal))
         mesh = Mesh(np.array(jax.devices()[:n]), ("sep",))
         fa_mod.reset_dispatch_stats()
-        f = jax.shard_map(
+        f = jax.jit(jax.shard_map(
             lambda a, b_, c: _ring_attention_core(a, b_, c, "sep", n,
                                                   causal, None),
             mesh=mesh, in_specs=Pspec(None, "sep"),
-            out_specs=Pspec(None, "sep"), check_vma=False)
+            out_specs=Pspec(None, "sep"), check_vma=False))
         out = np.asarray(f(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)))
         # the kernel must actually engage (a silent fallback here hid
         # behind parity-only asserts until round 3's dispatch counters)
@@ -346,11 +350,11 @@ class TestRingWithPallasKernel:
         mesh = Mesh(np.array(jax.devices()[:n]), ("sep",))
 
         def loss(qa, ka, va):
-            f = jax.shard_map(
+            f = jax.jit(jax.shard_map(
                 lambda a, b_, c: _ring_attention_core(a, b_, c, "sep", n,
                                                       True, None),
                 mesh=mesh, in_specs=Pspec(None, "sep"),
-                out_specs=Pspec(None, "sep"), check_vma=False)
+                out_specs=Pspec(None, "sep"), check_vma=False))
             return jnp.sum(f(qa, ka, va) ** 2)
 
         def dense_loss(qa, ka, va):
@@ -411,9 +415,9 @@ class TestUlyssesOnFlashCore:
                                     P.Tensor(va), group=g, causal=causal)
             return out._data
 
-        f = jax.shard_map(body, mesh=mesh,
+        f = jax.jit(jax.shard_map(body, mesh=mesh,
                           in_specs=Pspec(None, "sep"),
-                          out_specs=Pspec(None, "sep"), check_vma=False)
+                          out_specs=Pspec(None, "sep"), check_vma=False))
         with axis_env("sep"):
             out = np.asarray(f(jnp.asarray(q), jnp.asarray(k),
                                jnp.asarray(v)))
@@ -435,10 +439,10 @@ class TestUlyssesOnFlashCore:
                 out = ua(P.Tensor(q_), P.Tensor(k_), P.Tensor(v_),
                          group=g, causal=True)
                 return out._data
-            f = jax.shard_map(body, mesh=mesh,
+            f = jax.jit(jax.shard_map(body, mesh=mesh,
                               in_specs=Pspec(None, "sep"),
                               out_specs=Pspec(None, "sep"),
-                              check_vma=False)
+                              check_vma=False))
             with axis_env("sep"):
                 return jnp.sum(f(qa, ka, va) ** 2)
 

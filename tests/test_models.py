@@ -50,9 +50,13 @@ class TestLlama:
         crit = LlamaPretrainingCriterion(cfg)
         opt = P.optimizer.AdamW(1e-3, parameters=m.parameters())
         ids = batch(cfg.vocab_size, b=4, s=32)
+        # the subject is the family, not the eager path: the loss is one
+        # traced program (`to_static`) and backward() differentiates that
+        # one program — not one XLA compile an op
+        loss_of = P.jit.to_static(lambda ids: crit(m(ids), ids))
         losses = []
         for _ in range(8):
-            loss = crit(m(ids), ids)
+            loss = loss_of(ids)
             loss.backward()
             opt.step()
             opt.clear_grad()
@@ -119,12 +123,15 @@ class TestGPT:
         out = m(ids)
         assert out.shape == [4, 32, cfg.vocab_size]
         opt = P.optimizer.AdamW(1e-3, parameters=m.parameters())
+        # the eager forward is asserted above; the five training steps are
+        # about the family: the loss is one traced program (`to_static`)
+        # and backward() differentiates that one program
+        loss_of = P.jit.to_static(lambda ids: nn.functional.cross_entropy(
+            m(ids)[:, :-1].reshape([-1, cfg.vocab_size]),
+            ids[:, 1:].reshape([-1])))
         losses = []
         for _ in range(5):
-            logits = m(ids)
-            loss = nn.functional.cross_entropy(
-                logits[:, :-1].reshape([-1, cfg.vocab_size]),
-                ids[:, 1:].reshape([-1]))
+            loss = loss_of(ids)
             loss.backward()
             opt.step()
             opt.clear_grad()

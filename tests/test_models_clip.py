@@ -149,10 +149,14 @@ class TestCLIPParity:
         px, ids = _batch(rng, b=4)
         pxt = P.to_tensor(px)
         idt = P.to_tensor(ids.astype(np.int32))
+        # the subject is the family, not the eager path: the loss is one
+        # traced program (`to_static`) and backward() differentiates that
+        # one program — not one XLA compile an op
+        loss_of = P.jit.to_static(
+            lambda idt, pxt: clip_loss(ours(idt, pxt)[1]))
         losses = []
         for _ in range(8):
-            _, lt = ours(idt, pxt)
-            loss = clip_loss(lt)
+            loss = loss_of(idt, pxt)
             loss.backward()
             opt.step()
             opt.clear_grad()
@@ -204,10 +208,12 @@ class TestCLIPGlobalLoss:
             gi, gt, gs = vjp(jnp.ones(()))
             return val[None], gi, gt, gs[None]
 
-        fm = jax.shard_map(body, mesh=mesh,
-                           in_specs=(Pspec("dp"), Pspec("dp")),
-                           out_specs=(Pspec("dp"), Pspec("dp"),
-                                      Pspec("dp"), Pspec("dp")))
+        # jitted: the subject is the collective's value and vjp, and a
+        # bare shard_map compiles its body one op at a time (~240 programs)
+        fm = jax.jit(jax.shard_map(body, mesh=mesh,
+                                   in_specs=(Pspec("dp"), Pspec("dp")),
+                                   out_specs=(Pspec("dp"), Pspec("dp"),
+                                              Pspec("dp"), Pspec("dp"))))
         with axis_env("dp"):
             vals, gi, gt, gs = fm(img, txt)
         # every rank's pmean equals the global loss

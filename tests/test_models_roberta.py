@@ -37,6 +37,9 @@ def pair():
     hf = HFModel(hf_cfg, add_pooling_layer=True).eval()
     ours = RobertaModel(RobertaConfig.tiny())
     ours.eval()
+    # the parity tests compare values, not the eager path: forward runs
+    # as one traced program a shape, not one XLA compile an op
+    P.jit.to_static(ours)
     e = hf.embeddings
     _set(ours.embeddings.word_embeddings.weight,
          e.word_embeddings.weight)

@@ -51,8 +51,13 @@ class TestUNet:
         img[:, 0][np.broadcast_to(disc, (2, 32, 32))] += 1.0
         img[:, 0, :, 26:30] -= 1.0
         x, y = P.to_tensor(img), P.to_tensor(mask)
+        # the subject is the family, not the eager path: the loss is one
+        # traced program (`to_static`) and backward() differentiates that
+        # one program — not one XLA compile an op
+        loss_of = P.jit.to_static(
+            lambda x, y: m.loss(m(x), y, dice_weight=0.5))
         for _ in range(40):
-            loss = m.loss(m(x), y, dice_weight=0.5)
+            loss = loss_of(x, y)
             loss.backward()
             opt.step()
             opt.clear_grad()

@@ -44,6 +44,9 @@ def _transplant(hf):
                                             Wav2Vec2ForCTC)
     ours = Wav2Vec2ForCTC(Wav2Vec2Config.tiny())
     ours.eval()
+    # the parity tests compare values, not the eager path: forward runs
+    # as one traced program a shape, not one XLA compile an op
+    P.jit.to_static(ours)
     w_o, w_h = ours.wav2vec2, hf.wav2vec2
     for i, (oc, hc) in enumerate(zip(w_o.feature_extractor.convs,
                                      w_h.feature_extractor.conv_layers)):
@@ -123,9 +126,14 @@ class TestWav2Vec2Parity:
                            .astype(np.float32) * 0.1)
         labels = P.to_tensor(rng.integers(1, 32, (2, 5))
                              .astype(np.int32))
+        # the subject is the family, not the eager path: the loss is one
+        # traced program (`to_static`) and backward() differentiates that
+        # one program — not one XLA compile an op
+        loss_of = P.jit.to_static(
+            lambda wave, labels: m(wave, labels=labels)[0])
         losses = []
         for _ in range(8):
-            loss, _lg = m(wave, labels=labels)
+            loss = loss_of(wave, labels)
             loss.backward()
             opt.step()
             opt.clear_grad()

@@ -20,8 +20,11 @@ def _iou(b, g):
 
 
 class TestYOLOv3:
+    # Shape contracts and the loss/predict plumbing below are the family's,
+    # not the eager path's: the forward runs as one traced program
+    # (`to_static`), not ~170 XLA compiles an input shape.
     def test_head_shapes_and_strides(self):
-        m = YOLOv3(YOLOv3Config.tiny())
+        m = P.jit.to_static(YOLOv3(YOLOv3Config.tiny()))
         m.eval()
         x = P.to_tensor(np.zeros((2, 3, 64, 64), np.float32))
         o5, o4, o3 = m(x)
@@ -32,7 +35,7 @@ class TestYOLOv3:
 
     def test_backbone_feature_pyramid(self):
         cfg = YOLOv3Config.tiny()
-        bb = DarkNet53(cfg)
+        bb = P.jit.to_static(DarkNet53(cfg))
         bb.eval()
         c3, c4, c5 = bb(P.to_tensor(np.zeros((1, 3, 64, 64),
                                              np.float32)))
@@ -44,7 +47,13 @@ class TestYOLOv3:
         """30 Adam steps on one image with one bright box: the top
         prediction must be the right class with IoU > 0.3 — this fails
         if ANY of target assignment, decode, or NMS disagree on the
-        (cx, cy, w, h)/pixel conventions."""
+        (cx, cy, w, h)/pixel conventions.
+
+        The subject is the family, not the eager path: the 30 steps run
+        through hapi's jitted step (`Model.train_batch`) and predict's
+        forward through `to_static` — two compiles, not one an op. The
+        eager loop's steady state is guarded by
+        test_autograd.py::TestEagerLoopsCompileOnce."""
         from paddle_tpu.optimizer import Adam
         P.seed(0)
         rng = np.random.default_rng(0)
@@ -58,15 +67,13 @@ class TestYOLOv3:
         gb = P.to_tensor(np.array([[[0.375, 0.5, 0.5, 0.5]]],
                                   np.float32))
         gl = P.to_tensor(np.array([[1]], np.int32))
-        losses = []
-        for _ in range(30):
-            loss = m.get_loss(m(x), gb, gl)
-            loss.backward()
-            opt.step()
-            opt.clear_grad()
-            losses.append(float(loss))
+        model = P.Model(m)
+        model.prepare(opt, loss=lambda o5, o4, o3, b, l: m.get_loss(
+            (o5, o4, o3), b, l))
+        losses = [model.train_batch([x], [gb, gl]) for _ in range(30)]
         assert losses[-1] < losses[0] * 0.2, (losses[0], losses[-1])
         m.eval()
+        P.jit.to_static(m)
         res = m.predict(x, P.to_tensor(np.array([[64, 64]],
                                                 np.int32)))[0]
         assert len(res) > 0
@@ -76,7 +83,7 @@ class TestYOLOv3:
         assert _iou(top[2:], (8, 16, 40, 48)) > 0.3, res[:3]
 
     def test_multiimage_batch_loss_and_predict(self):
-        m = YOLOv3(YOLOv3Config.tiny())
+        m = P.jit.to_static(YOLOv3(YOLOv3Config.tiny()))
         m.eval()
         rng = np.random.default_rng(1)
         x = P.to_tensor(rng.standard_normal((2, 3, 64, 64))

@@ -78,10 +78,10 @@ class TestRaggedGlobalScatter:
                                  Tensor(gc[0]), group=g, out_rows=rows)
             return out._data[None]
 
-        f = jax.shard_map(body, mesh=_mesh(),
+        f = jax.jit(jax.shard_map(body, mesh=_mesh(),
                           in_specs=(Pspec("ep"), Pspec("ep"),
                                     Pspec("ep")),
-                          out_specs=Pspec("ep"))
+                          out_specs=Pspec("ep")))
         with axis_env("ep"):
             out = np.asarray(f(jnp.asarray(np.stack(xs)),
                                jnp.asarray(lcs), jnp.asarray(gcs)))
@@ -105,10 +105,10 @@ class TestRaggedGlobalScatter:
                                  group=g, out_rows=N)
             return back._data[None]
 
-        f = jax.shard_map(body, mesh=_mesh(),
+        f = jax.jit(jax.shard_map(body, mesh=_mesh(),
                           in_specs=(Pspec("ep"), Pspec("ep"),
                                     Pspec("ep")),
-                          out_specs=Pspec("ep"))
+                          out_specs=Pspec("ep")))
         with axis_env("ep"):
             back = np.asarray(f(jnp.asarray(np.stack(xs)),
                                 jnp.asarray(lcs), jnp.asarray(gcs)))
@@ -138,10 +138,10 @@ class TestRaggedEndToEnd:
                                  Tensor(gc[0]), group=g, out_rows=N)
             return back._data[None]
 
-        f = jax.shard_map(body, mesh=_mesh(),
+        f = jax.jit(jax.shard_map(body, mesh=_mesh(),
                           in_specs=(Pspec("ep"), Pspec("ep"),
                                     Pspec("ep")),
-                          out_specs=Pspec("ep"))
+                          out_specs=Pspec("ep")))
         with axis_env("ep"):
             out = np.asarray(f(jnp.asarray(np.stack(xs)),
                                jnp.asarray(lcs), jnp.asarray(gcs)))

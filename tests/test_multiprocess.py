@@ -42,7 +42,7 @@ class TestLaunchMultiProcess:
                "--nnodes", "2", "--master", f"127.0.0.1:{port}",
                "--log_dir", str(tmp_path / "logs"),
                os.path.join(WORKERS, "mp_worker.py"), str(tmp_path)]
-        r = subprocess.run(cmd, env=_clean_env(), cwd=REPO, timeout=300,
+        r = subprocess.run(cmd, env=_clean_env(), cwd=REPO, timeout=280,
                            capture_output=True, text=True)
         logs = ""
         logdir = tmp_path / "logs"
@@ -107,7 +107,7 @@ class TestLaunchMultiProcess:
                    os.path.join(WORKERS, "elastic_worker.py"),
                    str(store_port), str(tmp_path)]
             r = subprocess.run(cmd, env=_clean_env(), cwd=REPO,
-                               timeout=300, capture_output=True, text=True)
+                               timeout=280, capture_output=True, text=True)
             assert r.returncode == 0, (r.stdout, r.stderr)
             # the launcher really did restart rank 1
             assert "restart" in r.stdout, r.stdout
@@ -161,7 +161,7 @@ class TestMultiProcessCheckpoint:
                "--nnodes", "2", "--master", f"127.0.0.1:{port}",
                "--log_dir", str(tmp_path / "logs"),
                os.path.join(WORKERS, "ckpt_worker.py"), str(tmp_path)]
-        r = subprocess.run(cmd, env=_clean_env(), cwd=REPO, timeout=300,
+        r = subprocess.run(cmd, env=_clean_env(), cwd=REPO, timeout=280,
                            capture_output=True, text=True)
         logs = ""
         logdir = tmp_path / "logs"
@@ -189,7 +189,7 @@ class TestMultiControllerSPMD:
                "--log_dir", str(tmp_path / "logs"),
                os.path.join(WORKERS, "spmd_mc_worker.py"), str(tmp_path)]
         env = _clean_env()
-        r = subprocess.run(cmd, env=env, cwd=REPO, timeout=600,
+        r = subprocess.run(cmd, env=env, cwd=REPO, timeout=280,
                            capture_output=True, text=True)
         logs = ""
         logdir = tmp_path / "logs"
@@ -241,7 +241,7 @@ class TestElasticScaleOut:
                "--log_dir", str(logdir),
                os.path.join(WORKERS, "elastic_scaleout_worker.py"),
                str(tmp_path), str(logdir)]
-        r = subprocess.run(cmd, env=_clean_env(), cwd=REPO, timeout=300,
+        r = subprocess.run(cmd, env=_clean_env(), cwd=REPO, timeout=280,
                            capture_output=True, text=True)
         logs = ""
         if logdir.exists():
@@ -266,7 +266,7 @@ class TestElasticScaleIn:
                "--nnodes", "1:2", "--log_dir", str(tmp_path / "logs"),
                os.path.join(WORKERS, "elastic_scalein_worker.py"),
                str(tmp_path)]
-        r = subprocess.run(cmd, env=_clean_env(), cwd=REPO, timeout=300,
+        r = subprocess.run(cmd, env=_clean_env(), cwd=REPO, timeout=280,
                            capture_output=True, text=True)
         logs = ""
         logdir = tmp_path / "logs"

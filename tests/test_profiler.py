@@ -102,7 +102,7 @@ class TestRecordEvent:
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(60)
         p.stop()
         path = p.export_chrome_tracing(str(tmp_path), "mt")
         evs = load_profiler_result(path)["traceEvents"]

@@ -754,7 +754,7 @@ class TestServingDeployReplay:
              "--json"],
             cwd=repo, stdout=subprocess.PIPE,
             stderr=subprocess.DEVNULL)
-        out, _ = proc.communicate(timeout=300)
+        out, _ = proc.communicate(timeout=280)
         assert proc.returncode == 0
         report = json.loads(out)
         gate = report["deploy_gate"]

@@ -509,7 +509,7 @@ class TestDisaggHandoff:
             for t in th:
                 t.start()
             for t in th:
-                t.join()
+                t.join(60)
             assert not errs, errs
             assert out == want
             assert router.metrics.migrations_total.value == 8
@@ -582,7 +582,7 @@ class TestDisaggHandoff:
             for t in th:
                 t.start()
             for t in th:
-                t.join()
+                t.join(60)
             assert not errs, errs
             assert out == want
             assert router.metrics.failovers_total.total >= 1
@@ -1022,7 +1022,7 @@ class TestAutoscalerDrill:
                 grew = any(d == "up" for d, _, _ in aut.tick())
                 time.sleep(0.01)
             for t in th:
-                t.join()
+                t.join(60)
             assert not errs, errs
             assert grew, "burst never scaled up"
             assert len(router.replicas) == 3

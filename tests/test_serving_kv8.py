@@ -418,7 +418,7 @@ class TestInt8Migration:
             for t in th:
                 t.start()
             for t in th:
-                t.join()
+                t.join(60)
             assert not errs, errs
             assert out == want
             assert router.metrics.migrations_total.value == 8
@@ -457,7 +457,7 @@ class TestInt8Migration:
             for t in th:
                 t.start()
             for t in th:
-                t.join()
+                t.join(60)
             assert not errs, errs
             assert out == want
             assert router.metrics.failovers_total.total >= 1

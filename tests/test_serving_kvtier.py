@@ -516,7 +516,7 @@ class TestServingKvtierReplay:
         proc = subprocess.Popen(
             [sys.executable, "bench_serving.py", "--smoke", "--kvtier"],
             cwd=root, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
-        out, _ = proc.communicate(timeout=900)
+        out, _ = proc.communicate(timeout=280)
         assert proc.returncode == 0, out.decode(errors="replace")[-2000:]
         rec = json.loads(out.decode().strip().splitlines()[-1])
         assert rec["smoke"] is True

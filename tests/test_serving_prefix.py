@@ -521,7 +521,7 @@ class TestPrefixScheduling:
             try:
                 # warm the tree with one shared-prefix request
                 fe.submit(np.concatenate([shared, _tok(60, 3)]),
-                          max_new_tokens=2).result()
+                          max_new_tokens=2).result(timeout=60)
                 # a long-running decode to protect from preemption
                 longrun = fe.submit(_tok(70, 8), max_new_tokens=16)
                 accepted, rejected = [], 0
@@ -533,8 +533,8 @@ class TestPrefixScheduling:
                             max_new_tokens=4))
                     except Rejected:
                         rejected += 1
-                results = [s.result() for s in accepted]
-                long_res = longrun.result()
+                results = [s.result(timeout=60) for s in accepted]
+                long_res = longrun.result(timeout=120)
                 assert fe.drain()
             finally:
                 fe.close()

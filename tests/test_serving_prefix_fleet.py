@@ -560,7 +560,8 @@ class TestPrefixWire:
         meta, k, v = self.rep.export_prefix(prompt)
         self.rep.drop_prefix(prompt[:3 * PS])
         payload = serialize_pages(meta, k, v)[:-7]  # torn transfer
-        conn = http.client.HTTPConnection(self.rep.host, self.rep.port)
+        conn = http.client.HTTPConnection(self.rep.host, self.rep.port,
+                                          timeout=60)
         conn.request("POST", "/v1/_pages/prefix", payload,
                      {"Content-Type":
                       "application/x-paddle-tpu-kv-pages"})

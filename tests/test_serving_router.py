@@ -187,7 +187,7 @@ class TestFailover:
         for t in th:
             t.start()
         for t in th:
-            t.join()
+            t.join(60)
         assert not errs, errs
         return out
 
@@ -379,7 +379,7 @@ class TestRollingDrain:
                      for p in rng_prompts(3, seed=21)]
             for s in extra:
                 assert s.replica_idx != target
-            td.join()
+            td.join(60)
             assert done["ok"] is True
             # zero lost requests: every pre-drain stream completed
             for s in streams:

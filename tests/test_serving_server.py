@@ -125,7 +125,7 @@ class TestStreamingExactness:
             for t in th:
                 t.start()
             for t in th:
-                t.join()
+                t.join(60)
             for i, rid in enumerate(rids):
                 assert out[i] == oracle[rid]["tokens"], i
             assert eng.metrics.batch_size.export()["max"] > 1  # batched
@@ -239,7 +239,7 @@ class TestOverload:
             for t in th:
                 t.start()
             for t in th:
-                t.join()
+                t.join(60)
             codes = sorted(st for st, _, _ in results)
             assert codes == [200] * 3 + [429] * 5
             for st, headers, data in results:
@@ -309,8 +309,8 @@ class TestDrain:
                                 {"prompt": [9], "max_tokens": 2})
             assert st == 503
             assert json.loads(data)["error"]["type"] == "unavailable"
-            t.join()
-            td.join()
+            t.join(60)
+            td.join(60)
             assert drained["ok"] is True
             st, _, data = inflight["r"]
             ch = json.loads(data)["choices"][0]
@@ -339,7 +339,7 @@ class TestTeardownRace:
             barrier = threading.Barrier(len(tearers))
 
             def tear(fn):
-                barrier.wait()
+                barrier.wait(60)
                 try:
                     fn()
                 except Exception as e:  # pragma: no cover - the bug

@@ -201,3 +201,30 @@ class TestGeometric:
         np.testing.assert_allclose(out2, np.roll(np.eye(3), 1, 0) + 1)
         uv = np.asarray(P.geometric.send_uv(x, x, src, dst, "add")._data)
         assert uv.shape == (3, 3)
+
+
+class TestPerTestTimeLimit:
+    def test_overrun_fails_with_the_limits_message_and_the_next_runs(
+            self, tmp_path):
+        """tests/conftest.py's limit, end to end: a fresh pytest run over
+        a copy of the conftest, the limit patched to 1 s."""
+        import shutil
+        import subprocess
+        import sys
+        here = os.path.dirname(os.path.abspath(__file__))
+        shutil.copy(os.path.join(here, "conftest.py"), tmp_path)
+        (tmp_path / "test_limit.py").write_text(
+            "import time\n"
+            "import conftest\n"
+            "conftest._TEST_LIMIT_S = 1\n"
+            "def test_sleeps():\n"
+            "    time.sleep(2)\n"
+            "def test_next():\n"
+            "    pass\n")
+        p = subprocess.run(
+            [sys.executable, "-m", "pytest", str(tmp_path), "-q",
+             "-p", "no:cacheprovider", "--rootdir", str(tmp_path)],
+            capture_output=True, text=True, timeout=120, cwd=str(tmp_path))
+        assert "test_limit.py::test_sleeps ran over the per-test limit " \
+            "of 1 s" in p.stdout, p.stdout[-2000:] + p.stderr[-2000:]
+        assert "1 failed, 1 passed" in p.stdout, p.stdout[-2000:]

@@ -14,7 +14,12 @@ NUM_CLASSES = 10
 
 
 def _check(model, hw, num_classes=NUM_CLASSES):
+    """Output shape + grad flow of a family — not of the eager path: the
+    forward runs as one traced program (`to_static`), and `backward()`
+    differentiates that one program, instead of one XLA compile an op
+    (densenet121: ~1,500 of them)."""
     model.eval()
+    P.jit.to_static(model)
     x = P.to_tensor(np.random.default_rng(0)
                     .standard_normal((2, 3, hw, hw)).astype(np.float32))
     x.stop_gradient = False
@@ -49,7 +54,7 @@ def test_zoo_forward_backward(name, factory, hw):
 
 def test_inception_v3():
     P.seed(0)
-    model = M.inception_v3(num_classes=NUM_CLASSES)
+    model = P.jit.to_static(M.inception_v3(num_classes=NUM_CLASSES))
     model.eval()
     x = P.to_tensor(np.random.default_rng(0)
                     .standard_normal((1, 3, 299, 299)).astype(np.float32))
@@ -59,7 +64,7 @@ def test_inception_v3():
 
 def test_googlenet_aux_heads():
     P.seed(0)
-    model = M.googlenet(num_classes=NUM_CLASSES)
+    model = P.jit.to_static(M.googlenet(num_classes=NUM_CLASSES))
     x = P.to_tensor(np.random.default_rng(0)
                     .standard_normal((1, 3, 224, 224)).astype(np.float32))
     model.train()
