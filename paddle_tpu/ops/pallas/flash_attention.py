@@ -50,24 +50,25 @@ from ...core.random import next_key
 # ---------------------------------------------------------------------------
 # dispatch accounting: Pallas engagement is observable, fallbacks are loud
 
-_DISPATCH = {"pallas": 0, "fallback": 0}
+_DISPATCH = {"pallas": 0, "fallback": 0, "resident": 0, "streamed": 0,
+             "window_as_causal": 0}
 _WARNED: set = set()
 
 
 def dispatch_stats():
-    """{'pallas': n, 'fallback': m} — counted at TRACE time (how many
-    attention calls engaged the kernel vs fell back while on TPU)."""
+    """Counted at TRACE time: how many attention calls engaged the
+    kernel ('pallas') vs fell back while on TPU ('fallback'); of the
+    kernel calls, how many took each forward family ('resident': full
+    K/V in VMEM, two-axis grid; 'streamed': three-axis grid, masks and
+    cross-length); and how many windows that could not bind were sent
+    to the plain causal call ('window_as_causal')."""
     return dict(_DISPATCH)
 
 
 def reset_dispatch_stats():
-    _DISPATCH["pallas"] = 0
-    _DISPATCH["fallback"] = 0
+    for key in _DISPATCH:
+        _DISPATCH[key] = 0
     _WARNED.clear()
-
-
-def _note_pallas():
-    _DISPATCH["pallas"] += 1
 
 
 def _fallback(site, err=None):
@@ -328,9 +329,10 @@ def _fa_fwd(plan, q, k, v, *, causal, scale, return_lse=False,
             lse_bhs=False, mask=None, q_seg=None, kv_seg=None,
             fm=(None, None, None, None), dropout_p=0.0,
             dropout_seed=None):
-    """fa_forward under ``plan``. Returns out, or (out, lse_l) with
+    """fa_forward under ``plan``, counted as a kernel call of its
+    family once it has traced. Returns out, or (out, lse_l) with
     return_lse, or (out, lse_l, lse[B,H,S]) with lse_bhs too."""
-    from ._fa_kernel import fa_forward
+    from ._fa_kernel import fa_forward, forward_is_streamed
     b, _, h, _ = q.shape
 
     def fn(q, k, v, mask, q_seg, kv_seg, f0, f1, f2, f3, seed):
@@ -347,10 +349,16 @@ def _fa_fwd(plan, q, k, v, *, causal, scale, return_lse=False,
         return res
 
     out_roles = ["bshd"] + ["bh.."] * return_lse + ["bhs"] * lse_bhs
-    return _per_shard(
+    res = _per_shard(
         plan, fn, (q, k, v, mask, q_seg, kv_seg, *fm, dropout_seed),
         ("bshd", "bshd", "bshd", "mask", "seg", "seg", "fm", "fm", "fm",
          "fm", "rep"), out_roles, b, h)
+    _DISPATCH["pallas"] += 1
+    streamed = forward_is_streamed(
+        q.shape[1], k.shape[1], mask is not None,
+        any(f is not None for f in fm))
+    _DISPATCH["streamed" if streamed else "resident"] += 1
+    return res
 
 
 def _fa_bwd(plan, q, k, v, out, lse_l, g, *, causal, scale, g_lse=None,
@@ -397,10 +405,8 @@ def _flash_core_ext(q, k, v, mask, q_seg, kv_seg, causal, scale):
                      k.shape[1]))
     if ok:
         try:
-            out = _fa_fwd(plan, q, k, v, causal=causal, scale=scale,
-                          mask=mask, q_seg=q_seg, kv_seg=kv_seg)
-            _note_pallas()
-            return out
+            return _fa_fwd(plan, q, k, v, causal=causal, scale=scale,
+                           mask=mask, q_seg=q_seg, kv_seg=kv_seg)
         except Exception as e:
             _fallback("fa_forward", e)
     return _ref_ext(q, k, v, mask, q_seg, kv_seg, causal, scale)
@@ -416,7 +422,6 @@ def _ext_fwd(q, k, v, mask, q_seg, kv_seg, causal, scale):
             out, lse_l = _fa_fwd(plan, q, k, v, causal=causal,
                                  scale=scale, return_lse=True, mask=mask,
                                  q_seg=q_seg, kv_seg=kv_seg)
-            _note_pallas()
             return out, (q, k, v, out, lse_l, mask, q_seg, kv_seg,
                          _PlanRes(plan))
         except Exception as e:
@@ -521,7 +526,6 @@ def _drop_fwd(q, k, v, seed, q_seg, kv_seg, causal, scale, dropout_p):
                                  scale=scale, return_lse=True,
                                  q_seg=q_seg, kv_seg=kv_seg,
                                  dropout_p=dropout_p, dropout_seed=seed)
-            _note_pallas()
             return out, (q, k, v, out, lse_l, seed, q_seg, kv_seg)
         except Exception as e:
             _fallback("fa_forward(kernel-dropout)", e)
@@ -604,7 +608,6 @@ def _flash_lse_fwd(q, k, v, causal, scale):
             out, lse_l, lse = _fa_fwd(plan, q, k, v, causal=causal,
                                       scale=scale, return_lse=True,
                                       lse_bhs=True)
-            _note_pallas()
             return (out, lse), (q, k, v, out, lse_l, _PlanRes(plan))
         except Exception as e:
             _fallback("flash_core_lse", e)
@@ -835,7 +838,6 @@ def _try_kernel_fm(q, k, v, fm, causal, scale, want_lse, site,
     try:
         res = _fa_fwd(plan, q, k, v, causal=causal, scale=scale,
                       return_lse=want_lse, lse_bhs=lse_bhs, fm=fm)
-        _note_pallas()
         return res, _PlanRes(plan)
     except Exception as e:
         _fallback(site, e)
@@ -1020,10 +1022,17 @@ def flashmask_attention(query, key, value, startend_row_indices=None,
             # causal convention, offset = sk - sq): key j is visible to
             # query row i iff i + offset - w <= j <= i + offset, so
             # column j masks rows >= j + w + 1 - offset
-            offset = sk - q.shape[1]
-            win_rows = jnp.maximum(
-                jnp.arange(sk, dtype=jnp.int32) + w + 1 - offset, 0
-            )[None, None, :]                          # [1, 1, Sk]
+            sq = q.shape[1]
+            offset = sk - sq
+            if fm is None and w + 1 - offset >= sq:
+                # column 0 masks no row, so no column does: the window
+                # cannot bind at these lengths and the call is plain
+                # causal (the resident forward, no bounds to stream)
+                _DISPATCH["window_as_causal"] += 1
+            else:
+                win_rows = jnp.maximum(
+                    jnp.arange(sk, dtype=jnp.int32) + w + 1 - offset, 0
+                )[None, None, :]                      # [1, 1, Sk]
     imax = jnp.iinfo(jnp.int32).max
     if win_rows is not None:
         # compose (round 5): the window is one more masked row band per

@@ -108,3 +108,61 @@ class TestSlidingWindow:
         dense = P.to_tensor(np.zeros((1, 1, 8, 8), np.float32))
         with pytest.raises(NotImplementedError, match="dense"):
             m(ids, attn_mask=dense)
+
+
+class TestWindowAgainstLength:
+    """The dispatch reads the window against the static lengths: one
+    that no row can reach the edge of is the plain causal call (the
+    resident kernel, no FlashMask bounds), one row short of that it
+    binds and takes the FlashMask kernels. Kernels interpreted."""
+
+    S = 128
+
+    @pytest.fixture
+    def models(self, monkeypatch):
+        import paddle_tpu.ops.pallas.flash_attention as fa
+        monkeypatch.setattr(fa, "_FORCE_INTERPRET", True)
+        kw = dict(hidden_size=256, num_key_value_heads=2,
+                  num_hidden_layers=1)
+
+        def build(**more):
+            P.seed(0)
+            m = LlamaForCausalLM(LlamaConfig.tiny(**kw, **more))
+            m.eval()
+            return m
+        ids = P.to_tensor(np.random.default_rng(5).integers(
+            0, 256, (1, self.S)).astype(np.int32))
+        return fa, build, ids
+
+    def test_window_of_the_length_is_the_causal_call(self, models):
+        fa, build, ids = models
+        causal = build()
+        fa.reset_dispatch_stats()
+        want = np.asarray(causal(ids)._data)
+        assert fa.dispatch_stats()["window_as_causal"] == 0
+        windowed = build(sliding_window=self.S)   # window_size = s - 1
+        windowed.set_state_dict(causal.state_dict())
+        fa.reset_dispatch_stats()
+        got = np.asarray(windowed(ids)._data)
+        stats = fa.dispatch_stats()
+        assert stats["window_as_causal"] == 1, stats
+        assert stats["streamed"] == 0 and stats["resident"] == 1, stats
+        assert stats["fallback"] == 0, stats
+        np.testing.assert_array_equal(got, want)
+
+    def test_window_one_short_of_the_length_binds(self, models):
+        fa, build, ids = models
+        windowed = build(sliding_window=self.S - 1)  # window_size = s - 2
+        fa.reset_dispatch_stats()
+        got = np.asarray(windowed(ids)._data)
+        stats = fa.dispatch_stats()
+        assert stats["window_as_causal"] == 0, stats
+        assert stats["streamed"] == 1 and stats["resident"] == 0, stats
+        oracle = build(use_flash_attention=False)
+        oracle.set_state_dict(windowed.state_dict())
+        ref = np.asarray(oracle(ids, attn_mask=P.to_tensor(
+            _band(self.S, self.S - 1)[None, None]))._data)
+        np.testing.assert_allclose(got, ref, atol=3e-4, rtol=1e-3)
+        # the one masked link (row s-1, column 0) is load-bearing
+        full = np.asarray(oracle(ids)._data)
+        assert np.abs(full[:, -1] - ref[:, -1]).max() > 1e-6

@@ -813,3 +813,87 @@ class TestFlashMask:
         out = fa_forward(q, k, v, causal=True, interpret=True,
                          fm_start=s_, fm_end=e_)
         assert np.allclose(np.asarray(out), 0.0)
+
+
+def _tile_case(variant, dtype, seed=11):
+    """(q, k, v, g, kernel kwargs, reference mask) of one variant of the
+    tile tests: [1, S, 4 | 1, 64] at S = 1024 (two 512 tiles a side)."""
+    from paddle_tpu.ops.pallas.flash_attention import (
+        _fm_dense_mask, _seg_additive_mask)
+    s, h, d = 1024, 4, 64
+    sq = 512 if variant == "cross_length" else s
+    hkv = 1 if variant in ("gqa", "cross_length") else h
+    rng = np.random.default_rng(seed)
+
+    def rand(*shape):
+        return jnp.asarray(rng.standard_normal(shape).astype(np.float32),
+                           dtype)
+    q, g = rand(1, sq, h, d), rand(1, sq, h, d)
+    k, v = rand(1, s, hkv, d), rand(1, s, hkv, d)
+    kw, mask = {}, None
+    if variant == "window":          # 300 keys before self: it binds
+        start = (jnp.arange(s, dtype=jnp.int32) + 301)[None, None]
+        end = jnp.full_like(start, jnp.iinfo(jnp.int32).max)
+        kw = dict(fm_start=start, fm_end=end)
+        mask = _fm_dense_mask(start, end, sq)
+    elif variant == "segments":
+        seg = jnp.asarray(np.searchsorted([300, 700], np.arange(s),
+                                          side="right")[None], jnp.int32)
+        kw = dict(q_seg=seg, kv_seg=seg)
+        mask = _seg_additive_mask(seg, seg)
+    return q, k, v, g, kw, mask
+
+
+def _fwd_bwd(q, k, v, g, kw, tile):
+    from paddle_tpu.ops.pallas._fa_kernel import fa_backward
+    out, lse = fa_forward(q, k, v, causal=True, interpret=True,
+                          return_lse=True, block_q=tile, block_k=tile,
+                          **kw)
+    grads = fa_backward(q, k, v, out, lse, g, causal=True, interpret=True,
+                        block_q=tile, block_k=tile, **kw)
+    return (out, *grads)
+
+
+_VARIANTS = ["causal", "gqa", "window", "segments", "cross_length"]
+
+
+class TestStoredDtypeTiles:
+    """The kernels feed the MXU the dtype they are given, in tiles of
+    128 / 256 / 512: forward, dq and dk/dv against the XLA reference."""
+
+    @pytest.mark.parametrize("tile", [128, 256, 512])
+    @pytest.mark.parametrize("variant", _VARIANTS)
+    def test_bf16_matches_reference(self, variant, tile):
+        import jax
+        q, k, v, g, kw, mask = _tile_case(variant, jnp.bfloat16)
+        got = _fwd_bwd(q, k, v, g, kw, tile)
+        assert [x.dtype for x in got] == [jnp.bfloat16] * 4
+        f32 = [x.astype(jnp.float32) for x in (q, k, v, g)]
+        want, vjp = jax.vjp(lambda a, b_, c: _attention_ref(
+            a, b_, c, mask=mask, causal=True), *f32[:3])
+        for name, a, b_ in zip(("out", "dq", "dk", "dv"), got,
+                               (want, *vjp(f32[3]))):
+            b_ = np.asarray(b_)
+            err = np.abs(np.asarray(a, np.float32) - b_).max()
+            # bf16 keeps 8 bits (eps 3.9e-3): the stored result and the
+            # p / ds operands each round once; read 3.3-4.6e-3 of the scale
+            assert err < 8e-3 * np.abs(b_).max(), (name, err)
+
+    @pytest.mark.parametrize("variant", _VARIANTS)
+    def test_tile_size_leaves_f32_results_alone(self, variant):
+        q, k, v, g, kw, _ = _tile_case(variant, jnp.float32)
+        small = _fwd_bwd(q, k, v, g, kw, 128)
+        large = _fwd_bwd(q, k, v, g, kw, 512)
+        for name, a, b_ in zip(("out", "dq", "dk", "dv"), small, large):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       atol=2e-5, rtol=2e-5, err_msg=name)
+
+    @pytest.mark.parametrize("seq,tile", [(4096, 512), (768, 256),
+                                          (640, 128), (64, 64)])
+    def test_default_tile_follows_the_length(self, seq, tile, monkeypatch):
+        from paddle_tpu.ops.pallas import _fa_kernel
+        for name in ("PADDLE_TPU_FA_BLOCK_Q", "PADDLE_TPU_FA_BWD_BLOCK_K"):
+            monkeypatch.delenv(name, raising=False)
+            assert _fa_kernel._env_block(name, seq) == tile
+        monkeypatch.setenv("PADDLE_TPU_FA_BLOCK_Q", "128")
+        assert _fa_kernel._env_block("PADDLE_TPU_FA_BLOCK_Q", seq) == 128
