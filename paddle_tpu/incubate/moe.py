@@ -15,12 +15,13 @@ API.
 """
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
 import jax.numpy as jnp
 
-from ..core.autograd import apply
+from ..core.autograd import apply, mark_stable
 from ..core.tensor import Tensor
 from ..nn import functional as F
 from ..nn import initializer as I
@@ -327,3 +328,152 @@ class MoELayer(Layer):
         if squeeze:
             out = out.squeeze(0)
         return out
+
+
+# -- dropless routing over the experts held here ---------------------------
+
+def dropless_route(y, w_router, bias, top_k, scale, norm_topk_prob=True):
+    """The ``noaux_tc`` sigmoid router without group limits, in float32:
+    ``s = sigmoid(y W_r)``; the ``top_k`` of ``s + bias`` are chosen (the
+    correction bias moves the choice, never the weight); the weights are
+    the chosen ``s``, normalised to sum 1 where asked, times ``scale``.
+    y [T, H] -> (expert ids [T, k] int32, weights [T, k] float32)."""
+    s = jax.nn.sigmoid(jnp.matmul(y.astype(jnp.float32),
+                                  w_router.astype(jnp.float32)))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    g = jnp.take_along_axis(s, idx, -1)
+    if norm_topk_prob:
+        g = g / (jnp.sum(g, -1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), g * scale
+
+
+def dropless_experts(y, idx, gates, w_gate, w_up, w_down, first=0):
+    """``sum_i g_i E_i(y)`` over the experts held here (global ids
+    ``first .. first + count - 1``; ``w_*`` are their stacks), each a
+    SwiGLU. No capacity, no drop: every held expert runs over every
+    token, weighted by a gate that is zero where the token was not
+    routed to it -- at serving's token counts the layer is bound by
+    reading the expert weights once, which this does. Both products
+    accumulate in float32; the down product contracts experts and width
+    together. y [T, H] -> [T, H] in y's dtype."""
+    count = w_gate.shape[0]
+    dense = jnp.sum(jax.nn.one_hot(idx - first, count, dtype=jnp.float32)
+                    * gates[..., None], axis=1)               # [T, count]
+    h1 = jnp.einsum("th,ehf->etf", y, w_gate,
+                    preferred_element_type=jnp.float32)
+    h2 = jnp.einsum("th,ehf->etf", y, w_up,
+                    preferred_element_type=jnp.float32)
+    a = jax.nn.silu(h1) * h2 * dense.T[:, :, None]
+    out = jnp.einsum("etf,efh->th", a.astype(w_down.dtype), w_down,
+                     preferred_element_type=jnp.float32)
+    return out.astype(y.dtype)
+
+
+def routing_counts(idx, first, count, valid=None):
+    """int32 [4] for one layer-step: assignments to the experts held
+    here, distinct held experts with a token, the largest count on one
+    of them, and 1 (the layer-step itself). ``valid`` [T] leaves padding
+    tokens out."""
+    hot = jax.nn.one_hot(idx - first, count, dtype=jnp.int32)  # [T,k,count]
+    if valid is not None:
+        hot = hot * valid.astype(jnp.int32)[:, None, None]
+    load = jnp.sum(hot, axis=(0, 1))
+    return jnp.stack([jnp.sum(load), jnp.sum(load > 0), jnp.max(load),
+                      jnp.int32(1)]).astype(jnp.int32)
+
+
+@functools.lru_cache(maxsize=32)
+def _dropless_fn(top_k, scale, norm, first):
+    def f(y, w_router, bias, w_gate, w_up, w_down):
+        shape = y.shape
+        y = y.reshape(-1, shape[-1])
+        idx, gates = dropless_route(y, w_router, bias, top_k, scale, norm)
+        return dropless_experts(y, idx, gates, w_gate, w_up, w_down,
+                                first).reshape(shape)
+    return mark_stable(f)
+
+
+class SwiGLU(Layer):
+    """``down(silu(gate(x)) * up(x))``, no biases."""
+
+    def __init__(self, hidden, width):
+        super().__init__()
+        from ..nn import Linear
+        self.gate_proj = Linear(hidden, width, bias_attr=False)
+        self.up_proj = Linear(hidden, width, bias_attr=False)
+        self.down_proj = Linear(width, hidden, bias_attr=False)
+
+    def forward(self, x):
+        from .nn.functional import swiglu
+        return self.down_proj(swiglu(self.gate_proj(x), self.up_proj(x)))
+
+
+class DroplessMoE(Layer):
+    """A sparse-expert layer as one chip's share of it: it is told which
+    routed experts it holds (``experts_held=(first, count)``, default
+    all), routes every token over all ``num_experts`` with the published
+    router (:func:`dropless_route`), and returns the part of
+    ``sum_i g_i E_i(y)`` that its own experts give plus, where
+    ``shared_width`` is set, the shared expert ``E_shared(y)``. The
+    shares of a layer, the shared expert counted once, add up to the
+    whole layer. On one chip it runs without an exchange; the exchange
+    over chips is not built (ROADMAP R1).
+
+    Parameters: ``router.weight`` [H, E], ``e_score_correction_bias`` [E],
+    ``w_gate`` / ``w_up`` [count, H, F], ``w_down`` [count, F, H],
+    ``shared_experts.{gate,up,down}_proj``."""
+
+    def __init__(self, d_model, d_expert, num_experts, top_k, *,
+                 routed_scaling_factor=1.0, norm_topk_prob=True,
+                 shared_width=0, experts_held=None):
+        super().__init__()
+        from ..nn import Linear
+        first, count = experts_held or (0, num_experts)
+        if not (0 <= first and count >= 1
+                and first + count <= num_experts):
+            raise ValueError(
+                f"experts_held={experts_held!r} is not a range of the "
+                f"{num_experts} routed experts")
+        if top_k > num_experts:
+            raise ValueError(f"top_k={top_k} > num_experts={num_experts}")
+        self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.first, self.count = int(first), int(count)
+        self.scale = float(routed_scaling_factor)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.router = Linear(d_model, num_experts, bias_attr=False)
+        self.e_score_correction_bias = self.create_parameter(
+            (num_experts,), default_initializer=I.Constant(0.0))
+        init = I.Normal(0.0, 0.02)
+        self.w_gate = self.create_parameter((count, d_model, d_expert),
+                                            default_initializer=init)
+        self.w_up = self.create_parameter((count, d_model, d_expert),
+                                          default_initializer=init)
+        self.w_down = self.create_parameter((count, d_expert, d_model),
+                                            default_initializer=init)
+        self.shared_experts = (SwiGLU(d_model, shared_width)
+                               if shared_width else None)
+
+    def _operands(self):
+        return (self.router.weight, self.e_score_correction_bias,
+                self.w_gate, self.w_up, self.w_down)
+
+    def forward(self, y):
+        out = apply(_dropless_fn(self.top_k, self.scale,
+                                 self.norm_topk_prob, self.first),
+                    y, *self._operands(), name="dropless_moe")
+        if self.shared_experts is not None:
+            out = out + self.shared_experts(y)
+        return out
+
+    def forward_counted(self, y, valid=None):
+        """As :meth:`forward`, also returning :func:`routing_counts` of
+        the step (the serving engine's call, inside its compiled step:
+        the counts stay on the device, and the compiler folds this
+        second routing into the forward's)."""
+        ya = y._data
+        idx, _ = dropless_route(
+            ya.reshape(-1, ya.shape[-1]), self.router.weight._data,
+            self.e_score_correction_bias._data, self.top_k, self.scale,
+            self.norm_topk_prob)
+        return self.forward(y), routing_counts(idx, self.first,
+                                               self.count, valid)

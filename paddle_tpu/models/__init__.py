@@ -7,6 +7,8 @@ from .llama import (LlamaConfig, LlamaForCausalLM,  # noqa: F401
                     LlamaForCausalLMPipe, LlamaModel,
                     LlamaPretrainingCriterion, count_params,
                     flops_per_token)
+from .latent_moe import (LatentMoEConfig,  # noqa: F401
+                         LatentMoEForCausalLM)
 from .t5 import (T5Config, T5ForConditionalGeneration,  # noqa: F401
                  T5Model)
 from .whisper import (WhisperConfig, WhisperModel,  # noqa: F401

@@ -136,15 +136,33 @@ class ServingEngine:
         for attr in ("embed_tokens", "layers", "norm"):
             if not hasattr(core, attr):
                 raise TypeError(
-                    "ServingEngine needs a LLaMA-family causal LM "
-                    "(model.llama or a core module with embed_tokens/"
-                    f"layers/norm); {what} {type(model).__name__} "
+                    "ServingEngine needs a causal LM whose core "
+                    "(model.llama, or the model itself) has "
+                    "embed_tokens/layers/norm, the layers either "
+                    "LLaMA-shaped (self_attn.q_proj/k_proj/v_proj/"
+                    "o_proj + mlp) or bringing their own "
+                    f"paged_forward; {what} {type(model).__name__} "
                     f"lacks {attr!r}")
         if not hasattr(model, "lm_head"):
             raise TypeError(f"{what} must expose lm_head")
         if cfg is None:
             raise TypeError(f"{what} must carry a .cfg")
         return cfg, core
+
+    @staticmethod
+    def _cache_geometry(cfg, core):
+        """What one token holds in a layer's cache: ``(n_kv_heads,
+        head_dim, latent_dim)``. A model whose layers bring a
+        ``paged_forward`` over a latent pool says so through
+        ``paged_latent_dim`` (one entry of that width a token a layer,
+        shared by every head); a LLaMA-shaped one holds K and V by
+        head."""
+        latent = getattr(core.layers[0], "paged_latent_dim", None)
+        if latent is not None:
+            return 1, int(latent), int(latent)
+        nh = cfg.num_attention_heads
+        nkv = getattr(cfg, "num_key_value_heads", None) or nh
+        return nkv, cfg.hidden_size // nh, None
 
     @staticmethod
     def _resolve_cache_dtype(cache_dtype, cfg):
@@ -202,8 +220,7 @@ class ServingEngine:
         self.model = model
         self._core = core
         nh = cfg.num_attention_heads
-        nkv = getattr(cfg, "num_key_value_heads", None) or nh
-        hd = cfg.hidden_size // nh
+        nkv, hd, latent_dim = self._cache_geometry(cfg, core)
         self.max_seq_len = int(max_seq_len
                                or cfg.max_position_embeddings)
         maxpos = getattr(cfg, "max_position_embeddings", None)
@@ -219,6 +236,10 @@ class ServingEngine:
         # per-shard q/kv slices would be ragged (loud at build time,
         # never silently at step time)
         self._tp = resolve_tp(mesh=mesh, tp_degree=tp_degree)
+        if self._tp is not None and latent_dim is not None:
+            raise NotImplementedError(
+                "tensor parallelism (tp_degree > 1 / mesh) over a latent "
+                "page pool is not built: one entry serves every head")
         if self._tp is not None and (nh % self._tp.degree
                                      or nkv % self._tp.degree):
             raise ValueError(
@@ -237,7 +258,7 @@ class ServingEngine:
             hbm_budget_bytes=(int(hbm_budget_mb * 2 ** 20)
                               if hbm_budget_mb is not None else None),
             dtype=cache_dtype, prefix_cache=bool(prefix_cache),
-            tp_degree=self.tp_degree)
+            tp_degree=self.tp_degree, latent_dim=latent_dim)
         self.max_pages_per_seq = math.ceil(
             self.max_seq_len / self.cache.page_size)
         # where the pools actually live (advertised in /healthz): a
@@ -267,8 +288,13 @@ class ServingEngine:
             self._draft_core = dcore
             self._draft_window = getattr(dcfg, "sliding_window",
                                          None) or None
-            dnh = dcfg.num_attention_heads
-            dnkv = getattr(dcfg, "num_key_value_heads", None) or dnh
+            dnkv, dhd, dlatent = self._cache_geometry(dcfg, dcore)
+            if latent_dim is not None or dlatent is not None:
+                # the cache refuses the rest by name (int8, tp_degree,
+                # kvtier, page shipping); a draft is the engine's
+                raise NotImplementedError(
+                    "a draft model (speculative decoding) beside a "
+                    "latent page pool is not built")
             # same page geometry/count as the target (token-capacity
             # parity), narrow per-page bytes (the draft is the cheap
             # model); no prefix cache — draft K/V is disposable state.
@@ -276,8 +302,7 @@ class ServingEngine:
             # a duplicated bf16-or-f32 decision here once let draft and
             # target caches silently diverge (regression-tested).
             self._draft_cache = PagedKVCache(
-                dcfg.num_hidden_layers, dnkv,
-                dcfg.hidden_size // dnh, page_size=page_size,
+                dcfg.num_hidden_layers, dnkv, dhd, page_size=page_size,
                 num_pages=self.cache.num_pages,
                 dtype=self.cache_dtype)
         else:
@@ -335,6 +360,10 @@ class ServingEngine:
         # per-page cost so a scrape can verify the sizing
         self.metrics.kv_page_bytes.set(self.cache.bytes_total
                                        / self.cache.num_pages)
+        self.metrics.cache_bytes_per_token.set(self.cache.bytes_per_token)
+        # routing counts of sparse-expert layers, summed on the device
+        # by the ragged step and fetched with its tokens
+        self._moe_counts_dev = None
         self.eos = eos_token_id
         self.window = getattr(cfg, "sliding_window", None) or None
         self._step_fn = None          # one jit fn; traces per bucket
@@ -1658,6 +1687,7 @@ class ServingEngine:
             lps = np.asarray(lp_d, np.float32)
             self.metrics.fetch_bytes.inc(toks.nbytes + lps.nbytes)
             self.metrics.step_fetches.inc()
+        experts_hit = self._record_moe_counts()
         # 8. host-side per-lane processing, bucketed event order:
         # verify lanes, plain lanes, then the prefill completion
         accepted = 0
@@ -1731,7 +1761,8 @@ class ServingEngine:
                 "ragged_step", tokens=int(n_tok), cap=int(tcap),
                 lanes=int(lane), spec=len(emit_spec),
                 plain=len(emit_plain),
-                prefill=(pf[0].req_id if pf is not None else None))
+                prefill=(pf[0].req_id if pf is not None else None),
+                experts_hit=experts_hit)
 
     # -- KV page migration (disaggregated serving, round 14) ---------------
     def export_request(self, req_id, skip_pages=0):
@@ -2067,6 +2098,19 @@ class ServingEngine:
         self.metrics.step_fetches.inc()
         return out
 
+    def _record_moe_counts(self):
+        """The last ragged step's routing counts into the metrics; the
+        16 bytes come with the step's tokens (the program has finished
+        by then: no further wait). Returns the experts hit, None for a
+        model without sparse experts."""
+        if self._moe_counts_dev is None:
+            return None
+        counts = np.asarray(self._moe_counts_dev)
+        self._moe_counts_dev = None
+        self.metrics.fetch_bytes.inc(counts.nbytes)
+        self.metrics.record_moe_counts(counts)
+        return int(counts[1])
+
     def _sync_prefix_metrics(self):
         c, m = self.cache, self.metrics
         m.prefix_hit_pages.value = c.prefix_hit_pages
@@ -2177,13 +2221,14 @@ class ServingEngine:
                                   self._core, self.window, self._tp))
         warrs = [t._data for t in self.model._gen_state_tensors()]
         k_ops, v_ops = self.cache.program_operands()
-        tok, lp, logits, k_pages, v_pages = self._ragged_fn(
+        tok, lp, logits, k_pages, v_pages, moe_counts = self._ragged_fn(
             warrs, jnp.asarray(ids), jnp.asarray(positions),
             jnp.asarray(pt), jnp.asarray(cl), jnp.asarray(ql),
             jnp.asarray(qoff), jnp.asarray(slot_map),
             tuple(jnp.asarray(a) for a in samp), k_ops, v_ops)
         self.cache.store_operands(k_pages, v_pages)
         self._logits_dev = logits          # [T, V], fetched on demand
+        self._moe_counts_dev = moe_counts  # fetched with the tokens
         self._count_dispatch(("ragged", ids.shape[1]))
         return tok, lp
 
@@ -2226,7 +2271,7 @@ def _paged_step_pure(model, core, window, tp, sample_capable,
 
 
 def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
-                   k_pages, v_pages, ragged=None, tp=None):
+                   k_pages, v_pages, ragged=None, tp=None, stats=None):
     """The transformer trunk over the paged cache: embed, attend (K/V
     scattered into the page pool), final norm. Shared by the target
     step program, the draft catchup step, the draft proposal scan, and
@@ -2246,7 +2291,15 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
     shard-local.  The MLP is inlined under TP because
     ``layer.mlp(...)`` offers no hook to replicate the swiglu output
     before down_proj's contraction — the inline mirrors
-    ``down_proj(swiglu(gate_proj(x), up_proj(x)))`` exactly."""
+    ``down_proj(swiglu(gate_proj(x), up_proj(x)))`` exactly.
+
+    A layer that brings its own ``paged_forward`` (latent attention over
+    a latent page pool, sparse experts: ``models/latent_moe.py``) is
+    asked for it, with each packed token's page-table row, visible keys
+    and validity; ``v_pages`` is then empty and ``new_v`` comes back
+    empty. ``stats``, a list, receives such layers' routing counts.
+    LLaMA-shaped layers run the code below, as before (two paged
+    forwards until D1 gives the block one definition)."""
     from ..core.autograd import no_grad
     from ..core.tensor import Tensor
     from ..incubate.nn.functional import (
@@ -2267,6 +2320,14 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
             x = Tensor(tp.replicate(x._data))
         pos_t = Tensor(positions)
         new_k, new_v = [], []
+        if hasattr(core.layers[0], "paged_forward"):
+            per_tok = _per_token_tables(b, s, positions, pt, cl, ragged)
+            for layer, pool in zip(core.layers, k_pages):
+                x, pool = layer.paged_forward(x, positions, pool,
+                                              flat_slots, *per_tok,
+                                              stats=stats)
+                new_k.append(pool)
+            return core.norm(x)._data, new_k, new_v
         for layer, kp, vp in zip(core.layers, k_pages, v_pages):
             at = layer.self_attn
             nh, nkv, hd = at.num_heads, at.num_kv_heads, at.head_dim
@@ -2352,6 +2413,25 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
     return x._data, new_k, new_v
 
 
+def _per_token_tables(b, s, positions, pt, cl, ragged):
+    """``(pt_tok [b*s, P], cl_tok [b*s], valid [b*s])``: each packed
+    token's page-table row, the keys it may see and whether it is a real
+    token -- what a layer's own ``paged_forward`` attends with. The
+    rectangular [B, S] step is the ragged one with S tokens a lane."""
+    import jax.numpy as jnp
+
+    from .attention import _token_lanes
+    if ragged is None:
+        return (jnp.repeat(pt, s, axis=0),
+                jnp.repeat(cl.astype(jnp.int32), s),
+                jnp.ones((b * s,), jnp.bool_))
+    ql, qoff = ragged
+    lane, _ = _token_lanes(ql, qoff, b * s)
+    valid = jnp.arange(b * s, dtype=jnp.int32) < jnp.sum(
+        ql.astype(jnp.int32))
+    return pt[lane], cl[lane].astype(jnp.int32), valid
+
+
 def _paged_step_body(model, core, window, tp, sample_capable,
                      multi_pos, ids, positions, pt, cl, slot_map,
                      last_idx, samp, k_pages, v_pages):
@@ -2434,9 +2514,14 @@ def _ragged_step_body(model, core, window, tp, ids, positions, pt, cl,
     from ..core.autograd import no_grad
     from ..core.tensor import Tensor
 
+    stats = []
     x, new_k, new_v = _paged_forward(core, window, ids, positions, pt,
                                      cl, slot_map, k_pages, v_pages,
-                                     ragged=(ql, qoff), tp=tp)
+                                     ragged=(ql, qoff), tp=tp,
+                                     stats=stats)
+    # sparse-expert layers' routing counts of this step, summed over
+    # layers: int32 [4] (MOE_COUNTS), None for a model without them
+    moe_counts = sum(stats[1:], stats[0]) if stats else None
     from .sampling import fused_sample
     do_sample, temperature, top_k, top_p, seeds, steps = samp
     with no_grad():
@@ -2449,7 +2534,7 @@ def _ragged_step_body(model, core, window, tp, ids, positions, pt, cl,
     tokens, logprobs = fused_sample(
         logits, do_sample, temperature, top_k, top_p, seeds, steps,
         sample_capable=True)
-    return tokens, logprobs, logits, new_k, new_v
+    return tokens, logprobs, logits, new_k, new_v, moe_counts
 
 
 # -- the fused draft-proposal scan (speculative decoding, round 12) --------

@@ -135,10 +135,29 @@ class PagedKVCache:
 
     def __init__(self, n_layers, n_kv_heads, head_dim, *, page_size=16,
                  num_pages=None, hbm_budget_bytes=None, dtype="float32",
-                 prefix_cache=False, tp_degree=1):
+                 prefix_cache=False, tp_degree=1, latent_dim=None):
         import jax.numpy as jnp
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
+        # latent geometry (MLA): ONE pool a layer, [num_pages, page_size,
+        # latent_dim] -- a token's compressed entry, shared by every
+        # head -- held in ``k_pages``; ``v_pages`` is empty. Allocation,
+        # page tables, copy-on-write and the prefix tree work on pages
+        # and do not care. What moves page BYTES elsewhere (migration,
+        # the tiers, an int8 layout, head-sharded pools) is not built
+        # for it and refuses by name.
+        self.latent = latent_dim is not None
+        if self.latent:
+            n_kv_heads, head_dim = 1, int(latent_dim)
+            if str(jnp.dtype(dtype)) == "int8":
+                raise NotImplementedError(
+                    "an int8 latent cache is not built: the latent page "
+                    "pool holds its entries in a float dtype")
+            if int(tp_degree or 1) > 1:
+                raise NotImplementedError(
+                    "a latent page pool under tensor parallelism "
+                    "(tp_degree > 1) is not built: one entry serves "
+                    "every head")
         self.n_layers = int(n_layers)
         self.n_kv_heads = int(n_kv_heads)
         self.head_dim = int(head_dim)
@@ -161,7 +180,8 @@ class PagedKVCache:
                 "or 'int8' (quantized codes + scales)")
         self.quantized = str(self.dtype) == "int8"
         per_page = self.page_bytes_per_page(
-            n_layers, n_kv_heads, head_dim, page_size, self.dtype)
+            n_layers, n_kv_heads, head_dim, page_size, self.dtype,
+            latent=self.latent)
         if num_pages is None:
             if hbm_budget_bytes is None:
                 raise ValueError(
@@ -177,11 +197,13 @@ class PagedKVCache:
         self.num_pages = num_pages
         self.bytes_total = num_pages * per_page
         # device buffers: per layer, [num_pages, page_size, n_kv, hd]
-        shape = (num_pages, self.page_size, self.n_kv_heads, self.head_dim)
+        shape = ((num_pages, self.page_size, self.head_dim) if self.latent
+                 else (num_pages, self.page_size, self.n_kv_heads,
+                       self.head_dim))
         self.k_pages = [jnp.zeros(shape, self.dtype)
                         for _ in range(self.n_layers)]
-        self.v_pages = [jnp.zeros(shape, self.dtype)
-                        for _ in range(self.n_layers)]
+        self.v_pages = [] if self.latent else [
+            jnp.zeros(shape, self.dtype) for _ in range(self.n_layers)]
         if self.quantized:
             sshape = (num_pages, self.page_size, self.n_kv_heads)
             self.k_scales = [jnp.zeros(sshape, jnp.float32)
@@ -219,23 +241,39 @@ class PagedKVCache:
     def attach_tier(self, tier):
         """Bind a :class:`~.kvtier.KVTier` so prefix-cache evictions
         spill to the host tier.  ``None`` detaches."""
+        if tier is not None:
+            self._refuse_latent("kvtier (host/disk page tiers)")
         self._tier = tier
+
+    def _refuse_latent(self, what):
+        if self.latent:
+            raise NotImplementedError(
+                f"{what} is not built for the latent page pool: its "
+                "payload format is K and V by head")
 
     # -- sizing helpers ---------------------------------------------------
     @staticmethod
     def page_bytes_per_page(n_layers, n_kv_heads, head_dim, page_size,
-                            dtype):
-        """Bytes one page costs across every layer's K and V buffers.
-        int8 pages carry their f32 scale rows (4 bytes per slot per kv
-        head, K and V each) so ``hbm_budget_bytes`` sizing honestly
-        reflects the quantized capacity."""
+                            dtype, latent=False):
+        """Bytes one page costs across every layer's K and V buffers
+        (``latent``: across every layer's one pool of ``head_dim``-wide
+        entries). int8 pages carry their f32 scale rows (4 bytes per
+        slot per kv head, K and V each) so ``hbm_budget_bytes`` sizing
+        honestly reflects the quantized capacity."""
         import jax.numpy as jnp
         dt = jnp.dtype(dtype)
         per_slot_head = int(head_dim) * dt.itemsize
+        if latent:
+            return int(n_layers) * int(page_size) * per_slot_head
         if str(dt) == "int8":
             per_slot_head += 4  # the float32 absmax scale
         return (2 * int(n_layers) * int(page_size) * int(n_kv_heads)
                 * per_slot_head)
+
+    @property
+    def bytes_per_token(self):
+        """What one cached token costs across every layer."""
+        return self.bytes_total // (self.num_pages * self.page_size)
 
     def pages_for(self, n_tokens):
         """Pages a sequence of n_tokens occupies."""
@@ -637,6 +675,7 @@ class PagedKVCache:
         :class:`GeometryMismatch` up front — the router/disagg
         re-prefill fallback covers it.
         """
+        self._refuse_latent("pagewire / disagg page shipping (export_pages)")
         if seq_id not in self._tables:
             raise KeyError(f"export_pages: unknown sequence {seq_id!r}")
         table = self._tables[seq_id]
@@ -671,6 +710,7 @@ class PagedKVCache:
         when free + reclaimable pages cannot host the suffix.  All
         failures roll back fully (no sequence state left behind).
         """
+        self._refuse_latent("pagewire / disagg page shipping (import_pages)")
         self.check_geometry(meta)
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id!r} already allocated")
@@ -885,6 +925,8 @@ class PagedKVCache:
         "prefix"`` and ``meta["prompt"]`` holding the FULL matched
         token prefix (skipped pages included, so the importer can walk
         its own tree from the root)."""
+        self._refuse_latent(
+            "pagewire / disagg page shipping (export_prefix_pages)")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         chain = self._walk(prompt, len(prompt) // self.page_size)
         matched = len(chain)
@@ -922,6 +964,8 @@ class PagedKVCache:
         :class:`OutOfPages` when the suffix cannot be hosted.  All
         failures roll back fully.  Returns the number of pages
         imported."""
+        self._refuse_latent(
+            "pagewire / disagg page shipping (import_prefix_pages)")
         if not self.prefix_cache_enabled:
             raise GeometryMismatch(
                 "prefix ship into a cache with prefix_cache disabled: "

@@ -241,6 +241,26 @@ class ServingMetrics:
         self.weight_version_target = Gauge()
         self.weight_version_draft = Gauge()
         self.distill_pairs = Counter()        # verify pairs logged
+        # sparse-expert layers in the ragged step: counted on the device
+        # over a step's real tokens, summed over the layers
+        # (incubate/moe.py::routing_counts), fetched with its tokens
+        self.moe_assignments = Counter()      # tokens x top-k x layers
+        self.moe_experts_hit = Counter()      # distinct experts with a
+        #                                       token, per layer-step
+        self.moe_expert_load_max = Counter()  # the fullest expert's
+        #                                       tokens, per layer-step
+        self.moe_layer_steps = Counter()      # expert layers x steps
+        # what one cached token costs across every layer (a latent pool:
+        # (rank + rope) x itemsize x layers; K and V by head otherwise)
+        self.cache_bytes_per_token = Gauge()
+
+    # the order incubate/moe.py::routing_counts packs its int32 [4] in
+    MOE_COUNTS = ("moe_assignments", "moe_experts_hit",
+                  "moe_expert_load_max", "moe_layer_steps")
+
+    def record_moe_counts(self, counts):
+        for name, n in zip(self.MOE_COUNTS, counts):
+            getattr(self, name).inc(int(n))
 
     def export(self):
         return {name: m.export() for name, m in vars(self).items()}
