@@ -107,6 +107,7 @@ from .kv_cache import (SCRATCH_PAGE, GeometryMismatch, OutOfPages,
                        PagedKVCache)
 from .kvtier import KVTier, host_pool_from_env
 from .metrics import ServingMetrics
+from .sampling import filter_binds
 from .scheduler import Request, RequestState, Scheduler
 from .tp import resolve_tp
 from .trace import ServingTrace
@@ -1650,16 +1651,20 @@ class ServingEngine:
             b["positions"][0, sl] = start + np.arange(n,
                                                       dtype=np.int32)
             b["slot_map"][0, sl] = pslots
-            # only the chunk's LAST token's sample is ever consumed
-            # (at prefill completion); earlier tokens keep the neutral
-            # params and their greedy output is discarded
+            # only the chunk's LAST token's sample is ever consumed,
+            # and only at prefill completion: it takes the request's
+            # params in the prompt's last chunk alone, so an earlier
+            # chunk asks the sampler for no sort and no draw; every
+            # other token keeps the neutral params and its greedy
+            # output is discarded
             pf_off = off + n - 1
-            b["do_sample"][pf_off] = req.do_sample
-            b["temperature"][pf_off] = req.temperature
-            b["top_k"][pf_off] = req.top_k
-            b["top_p"][pf_off] = req.top_p
-            b["seeds"][pf_off] = req.device_seed
-            b["steps"][pf_off] = len(req.out_tokens)
+            if end >= req.prompt.size + len(req.out_tokens):
+                b["do_sample"][pf_off] = req.do_sample
+                b["temperature"][pf_off] = req.temperature
+                b["top_k"][pf_off] = req.top_k
+                b["top_p"][pf_off] = req.top_p
+                b["seeds"][pf_off] = req.device_seed
+                b["steps"][pf_off] = len(req.out_tokens)
             lane += 1
             off += n
         # 7. ONE dispatch, ONE [T]+[T] host fetch
@@ -2146,6 +2151,16 @@ class ServingEngine:
             self.metrics.step_program_classes.set(
                 len(self._program_classes))
 
+    def _count_sort(self, samp):
+        """Count a target step of a sample-capable program in which the
+        sampler's sort ran: the condition ``sampling.fused_sample``
+        computes on the device, from the same arrays as the host packed
+        them (no fetch; a greedy batch costs one ``any``)."""
+        do_sample, _, top_k, top_p = samp[:4]
+        if do_sample.any() and np.any(do_sample & filter_binds(
+                top_k, top_p, self.model.cfg.vocab_size)):
+            self.metrics.sampler_sort_steps.inc()
+
     def _tp_kernel_guard(self):
         """The loud Pallas guard (round 23): a TP step must never
         trace ``pallas_call`` into the SPMD program (no GSPMD
@@ -2201,6 +2216,8 @@ class ServingEngine:
         self._logits_dev = logits  # NOT fetched on the decode hot path
         self._count_dispatch(("step", ids.shape, bool(multi_pos),
                               bool(sample_capable)))
+        if sample_capable:
+            self._count_sort(samp)
         return tok, lp
 
     def _run_ragged_step(self, ids, positions, pt, cl, ql, qoff,
@@ -2211,11 +2228,11 @@ class ServingEngine:
         if self._ragged_fn is None:
             # ONE jit fn; the token capacity in {small, mixed} bounds
             # its trace cache at two entries — the <= 2-program-class
-            # contract. The sampler is always compiled sample-capable:
-            # greedy lanes take the argmax/raw-logprob branch inside
-            # fused_sample, so pinning the static flag costs an unused
-            # sort, not exactness (and keeps greedy and sampled steps
-            # in the SAME class).
+            # contract. The sampler is always compiled sample-capable,
+            # so greedy and sampled steps share a class: a batch's sort
+            # and draw run under conditions on its own sampling
+            # arguments (sampling.py), and a greedy lane takes the
+            # argmax and the raw logprob either way.
             self._ragged_fn = jax.jit(
                 functools.partial(_ragged_step_pure, self.model,
                                   self._core, self.window, self._tp))
@@ -2230,6 +2247,7 @@ class ServingEngine:
         self._logits_dev = logits          # [T, V], fetched on demand
         self._moe_counts_dev = moe_counts  # fetched with the tokens
         self._count_dispatch(("ragged", ids.shape[1]))
+        self._count_sort(samp)
         return tok, lp
 
 
@@ -2503,12 +2521,14 @@ def _ragged_step_body(model, core, window, tp, ids, positions, pt, cl,
     """Token-packed unified step: the trunk runs at [1, T], lm_head +
     fused sampling cover EVERY packed token (each with its own
     per-token counter key — a verify token j carries steps0+j, exactly
-    fused_sample_multi's flattened key; a prefill chunk's non-final
-    tokens carry neutral params and their samples are discarded), and
-    the host fetch is [T] ids + [T] logprobs. Always compiled
-    sample-capable: greedy lanes take fused_sample's argmax/raw-logprob
-    branch, so values match the greedy-compiled bucketed programs
-    bit-for-bit while greedy and sampled steps share ONE class."""
+    fused_sample_multi's flattened key; a prefill chunk's tokens but
+    the prompt's last carry neutral params and their samples are
+    discarded), and the host fetch is [T] ids + [T] logprobs. Always
+    compiled sample-capable: a greedy lane takes fused_sample's argmax
+    and raw logprob, so values match the greedy-compiled bucketed
+    programs bit-for-bit while greedy and sampled steps share ONE
+    class; the sort and the draw run only in a step where a token asks
+    for them (sampling.py)."""
     import jax.numpy as jnp
 
     from ..core.autograd import no_grad

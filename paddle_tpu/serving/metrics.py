@@ -205,6 +205,10 @@ class ServingMetrics:
         self.step_dispatches = Counter()      # device dispatches issued
         self.step_fetches = Counter()         # host<-device fetches
         self.step_program_classes = Gauge()   # distinct compiled classes
+        # over step_dispatches: the target steps whose batch held a
+        # sampling row with a binding top-k or top-p (the sampler's
+        # sort ran), counted on the host from the arrays it packs
+        self.sampler_sort_steps = Counter()
         self.prefix_hit_pages = Counter()     # prompt pages served from
         self.prefix_miss_pages = Counter()    # the radix tree vs prefilled
         self.prefix_evictions = Counter()     # cached pages LRU-reclaimed

@@ -25,11 +25,19 @@ Design constraints (reproducibility rules):
   scaled logits: one argmax, no normalization, no [B, V] division —
   and a greedy lane is literally the same argmax without noise, which
   is what makes greedy device-vs-host parity token-exact.
-- ``sample_capable=False`` (a STATIC python flag at the engine's jit
-  boundary) compiles the greedy-only variant with no sort in it, so
-  an all-greedy decode batch — the common serving case — never pays
-  the top-k/top-p sort. The trace cache at most doubles (still
-  bounded by 2 * (log2(max_batch) + 2)).
+- A batch pays for what one of its lanes asks. The sample-capable
+  program holds the top-k/top-p sort (ONE, shared by both filters)
+  under ``lax.cond`` on "some sampling lane's filter binds", and the
+  Gumbel draw under ``lax.cond`` on "some lane samples" -- scalars of
+  the whole batch read from the per-lane arguments the program already
+  receives, so an all-greedy batch, the common serving case, runs
+  neither in the SAME compiled program a sampled batch uses. Nothing
+  may ``vmap`` over :func:`fused_sample`: a ``cond`` under ``vmap``
+  becomes a ``select`` and runs both branches.
+- ``sample_capable=False`` (a STATIC python flag at the bucketed
+  engine's jit boundary) still compiles the greedy-only variant with
+  no sort and no conditional in it. The trace cache at most doubles
+  (still bounded by 2 * (log2(max_batch) + 2)).
 
 Filter semantics match the host oracle (`engine._sample`, numpy):
 ``top_k <= 0`` or ``>= V`` disables top-k; ``top_p <= 0`` or ``>= 1``
@@ -39,10 +47,12 @@ the most probable token.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-__all__ = ["fused_sample", "fused_sample_multi"]
+__all__ = ["filter_binds", "fused_sample", "fused_sample_multi"]
 
 
 def _lane_keys(seeds, steps):
@@ -53,32 +63,49 @@ def _lane_keys(seeds, steps):
     return jax.vmap(one)(seeds, steps)
 
 
-def _filter_top_k(scaled, top_k):
-    """Per-lane top-k mask (k<=0 disables; ties kept)."""
-    b, v = scaled.shape
+def _filter_thresholds(scaled, top_k, top_p):
+    """Per-lane thresholds ``(kth [B, 1], thr [B])`` of the top-k and
+    the nucleus filter, from ONE descending sort: the top-k-filtered
+    row sorted descending is the sorted row with its tail masked,
+    element for element (same multiset, ties kept on both sides)."""
+    v = scaled.shape[-1]
     srt = jnp.sort(scaled, axis=-1)[:, ::-1]                 # descending
     k = jnp.clip(top_k, 1, v)
     kth = jnp.take_along_axis(srt, (k - 1)[:, None], axis=-1)  # [B,1]
-    disabled = (top_k[:, None] <= 0) | (top_k[:, None] >= v)
-    return disabled | (scaled >= kth)
-
-
-def _filter_top_p(filtered, top_p):
-    """Per-lane nucleus mask on the (already top-k-filtered) logits:
-    keep the smallest set of tokens whose cumulative probability
-    reaches top_p (the crossing token included; ties kept)."""
-    srt = jnp.sort(filtered, axis=-1)[:, ::-1]               # descending
+    srt = jnp.where(_no_top_k(top_k, v) | (srt >= kth), srt, -jnp.inf)
     probs = jax.nn.softmax(srt, axis=-1)
     cum = jnp.cumsum(probs, axis=-1)
     keep_sorted = (cum - probs) < top_p[:, None]   # exclusive cumsum < p
     thr = jnp.min(jnp.where(keep_sorted, srt, jnp.inf), axis=-1)
-    disabled = (top_p[:, None] <= 0.0) | (top_p[:, None] >= 1.0)
-    return disabled | (filtered >= thr[:, None])
+    return kth, thr
 
 
+def _no_top_k(top_k, v):
+    return (top_k[:, None] <= 0) | (top_k[:, None] >= v)
+
+
+def _no_top_p(top_p):
+    return (top_p[:, None] <= 0.0) | (top_p[:, None] >= 1.0)
+
+
+def filter_binds(top_k, top_p, v):
+    """Per lane: can its top-k or its top-p cut anything of a ``v``-wide
+    row. The condition the sort runs under, for device and host arrays
+    alike (the engine counts its steps with it)."""
+    return ~(_no_top_k(top_k, v) & _no_top_p(top_p))[:, 0]
+
+
+def _chosen_logprob(dist, tok):
+    lp = jax.nn.log_softmax(dist, axis=-1)
+    return jnp.take_along_axis(lp, tok[:, None], axis=-1)[:, 0]
+
+
+@functools.partial(jax.jit, static_argnames="sample_capable")
 def fused_sample(logits, do_sample, temperature, top_k, top_p, seeds,
                  steps, *, sample_capable=True):
-    """Sample one token per lane inside the compiled step program.
+    """Sample one token per lane inside the compiled step program (a
+    step program inlines this ``jit``; an eager caller, a fork child's
+    one row, compiles it once a shape and not a ``cond`` a call).
 
     logits [B, V] float; do_sample bool [B]; temperature float32 [B];
     top_k int32 [B]; top_p float32 [B]; seeds/steps int32 [B].
@@ -91,23 +118,35 @@ def fused_sample(logits, do_sample, temperature, top_k, top_p, seeds,
     """
     lg = logits.astype(jnp.float32)
     greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+
     if not sample_capable:
-        lp = jax.nn.log_softmax(lg, axis=-1)
-        return greedy, jnp.take_along_axis(
-            lp, greedy[:, None], axis=-1)[:, 0]
-    scaled = lg / jnp.maximum(temperature, 1e-6)[:, None]
-    keep = _filter_top_k(scaled, top_k)
-    filtered = jnp.where(keep, scaled, -jnp.inf)
-    keep = keep & _filter_top_p(filtered, top_p)
-    final = jnp.where(keep, scaled, -jnp.inf)
-    gumbel = jax.vmap(
-        lambda key: jax.random.gumbel(key, (lg.shape[1],), jnp.float32)
-    )(_lane_keys(seeds, steps))
-    sampled = jnp.argmax(final + gumbel, axis=-1).astype(jnp.int32)
-    tok = jnp.where(do_sample, sampled, greedy)
-    dist = jnp.where(do_sample[:, None], final, lg)
-    lp = jax.nn.log_softmax(dist, axis=-1)
-    return tok, jnp.take_along_axis(lp, tok[:, None], axis=-1)[:, 0]
+        return greedy, _chosen_logprob(lg, greedy)
+    v = lg.shape[-1]
+    binds = filter_binds(top_k, top_p, v)
+
+    def draw():
+        scaled = lg / jnp.maximum(temperature, 1e-6)[:, None]
+        # a threshold of -inf keeps every token: what a lane whose
+        # filters are off reads from the sort too
+        kth, thr = jax.lax.cond(
+            jnp.any(do_sample & binds),
+            lambda: _filter_thresholds(scaled, top_k, top_p),
+            lambda: (jnp.full_like(scaled[:, :1], -jnp.inf),
+                     jnp.full_like(scaled[:, 0], -jnp.inf)))
+        keep = _no_top_k(top_k, v) | (scaled >= kth)
+        filtered = jnp.where(keep, scaled, -jnp.inf)
+        keep = keep & (_no_top_p(top_p) | (filtered >= thr[:, None]))
+        final = jnp.where(keep, scaled, -jnp.inf)
+        gumbel = jax.vmap(
+            lambda key: jax.random.gumbel(key, (v,), jnp.float32)
+        )(_lane_keys(seeds, steps))
+        sampled = jnp.argmax(final + gumbel, axis=-1).astype(jnp.int32)
+        tok = jnp.where(do_sample, sampled, greedy)
+        dist = jnp.where(do_sample[:, None], final, lg)
+        return tok, _chosen_logprob(dist, tok)
+
+    return jax.lax.cond(jnp.any(do_sample), draw,
+                        lambda: (greedy, _chosen_logprob(lg, greedy)))
 
 
 def fused_sample_multi(logits, do_sample, temperature, top_k, top_p,

@@ -38,3 +38,76 @@ def wait_until_reserved(replica, timeout=30.0):
     (admission landed; the load signal other submits route on)."""
     return wait_until(lambda: replica.load() > 0, timeout=timeout,
                       msg="replica never reported a reservation")
+
+
+def hlo_sorts(hlo_text):
+    """``(outside, inside)``: the ``sort`` instructions of an HLO module
+    text that run whenever the program runs, and those that run only in
+    a branch of a ``conditional``. A computation is outside when the
+    entry reaches it through calls, fusions, loops or reducers alone."""
+    import re
+    comps, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$", line)
+        if head and "=" not in line.split("(")[0]:
+            name = head.group(2)
+            comps[name] = {"entry": bool(head.group(1)), "sorts": 0,
+                           "calls": set(), "branches": set()}
+            continue
+        if name is None or " = " not in line:
+            continue
+        c = comps[name]
+        if re.search(r"\ssort\(", line.split(" = ", 1)[1]):
+            c["sorts"] += 1
+        guarded = re.search(r"\sconditional\(", line) is not None
+        for group in re.findall(
+                r"(?:to_apply|calls|body|condition|true_computation|"
+                r"false_computation|branch_computations)="
+                r"(\{[^}]*\}|%?[\w.\-]+)", line):
+            for callee in re.findall(r"[\w.\-]+", group):
+                c["branches" if guarded else "calls"].add(callee)
+    entry = [n for n, c in comps.items() if c["entry"]]
+    assert len(entry) == 1, entry
+    seen, todo = set(), entry
+    while todo:
+        n = todo.pop()
+        if n in seen or n not in comps:
+            continue
+        seen.add(n)
+        todo += comps[n]["calls"]
+    total = sum(c["sorts"] for c in comps.values())
+    outside = sum(comps[n]["sorts"] for n in seen)
+    return outside, total - outside
+
+
+def ragged_step_avals(engine, tcap, sds=None):
+    """The operands ``engine._run_ragged_step`` hands the step program
+    at token capacity ``tcap``, as shapes (``sds(shape, dtype)`` makes
+    one: ``jax.ShapeDtypeStruct``, or one with a described sharding)."""
+    import jax
+    import jax.numpy as jnp
+    sds = sds or jax.ShapeDtypeStruct
+    lanes = engine._ragged_lanes
+    i32 = lambda *s: sds(s, jnp.int32)  # noqa: E731
+    f32 = lambda *s: sds(s, jnp.float32)  # noqa: E731
+    k_ops, v_ops = engine.cache.program_operands()
+    return ([sds(t._data.shape, t._data.dtype)
+             for t in engine.model._gen_state_tensors()],
+            i32(1, tcap), i32(1, tcap),
+            i32(lanes, engine.max_pages_per_seq), i32(lanes), i32(lanes),
+            i32(lanes), i32(1, tcap),
+            (sds((tcap,), jnp.bool_), f32(tcap), i32(tcap), f32(tcap),
+             i32(tcap), i32(tcap)),
+            [sds(a.shape, a.dtype) for a in k_ops],
+            [sds(a.shape, a.dtype) for a in v_ops])
+
+
+def ragged_step_fn(engine):
+    import functools
+
+    import jax
+
+    from paddle_tpu.serving import engine as eng_mod
+    return jax.jit(functools.partial(
+        eng_mod._ragged_step_pure, engine.model, engine._core,
+        engine.window, None))

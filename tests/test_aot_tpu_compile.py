@@ -259,6 +259,41 @@ class TestServingAttention:
             i32(t))
 
 
+class TestRaggedStepTail:
+    """The whole ragged step at the smoke's serving widths, one layer,
+    at the capacity that carries a chunk: what the chip's compiler
+    leaves of the sampler's sort."""
+
+    def test_sorts_once_and_only_under_a_condition(self, one):
+        import paddle_tpu as P
+        from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+        from paddle_tpu.serving import ServingEngine
+
+        from serving_utils import (hlo_sorts, ragged_step_avals,
+                                   ragged_step_fn)
+        with P.LazyGuard():
+            model = LlamaForCausalLM(LlamaConfig(
+                vocab_size=SZ.vocab, hidden_size=SZ.hidden,
+                intermediate_size=SZ.ffn, num_hidden_layers=1,
+                num_attention_heads=SZ.heads,
+                max_position_embeddings=SZ.max_seq_len, dtype=SZ.dtype))
+        for p in model.parameters():  # stay shapes: no initializer runs
+            del p._lazy_init
+        for lyr in model.sublayers(include_self=True):
+            lyr.__dict__["_has_lazy_params"] = False
+        model.eval()
+        eng = ServingEngine(model, ragged=True, page_size=SZ.page_size,
+                            num_pages=16, max_batch=SZ.max_batch,
+                            prefill_chunk=SZ.prefill_chunk,
+                            max_seq_len=SZ.max_seq_len)
+        avals = ragged_step_avals(     # the chunk-carrying class
+            eng, eng._ragged_tok_mixed,
+            lambda shape, dt: _sds(tuple(shape), dt, one))
+        avals[0][:] = [_sds(a.shape, BF16, one) for a in avals[0]]
+        c = ragged_step_fn(eng).lower(*avals).compile()   # about 25 s
+        assert hlo_sorts(c.as_text()) == (0, 1)
+
+
 def test_paged_kernel_knob_raises_off_cpu(monkeypatch):
     """PADDLE_TPU_PAGED_KERNEL=1 would run an interpreted kernel on a
     chip: anywhere but the cpu backend it raises."""
