@@ -6,8 +6,7 @@ Usage: python bench_serving.py [n_requests] [rate_per_s] [max_new]
                                [--smoke] [--server] [--shared-prefix]
                                [--router] [--spec] [--disagg] [--kv8]
                                [--trace] [--trace-out FILE]
-                               [--prefix-fleet] [--kvtier] [--ragged]
-                               [--tp]
+                               [--prefix-fleet] [--kvtier] [--tp]
 
 `--tp` measures tensor-parallel SPMD serving (round 23): the same
 Poisson trace replays through one warm engine per shard degree
@@ -17,18 +16,6 @@ degree's greedy streams identical to TP=1) riding the bench.  The CPU
 mesh proves exactness and baselines collective overhead — virtual
 host devices share cores, so TP>1 marginals are expected BELOW TP=1
 here.  Banks BENCH_serving_tp.json (non-smoke only).
-
-`--ragged` measures the round-22 unified ragged step: the SAME Poisson
-trace replays through a bucketed engine and a ragged one
-(`ServingEngine(..., ragged=True)` — one token-packed program for
-decode + prefill-chunk + verify lanes, sampling fused, ONE dispatch +
-ONE host fetch per step). Two-point marginal per engine, greedy
-streams asserted token-exact across the two, and the artifact records
-the compiled step-program-class count (ragged <= 2 is asserted) and
-dispatches/fetches per engine step (per-dispatch fixed cost ~0.79 of
-a small CPU step, FEASIBILITY.md; not measured on a chip).
-Banks BENCH_serving_ragged.json (non-smoke only: the tier-1 smoke can
-never clobber the banked quiet-VM numbers).
 
 `--kvtier` measures the round-20 hierarchical KV tier: a round-robin
 revisit schedule over MORE distinct long-prompt chains than the device
@@ -186,9 +173,6 @@ if prefix_fleet_mode:
 kvtier_mode = "--kvtier" in sys.argv
 if kvtier_mode:
     sys.argv.remove("--kvtier")
-ragged_mode = "--ragged" in sys.argv
-if ragged_mode:
-    sys.argv.remove("--ragged")
 tp_mode = "--tp" in sys.argv
 if tp_mode:
     sys.argv.remove("--tp")
@@ -378,9 +362,6 @@ def main():
         return
     if kvtier_mode:
         _bench_kvtier(on_tpu)
-        return
-    if ragged_mode:
-        _bench_ragged(model, cfg, engine_kw, on_tpu)
         return
     if tp_mode:
         _bench_tp(model, cfg, engine_kw, on_tpu)
@@ -1819,99 +1800,6 @@ def _bench_speculative(on_tpu):
     print(line)
     with open("BENCH_serving_spec.json", "w") as f:
         f.write(line + "\n")
-
-
-def _bench_ragged(model, cfg, engine_kw, on_tpu):
-    """Bucketed vs ragged step on the same Poisson trace (round 22).
-
-    One WARM engine per config (PR-3 recipe): warmup replays compile
-    every program class off the clock, then quarter + full replays give
-    the two-point marginal. The exactness gate rides the bench: greedy
-    streams must be token-identical across the two engines. Class and
-    dispatch accounting comes from the round-22 step metrics —
-    ``step_program_classes`` (gauge, counted over the engine lifetime
-    via the class set), ``step_dispatches``/``step_fetches`` per replay
-    divided by the step count (``step_duration_s`` records one sample
-    per engine step)."""
-    from paddle_tpu.serving import ServingEngine, ServingMetrics
-
-    arrivals, prompts = make_trace(n_requests, rate, cfg.vocab_size)
-    new_q = max(1, max_new // 4)
-
-    def measure(ragged):
-        eng = ServingEngine(model, ragged=ragged, **engine_kw)
-        warm_n = min(4, n_requests)
-        replay(model, np.zeros(warm_n), prompts[:warm_n], new_q,
-               engine=eng)
-        replay(model, np.zeros(warm_n), prompts[:warm_n], max_new,
-               engine=eng)
-        eng.metrics = ServingMetrics()
-        wall_q, toks_q, _ = replay(model, arrivals, prompts, new_q,
-                                   engine=eng)
-        eng.metrics = ServingMetrics()
-        wall, toks, metrics = replay(model, arrivals, prompts, max_new,
-                                     engine=eng)
-        m = metrics.export()
-        marginal = ((toks - toks_q) / (wall - wall_q)
-                    if wall > wall_q and toks > toks_q else None)
-        steps = m["step_duration_s"]["count"] or 1
-        out = {
-            "tok_per_s_marginal": (round(marginal, 1)
-                                   if marginal else None),
-            "e2e_tok_per_s": round(toks / wall, 1),
-            "wall_s": round(wall, 3),
-            "wall_quarter_s": round(wall_q, 3),
-            "ttft_p50_s": m["ttft_s"]["p50"],
-            "ttft_p99_s": m["ttft_s"]["p99"],
-            "inter_token_p50_s": m["inter_token_s"]["p50"],
-            "step_program_classes": len(eng._program_classes),
-            "dispatches_per_step": round(m["step_dispatches"] / steps,
-                                         3),
-            "fetches_per_step": round(m["step_fetches"] / steps, 3),
-            "preemptions": m["preemptions"],
-        }
-        results = {rid: tuple(r["tokens"])
-                   for rid, r in eng.results().items()}
-        return out, results
-
-    bucketed, ref = measure(False)
-    ragged, got = measure(True)
-    # the correctness gate: token-exact greedy streams
-    assert sorted(ref.values()) == sorted(got.values()), \
-        "ragged streams diverged from bucketed"
-    assert ragged["step_program_classes"] <= 2, ragged
-    if not smoke:
-        # quiet-VM acceptance: the merged step really is one dispatch
-        # + one fetch (padding: idle ticks record no dispatch, so the
-        # per-step ratio is exactly 1.0 on the ragged engine)
-        assert ragged["dispatches_per_step"] <= 1.0, ragged
-        assert ragged["fetches_per_step"] <= 1.0, ragged
-
-    speedup = None
-    if bucketed["tok_per_s_marginal"] and ragged["tok_per_s_marginal"]:
-        speedup = round(ragged["tok_per_s_marginal"]
-                        / bucketed["tok_per_s_marginal"], 2)
-    out = {
-        "metric": "serving_ragged_speedup" + ("" if on_tpu else "_cpu"),
-        "value": speedup,
-        "unit": "x marginal decode tok/s vs the bucketed step "
-                "(greedy, token-exact, same Poisson trace)",
-        "n_requests": n_requests, "rate_per_s": rate,
-        "max_new_tokens": max_new,
-        "token_exact_vs_bucketed": True,
-        "ragged_step_program_classes": ragged["step_program_classes"],
-        "bucketed_step_program_classes":
-            bucketed["step_program_classes"],
-        "ragged_dispatches_per_step": ragged["dispatches_per_step"],
-        "bucketed_dispatches_per_step": bucketed["dispatches_per_step"],
-        "ragged": ragged, "bucketed": bucketed,
-        "smoke": smoke,
-    }
-    line = json.dumps(out)
-    print(line)
-    if not smoke:
-        with open("BENCH_serving_ragged.json", "w") as f:
-            f.write(line + "\n")
 
 
 def _bench_tp(model, cfg, engine_kw, on_tpu):

@@ -11,10 +11,11 @@ depth cut to what one 16 GB chip holds, weights random from ``--seed``:
   ``train_batch_loop``. Checked against a plain float32 ``jax.numpy``
   forward of the same weights, and the Pallas kernels against the XLA
   reference attention.
-- **serve** — ``ServingEngine`` (ragged step) behind ``ServingServer`` in
-  a thread of this process; HTTP completions, streaming and not, checked
-  token by token against ``model.generate()`` (the static-cache path),
-  which is itself checked against the plain forward.
+- **serve** — ``ServingEngine`` (its token-packed step) behind
+  ``ServingServer`` in a thread of this process; HTTP completions,
+  streaming and not, checked token by token against
+  ``model.generate()`` (the static-cache path), which is itself checked
+  against the plain forward.
 
 ``--chips 4`` runs instead, and only, the two cross-chip paths and what
 they are compared with: the fleet SPMD stepper (sharding stage 3 × mp 2)
@@ -586,7 +587,7 @@ def phase_serve(sz, seed, clock):
     engine = ServingEngine(
         model, page_size=sz.page_size, num_pages=sz.num_pages,
         max_batch=sz.max_batch, prefill_chunk=sz.prefill_chunk,
-        max_seq_len=sz.max_seq_len, ragged=True, prefix_cache=True)
+        max_seq_len=sz.max_seq_len, prefix_cache=True)
     got, health, metrics = serve_over_http(engine, prompts,
                                            sz.new_tokens)
     exact, ties = compare_streams(model, prompts, got, want, noise)
@@ -594,7 +595,7 @@ def phase_serve(sz, seed, clock):
     classes = int(engine.metrics.step_program_classes.value)
     check(compiled == classes <= 2,
           f"step programs compiled {compiled}, classes {classes}: "
-          "the ragged step is bounded at 2")
+          "the step is bounded at 2")
     check(health["status"] in ("ok", "draining")
           and health["platform"] == device_record()["platform"],
           f"/healthz {health}")
@@ -744,7 +745,7 @@ def phase_tp_serve(sz, seed, clock):
     prompts = make_prompts(sz, seed)
     kw = dict(page_size=sz.page_size, num_pages=sz.num_pages,
               max_batch=sz.max_batch, prefill_chunk=sz.prefill_chunk,
-              max_seq_len=sz.max_seq_len, ragged=True)
+              max_seq_len=sz.max_seq_len)
     _, err, _ = static_cache_vs_plain(model, prompts[0])
     one = ServingEngine(model, **kw)
     want = _run_engine(one, prompts, sz.new_tokens)

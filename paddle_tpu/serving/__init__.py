@@ -19,16 +19,18 @@ Layers:
   ``ragged_paged_attention`` is the token-packed mixed-batch entry).
 - :mod:`scheduler`  — continuous batching: watermark admission, chunked
   prefill, decode-priority iteration, deadlines, LIFO preemption.
-- :mod:`engine`     — bucketed fixed-shape compiled step (weights as
-  arguments) + :mod:`metrics` (TTFT / inter-token / occupancy JSON +
+- :mod:`engine`     — ONE token-packed fixed-shape compiled step
+  (decode lanes, verify lanes and the prefill chunk in one program of
+  at most two token capacities; weights as arguments) +
+  :mod:`metrics` (TTFT / inter-token / occupancy JSON +
   Prometheus exposition). Round 9: per-token ``on_event`` streaming,
   ``cancel()`` (pages freed, queues purged), ``drain()`` mode,
   env-gated fault injection at the step boundary, failure-path page
   release. Round 12: batched speculative decoding
   (``draft_model=``/``speculative_k=`` — fused k+1-step draft-propose
-  scan + ONE [B, k+1] verify step with deterministic-sample
-  acceptance: greedy AND seeded-sampled streams token-exact vs the
-  plain engine; accounting-only rollback via
+  scan, then each lane rides the step with k+1 tokens and
+  deterministic-sample acceptance: greedy AND seeded-sampled streams
+  token-exact vs the plain engine; accounting-only rollback via
   ``PagedKVCache.free_tail``; admission reserves the verify burst).
 - :mod:`frontend`   — thread-safe request bridge: lock-serialized
   engine loop thread, per-request token streams, reservation-based
@@ -158,7 +160,7 @@ Layers:
 
 - :mod:`tp` — tensor-parallel SPMD serving (round 23):
   ``ServingEngine(mesh=...)`` / ``tp_degree=k`` runs the whole
-  decode/prefill/ragged step as ONE GSPMD program over a device mesh —
+  token-packed step as ONE GSPMD program over a device mesh —
   weights committed to mesh shardings (last-output-dim splits composed
   on top of fleet dist_specs via ``_add_sharding``, never returned
   verbatim), KV page pools sharded on the head axis (one allocator,

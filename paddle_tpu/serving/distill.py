@@ -2,7 +2,7 @@
 training↔serving loop.
 
 The speculative verify step already computes the TARGET model's sample
-for every draft position (engine ``_spec_round``) — i.e. live traffic
+for every draft position (engine ``_ragged_step``) — i.e. live traffic
 continuously produces free (history, target-token) supervision for the
 draft.  This module captures it and turns it into refreshed draft
 weights:

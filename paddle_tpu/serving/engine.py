@@ -4,10 +4,14 @@ Reference capability: the serving loop of vLLM / Paddle FastDeploy —
 admission, chunked prefill, batched decode, preemption — realized
 TPU-natively (SURVEY.md §7 static-shape stance):
 
-- ONE step program class, compiled per BUCKETED shape: decode runs at
-  batch buckets (powers of two up to ``max_batch``, S=1), prefill runs
-  at (B=1, S=``prefill_chunk``). The jit trace cache is therefore
-  bounded by ``log2(max_batch) + 2`` programs for the engine's lifetime.
+- ONE token-packed step program: a step's plain decode lanes (one
+  token each), speculative-verify lanes (up to k+1) and the prefill
+  chunk are packed along one token axis (``attention.py::
+  ragged_paged_attention`` lane layout) and run as one dispatch with
+  one host fetch. Lanes are always ``max_batch + 1``; the token
+  capacity is one of TWO static shapes (``max_batch`` for an all-decode
+  step, ``max_batch * (speculative_k + 1) + prefill_chunk`` otherwise),
+  so the engine compiles at most two programs in its lifetime.
 - Weights enter every compiled step as ARGUMENTS, never baked constants
   (the round-3 HTTP-413 lesson in models/generation.py): weight updates
   flow through with NO recompile and NO stale-constant hazard, and the
@@ -23,10 +27,10 @@ TPU-natively (SURVEY.md §7 static-shape stance):
   run past the context limit without a table clamp-gather hazard.
 
 The engine is host-driven: ``step()`` runs one scheduler iteration
-(decode-priority batch + at most one prefill chunk), advances request
-state, and ``run()`` loops until drained. All device work is CPU-mesh
-testable; nothing here compiles a first-time Mosaic kernel (the paged
-Pallas stub stays interpret-gated).
+(decode-priority batch + at most one prefill chunk, one program),
+advances request state, and ``run()`` loops until drained. All device
+work is CPU-mesh testable; nothing here compiles a first-time Mosaic
+kernel (the paged Pallas stub stays interpret-gated).
 
 Decode hot path (round 10):
 
@@ -42,19 +46,18 @@ Decode hot path (round 10):
 - **Radix-tree prefix caching** (``prefix_cache=True`` or
   ``PADDLE_TPU_SERVING_PREFIX_CACHE=1``): ``add_request`` pins the
   longest cached prompt prefix, the scheduler admits on UNCACHED page
-  need, and ``_prefill_chunk`` starts past the cached tokens and
-  registers fresh full prompt pages back into the tree.
-- Decode batches are staged through PERSISTENT per-bucket host buffers
-  (``_build_decode_batch``) — no per-step np.zeros garbage on the hot
-  path.
+  need, and the prefill starts past the cached tokens and registers
+  fresh full prompt pages back into the tree.
+- Steps are staged through PERSISTENT per-capacity host buffers
+  (``_ragged_bufs``) — no per-step np.zeros garbage on the hot path.
 
 Batched speculative decoding (round 12):
 
 - ``draft_model=``/``speculative_k=``: per decode round a small draft
   model proposes up to k tokens per running lane (ONE fused
-  ``lax.scan`` program — k+1 draft steps, one dispatch), then ONE
-  target step over the [B, k+1] extend shape — the chunked-prefill
-  program class in ``multi_pos`` mode — verifies every position.
+  ``lax.scan`` program — k+1 draft steps, one dispatch); the lane
+  then rides the step with k+1 tokens, and the step's sampler gives
+  the target's own token at every one of them.
 - Verification is DETERMINISTIC-SAMPLE MATCHING, not distributional
   rejection sampling: the verify step recomputes the target's own
   counter-RNG sample at every position (token ``t`` is pure in
@@ -331,14 +334,15 @@ class ServingEngine:
                                    prefill_chunk=prefill_chunk,
                                    watermark_frac=watermark_frac,
                                    spec_reserve_tokens=self.spec_k)
-        # -- unified ragged step (round 22 / PR 18) ------------------------
+        # -- the step (round 22 / PR 18; the only one since PR 29) --------
         # ONE token-packed program for mixed prefill+decode+verify
-        # steps (attention.py::ragged_paged_attention lane layout):
-        # opt-in via ragged= or PADDLE_TPU_SERVING_RAGGED=1; the
-        # bucketed path stays the default and the exactness oracle.
-        if ragged is None:
-            ragged = os.environ.get("PADDLE_TPU_SERVING_RAGGED") == "1"
-        self.ragged = bool(ragged)
+        # steps (attention.py::ragged_paged_attention lane layout).
+        # ``ragged=`` selects nothing: the benchmark's drivers still
+        # pass ragged=True, so the keyword stays until they drop it.
+        if ragged not in (None, True):
+            raise ValueError(
+                "ServingEngine(ragged=False): the bucketed step was "
+                "removed in PR 29; the token-packed step is the engine")
         self._ragged_fn = None        # one jit fn; <= 2 token shapes
         self._ragged_bufs = {}        # per-capacity persistent buffers
         # static geometry: L lanes always (max_batch decode/verify + 1
@@ -367,11 +371,10 @@ class ServingEngine:
         self._moe_counts_dev = None
         self.eos = eos_token_id
         self.window = getattr(cfg, "sliding_window", None) or None
-        self._step_fn = None          # one jit fn; traces per bucket
-        self._draft_fn = None         # draft catchup/prefill step fn
+        self._draft_fn = None         # draft catchup prefill (trunk only)
         self._propose_fn = None       # fused k+1-step draft scan program
-        self._logits_dev = None       # last step's on-device [B,V] logits
-        self._decode_bufs = {}        # per-bucket persistent host buffers
+        self._logits_dev = None       # last step's on-device [T,V] logits
+        self._logits_row = 0          # the row _last_logits_probe reads
         self._seed_rng = np.random.default_rng()  # seed=None fallback
         self._requests: dict[int, Request] = {}
         self._finished: dict[int, Request] = {}
@@ -544,17 +547,7 @@ class ServingEngine:
             self.metrics.deadline_evictions.inc()
             self._record_finish(r, events)
         self.sweep_held_deadlines(now)
-        if self.ragged:
-            self._ragged_step(out, events)
-        else:
-            if out.decode:
-                self._decode_batch(out.decode, events)
-            if out.prefill is not None:
-                req, start, end = out.prefill
-                # the decode batch may have preempted the prefilling
-                # request
-                if req.state == RequestState.PREFILLING:
-                    self._prefill_chunk(req, start, end, events)
+        self._ragged_step(out, events)
         if not out.decode and out.prefill is None and not out.expired \
                 and self.scheduler.waiting \
                 and not self.scheduler.live_requests():
@@ -964,110 +957,6 @@ class ServingEngine:
         return (self.spec_k > 0 and self.draft is not None
                 and req.speculative is not False)
 
-    def _decode_batch(self, reqs, events):
-        spec, plain = [], []
-        for r in reqs:
-            (spec if self._spec_enabled(r) else plain).append(r)
-        if spec:
-            # lanes whose draft cache cannot be readied this round fall
-            # back to the plain batch (output-identical, just slower)
-            self._spec_round(spec, plain, events)
-        if plain:
-            self._plain_decode(plain, events)
-
-    def _plain_decode(self, reqs, events):
-        t0 = self._now()
-        alloc = []
-        for r in reqs:
-            if r.state != RequestState.RUNNING:
-                continue  # preempted by an earlier member's allocation
-            slots = self._alloc_with_preemption(r, 1)
-            alloc.append((r, int(slots[0])))
-        active = [(r, s) for r, s in alloc
-                  if r.state == RequestState.RUNNING]
-        if not active:
-            return
-        host = self._host_sampling()
-        b = self._build_decode_batch(active)
-        sample_capable = (not host) and any(r.do_sample
-                                            for r, _ in active)
-        tok_d, lp_d = self._run_step(
-            b["ids"], b["positions"], b["pt"], b["cl"], b["slot_map"],
-            b["last_idx"],
-            (b["do_sample"], b["temperature"], b["top_k"], b["top_p"],
-             b["seeds"], b["steps"]), sample_capable)
-        self.metrics.decode_steps.inc()
-        self.metrics.batch_size.record(len(active))
-        if host:
-            logits = self._fetch_logits()
-            for i, (r, _) in enumerate(active):
-                self._emit_token(r, self._sample(r, logits[i]), events)
-        else:
-            toks = np.asarray(tok_d, np.int32)
-            lps = np.asarray(lp_d, np.float32)
-            self.metrics.fetch_bytes.inc(toks.nbytes + lps.nbytes)
-            self.metrics.step_fetches.inc()
-            for i, (r, _) in enumerate(active):
-                self._emit_token(r, int(toks[i]), events,
-                                 logprob=float(lps[i]))
-        if self.trace.enabled:
-            dur = self._now() - t0
-            for r, _ in active:
-                self.trace.run_span(r.req_id, "decode_round", t0, dur,
-                                    batch=len(active))
-
-    def _build_decode_batch(self, active):
-        """Stage the decode batch into PERSISTENT per-bucket host
-        buffers (allocated once per bucket, reused every step — no
-        per-step np.zeros on the hot path). Padded lanes are explicitly
-        reset each step: context 1, slots at the scratch page, neutral
-        sampling params."""
-        bb = self._bucket(len(active))
-        b = self._decode_bufs.get(bb)
-        if b is None:
-            mp = self.max_pages_per_seq
-            b = self._decode_bufs[bb] = {
-                "ids": np.zeros((bb, 1), np.int32),
-                "positions": np.zeros((bb, 1), np.int32),
-                "pt": np.full((bb, mp), SCRATCH_PAGE, np.int32),
-                "cl": np.ones(bb, np.int32),     # 1, not 0: keeps
-                "slot_map": np.zeros((bb, 1), np.int32),  # softmax
-                "last_idx": np.zeros(bb, np.int32),       # NaN-free
-                "do_sample": np.zeros(bb, np.bool_),
-                "temperature": np.ones(bb, np.float32),
-                "top_k": np.zeros(bb, np.int32),
-                "top_p": np.ones(bb, np.float32),
-                "seeds": np.zeros(bb, np.int32),
-                "steps": np.zeros(bb, np.int32),
-            }
-        n = len(active)
-        b["ids"][n:] = 0
-        b["positions"][n:] = 0
-        b["pt"][n:] = SCRATCH_PAGE
-        b["cl"][n:] = 1
-        b["slot_map"][n:] = 0
-        b["do_sample"][n:] = False
-        b["temperature"][n:] = 1.0
-        b["top_k"][n:] = 0
-        b["top_p"][n:] = 1.0
-        b["seeds"][n:] = 0
-        b["steps"][n:] = 0
-        for i, (r, slot) in enumerate(active):
-            hist_len = r.prompt.size + len(r.out_tokens)
-            b["ids"][i, 0] = r.out_tokens[-1]
-            b["positions"][i, 0] = hist_len - 1
-            b["pt"][i] = self.cache.page_table(r.seq_id,
-                                              self.max_pages_per_seq)
-            b["cl"][i] = hist_len
-            b["slot_map"][i, 0] = slot
-            b["do_sample"][i] = r.do_sample
-            b["temperature"][i] = r.temperature
-            b["top_k"][i] = r.top_k
-            b["top_p"][i] = r.top_p
-            b["seeds"][i] = r.device_seed
-            b["steps"][i] = len(r.out_tokens)
-        return b
-
     # -- speculative decoding (round 12) -----------------------------------
     def _draft_alloc(self, seq_id, n, protect=()):
         """Allocate ``n`` draft-cache slots, evicting OTHER lanes' draft
@@ -1093,9 +982,10 @@ class ServingEngine:
     def _draft_ready(self, req, protect=()):
         """Bring the draft cache up to date for ``req``: every history
         token but the last must have its draft K/V written (catchup
-        runs the draft's chunked-prefill program — a lane's first
-        speculative round after prefill/preemption/fork pays it once).
-        False -> the lane falls back to plain decode this round."""
+        runs the draft's trunk over a rectangular [1, prefill_chunk]
+        chunk — a lane's first speculative round after prefill/
+        preemption/fork pays it once). False -> the lane falls back to
+        plain decode this round."""
         dc = self._draft_cache
         sid = req.seq_id
         target = req.prompt.size + len(req.out_tokens) - 1
@@ -1109,9 +999,6 @@ class ServingEngine:
             return True
         hist = req.token_history()
         c = self.scheduler.prefill_chunk
-        neutral = (np.zeros(1, np.bool_), np.ones(1, np.float32),
-                   np.zeros(1, np.int32), np.ones(1, np.float32),
-                   np.zeros(1, np.int32), np.zeros(1, np.int32))
         while have < target:
             n = min(c, target - have)
             slots = self._draft_alloc(sid, n, protect)
@@ -1124,19 +1011,16 @@ class ServingEngine:
             cl = np.asarray([have + n], np.int32)
             slot_map = np.zeros((1, c), np.int32)
             slot_map[0, :n] = slots
-            self._run_draft_step(ids, positions, pt, cl, slot_map,
-                                 np.asarray([n - 1], np.int32), neutral)
+            self._run_draft_step(ids, positions, pt, cl, slot_map)
             have += n
         return True
 
     def _stage_draft_propose(self, active):
         """Build the bucketed draft arrays for the surviving verify
-        lanes and run the fused k+1-step proposal scan (shared by the
-        bucketed `_spec_round` and the ragged step — the draft program
-        stays its own dispatch in both: different model, disposable
-        K/V). ``active`` rows are ``(req, hist0, n_slots, tslots,
-        dslots)``. Returns ``(props [bb, k+1] int32, samp,
-        sample_capable)``."""
+        lanes and run the fused k+1-step proposal scan (its own
+        dispatch beside the step: different model, disposable K/V).
+        ``active`` rows are ``(req, hist0, n_slots, tslots, dslots)``.
+        Returns the proposals, ``[bb, k+1]`` int32."""
         k1 = self.spec_k + 1
         bb = self._bucket(len(active))
         mp = self.max_pages_per_seq
@@ -1170,154 +1054,29 @@ class ServingEngine:
             np.int32)                                  # [bb, k+1]
         self.metrics.fetch_bytes.inc(props.nbytes)
         self.metrics.step_fetches.inc()
-        return props, samp, sample_capable
+        return props
 
-    def _spec_round(self, lanes, plain, events):
-        """One draft-propose / target-verify round over the speculative
-        lanes: k+1 fused draft steps (ONE dispatch), ONE [B, k+1]
-        target extend step, deterministic-sample acceptance, rollback
-        of rejected slots. Lanes the draft cannot serve are demoted to
-        ``plain`` (token-identical output, just one-token decode)."""
-        k = self.spec_k
-        k1 = k + 1
-        t0 = self._now()
-        protect = {r.seq_id for r in lanes}
-        staged = []
-        for r in lanes:
-            if r.state != RequestState.RUNNING:
-                continue  # preempted by an earlier member's catchup
-            if not self._draft_ready(r, protect):
-                self.metrics.spec_fallbacks.inc()
-                plain.append(r)
-                continue
-            staged.append(r)
-        alloc = []
-        for r in staged:
-            if r.state != RequestState.RUNNING:
-                continue  # preempted by an earlier member's allocation
-            hist0 = r.prompt.size + len(r.out_tokens)
-            rem = r.max_new_tokens - len(r.out_tokens)
-            # slots past the request's final fed position go to scratch
-            # (they are never attended), keeping the round inside the
-            # front-end's prompt+max_new page reservation envelope
-            n_slots = min(k1, rem)
-            tslots = self._alloc_with_preemption(r, n_slots)
-            if r.state != RequestState.RUNNING:  # pragma: no cover
-                continue
-            dslots = self._draft_alloc(r.seq_id, n_slots, protect)
-            if dslots is None:
-                self.cache.free_tail(r.seq_id, hist0 - 1)
-                self.metrics.spec_fallbacks.inc()
-                plain.append(r)
-                continue
-            alloc.append((r, hist0, n_slots, tslots, dslots))
-        active = [a for a in alloc
-                  if a[0].state == RequestState.RUNNING]
-        if not active:
-            return
-        bb = self._bucket(len(active))
-        mp = self.max_pages_per_seq
-        props, samp, sample_capable = self._stage_draft_propose(active)
-        ids = np.zeros((bb, k1), np.int32)
-        positions = np.zeros((bb, k1), np.int32)
-        pt = np.full((bb, mp), SCRATCH_PAGE, np.int32)
-        cl = np.ones(bb, np.int32)
-        slot_map = np.zeros((bb, k1), np.int32)
-        for i, (r, hist0, n_slots, tslots, dslots) in enumerate(active):
-            ids[i, 0] = r.out_tokens[-1]
-            ids[i, 1:] = props[i, :k]
-            positions[i] = hist0 - 1 + np.arange(k1, dtype=np.int32)
-            pt[i] = self.cache.page_table(r.seq_id, mp)
-            cl[i] = hist0 - 1 + n_slots
-            slot_map[i, :n_slots] = tslots
-        host = self._host_sampling()
-        toks, lps = self._run_step(
-            ids, positions, pt, cl, slot_map, np.zeros(bb, np.int32),
-            samp, (not host) and sample_capable, multi_pos=True)
-        self.metrics.spec_rounds.inc()
-        self.metrics.decode_steps.inc()
-        self.metrics.batch_size.record(len(active))
-        # count only proposals that COULD be accepted (a lane about to
-        # hit max_new can use at most its remaining budget) so the
-        # acceptance rate measures the draft, not the budget clip
-        self.metrics.spec_draft_tokens.inc(
-            sum(min(k, a[2]) for a in active))
-        if host:
-            logits = self._fetch_logits()              # [bb, k+1, V]
-        else:
-            toks = np.asarray(toks, np.int32)
-            lps = np.asarray(lps, np.float32)
-            self.metrics.fetch_bytes.inc(toks.nbytes + lps.nbytes)
-            self.metrics.step_fetches.inc()
-        accepted = 0
-        for i, (r, hist0, n_slots, tslots, dslots) in enumerate(active):
-            emitted = 0
-            lane_accepted = 0
-            for j in range(k1):
-                if host:
-                    # host oracle: numpy RNG draws happen one per
-                    # EMITTED token, in stream order — identical
-                    # consumption to the non-speculative loop
-                    v = self._sample(r, logits[i, j])
-                    lp = None
-                else:
-                    v = int(toks[i, j])
-                    lp = float(lps[i, j])
-                is_draft = j < k and v == int(props[i, j])
-                if self.distill is not None:
-                    # online distillation (round 21): the verify step
-                    # computed the target's token for this history for
-                    # free — log the hard-target pair BEFORE the emit
-                    # appends v to the history
-                    self.distill.log(r.prompt, r.out_tokens, v)
-                    self.metrics.distill_pairs.inc()
-                self._emit_token(r, v, events, logprob=lp)
-                emitted += 1
-                if is_draft:
-                    accepted += 1
-                    lane_accepted += 1
-                if r.state == RequestState.FINISHED or not is_draft:
-                    break  # mismatch emits the correction; j==k = bonus
-            if r.state != RequestState.FINISHED:
-                # rollback: accounting only — rejected slots' K/V stays
-                # masked by context_len until overwritten
-                new_len = hist0 + emitted - 1
-                self.cache.free_tail(r.seq_id, new_len)
-                self._draft_cache.free_tail(r.seq_id, new_len)
-            if self.trace.enabled:
-                self.trace.run_span(r.req_id, "spec_round", t0,
-                                    self._now() - t0,
-                                    batch=len(active),
-                                    proposed=min(k, n_slots),
-                                    accepted=lane_accepted,
-                                    emitted=emitted)
-        self.metrics.spec_accepted_tokens.inc(accepted)
-
-    def _run_draft_step(self, ids, positions, pt, cl, slot_map,
-                        last_idx, samp):
-        """Draft catchup prefill: same compiled step class as the
-        target, on the draft model/cache (sampling output unused)."""
+    def _run_draft_step(self, ids, positions, pt, cl, slot_map):
+        """Draft catchup prefill: the draft's trunk over one
+        rectangular chunk, for the K/V it writes (no head, no
+        sampler: nothing of a catchup is ever emitted)."""
         import jax
         import jax.numpy as jnp
         if self._draft_fn is None:
-            # tp=None: the draft program never pins TP layouts — a
-            # distinct draft's weights are replicated (byte-identical
+            # no TP context: the draft program never pins TP layouts —
+            # a distinct draft's weights are replicated (byte-identical
             # program to TP=1), a self-draft's sharded tensors fall to
             # GSPMD auto.  Either way the verify step's deterministic-
             # sample matching keeps the EMITTED stream token-exact.
             self._draft_fn = jax.jit(
-                functools.partial(_paged_step_pure, self.draft,
-                                  self._draft_core, self._draft_window,
-                                  None),
-                static_argnums=(0, 1))
+                functools.partial(_draft_catchup_pure, self.draft,
+                                  self._draft_core, self._draft_window))
         dc = self._draft_cache
         dwarrs = [t._data for t in self.draft._gen_state_tensors()]
         k_ops, v_ops = dc.program_operands()
-        _, _, _, k_pages, v_pages = self._draft_fn(
-            False, False, dwarrs, jnp.asarray(ids),
-            jnp.asarray(positions), jnp.asarray(pt), jnp.asarray(cl),
-            jnp.asarray(slot_map), jnp.asarray(last_idx),
-            tuple(jnp.asarray(a) for a in samp),
+        k_pages, v_pages = self._draft_fn(
+            dwarrs, jnp.asarray(ids), jnp.asarray(positions),
+            jnp.asarray(pt), jnp.asarray(cl), jnp.asarray(slot_map),
             k_ops, v_ops)
         dc.store_operands(k_pages, v_pages)
         self._count_dispatch(("draft_step", ids.shape))
@@ -1350,77 +1109,14 @@ class ServingEngine:
                               bool(sample_capable)))
         return props
 
-    def _prefill_chunk(self, req, start, end, events):
-        t0 = self._now()
-        if self.trace.enabled:
-            # first chunk of this prefill pass: close the queued span
-            # (arrival -> admission, or requeue -> re-admission)
-            q0 = self.trace.pop_mark(req.req_id, "queued_t0")
-            if q0 is not None:
-                self.trace.span(req.req_id, "queued", q0, t0 - q0)
-        if not self.cache.has_seq(req.seq_id):
-            self.cache.alloc_seq(req.seq_id)
-        hist = req.token_history()
-        chunk = hist[start:end]
-        n = int(chunk.size)
-        slots = self._alloc_with_preemption(req, n)
-        c = self.scheduler.prefill_chunk
-        ids = np.zeros((1, c), np.int32)
-        ids[0, :n] = chunk
-        positions = (start
-                     + np.arange(c, dtype=np.int32))[None, :]
-        pt = self.cache.page_table(req.seq_id,
-                                   self.max_pages_per_seq)[None, :]
-        cl = np.asarray([start + n], np.int32)
-        slot_map = np.zeros((1, c), np.int32)  # padding -> scratch slots
-        slot_map[0, :n] = slots
-        last_idx = np.asarray([n - 1], np.int32)
-        host = self._host_sampling()
-        samp = (np.asarray([req.do_sample], np.bool_),
-                np.asarray([req.temperature], np.float32),
-                np.asarray([req.top_k], np.int32),
-                np.asarray([req.top_p], np.float32),
-                np.asarray([req.device_seed], np.int32),
-                np.asarray([len(req.out_tokens)], np.int32))
-        tok_d, lp_d = self._run_step(
-            ids, positions, pt, cl, slot_map, last_idx, samp,
-            (not host) and req.do_sample)
-        self.metrics.prefill_chunks.inc()
-        if self.trace.enabled:
-            # a chunk that replays already-sampled tokens is recompute
-            # work paid to preemption, not first-pass prefill — the
-            # finish log's stall_s bucket
-            self.trace.span(
-                req.req_id,
-                ("recompute" if (req.out_tokens or req.preemptions)
-                 else "prefill_chunk"),
-                t0, self._now() - t0, start=int(start), end=int(end),
-                tokens=n)
-        if self.cache.prefix_cache_enabled:
-            # fresh full PROMPT pages now hold K/V: register them
-            self.cache.commit_prefix(req.seq_id, req.prompt, end)
-        self.scheduler.prefill_advanced(req, end)
-        if req.state != RequestState.RUNNING:
-            return  # more chunks to go
-        if host:
-            self._prefill_finish(req, events, True, 0, None, None)
-        else:
-            toks = np.asarray(tok_d, np.int32)
-            lps = np.asarray(lp_d, np.float32)
-            self.metrics.fetch_bytes.inc(toks.nbytes + lps.nbytes)
-            self.metrics.step_fetches.inc()
-            self._prefill_finish(req, events, False, 0, int(toks[0]),
-                                 float(lps[0]))
-
     def _prefill_finish(self, req, events, host, row_idx, tok, lp):
-        """Prefill-completion tail, shared by the bucketed chunk and
-        the ragged step (``row_idx`` selects the request's last-token
-        logits row in the step's logits — 0 for the bucketed [1, V]
-        fetch, the packed token offset for the ragged [T, V] one).
-        Fork BEFORE sampling (children share the prefix pages; the
-        parent may finish — and free — immediately). A RECOMPUTE
-        prefill (out_tokens non-empty after preemption) must NOT fork
-        again: the children already exist."""
+        """Prefill-completion tail of the step (``row_idx`` is the
+        packed token offset of the request's last prompt token: its
+        row in the step's [T, V] logits). Fork BEFORE sampling
+        (children share the prefix pages; the parent may finish — and
+        free — immediately). A RECOMPUTE prefill (out_tokens non-empty
+        after preemption) must NOT fork again: the children already
+        exist."""
         children = []
         if req.n > 1 and not req.out_tokens:
             for i in range(1, req.n):
@@ -1458,21 +1154,21 @@ class ServingEngine:
             self.trace.mark(req.req_id, "held_t0", self._now())
         self._record_finish(req, events)
 
-    # -- unified ragged step (round 22 / PR 18) ----------------------------
+    # -- the step: token-packed, one program (round 22 / PR 18) ------------
     def _ragged_step(self, out, events):
         """ONE token-packed dispatch for the whole step: plain decode
         lanes (q=1), speculative-verify lanes (q=k+1), and the prefill
         chunk ride a single compiled program over the
         ``ragged_paged_attention`` lane layout — one dispatch + one
         host fetch per step (FEASIBILITY.md: per-dispatch overhead
-        ~0.79 of a small CPU step; not measured on a chip). Per-token
-        counter-RNG keys are IDENTICAL to the bucketed path's
-        ((seed, token-index) is schedule-independent), so streams are
-        token-exact vs it even though preemption ORDER may differ —
+        ~0.79 of a small CPU step; not measured on a chip). A token's
+        counter-RNG key is (seed, token-index) and knows no schedule,
+        so a stream is the one the request would get served alone,
+        whatever the crowd, the chunking and the preemption order —
         any valid schedule replays the same (weights, history, seed, t)
         function. The draft-proposal scan stays its own dispatch
         (different model, disposable K/V); draft catchup prefills ride
-        ahead of it exactly as in `_spec_round`."""
+        ahead of it."""
         t0 = self._now()
         k = self.spec_k
         k1 = k + 1
@@ -1546,7 +1242,7 @@ class ServingEngine:
         # 5. draft proposals for the surviving verify lanes
         props = None
         if spec_active:
-            props, _, _ = self._stage_draft_propose(spec_active)
+            props = self._stage_draft_propose(spec_active)
         # 6. pack the token batch. Two static token capacities only
         # (see __init__): a step fits the small all-decode shape or
         # pads to the mixed one.
@@ -1613,8 +1309,8 @@ class ServingEngine:
             b["top_k"][sl] = r.top_k
             b["top_p"][sl] = r.top_p
             b["seeds"][sl] = r.device_seed
-            # verify token j samples with counter key steps0+j — the
-            # flattened fused_sample_multi key of the bucketed verify
+            # verify token j samples with counter key steps0+j: the
+            # key a plain decode lane has at that token index
             b["steps"][sl] = len(r.out_tokens) + np.arange(
                 n_slots, dtype=np.int32)
             emit_spec.append((r, hist0, n_slots, i, off))
@@ -1673,6 +1369,7 @@ class ServingEngine:
             b["qoff"], b["slot_map"],
             (b["do_sample"], b["temperature"], b["top_k"], b["top_p"],
              b["seeds"], b["steps"]))
+        self._logits_row = pf_off if pf_off is not None else 0
         if spec_active:
             self.metrics.spec_rounds.inc()
             self.metrics.spec_draft_tokens.inc(
@@ -1693,8 +1390,8 @@ class ServingEngine:
             self.metrics.fetch_bytes.inc(toks.nbytes + lps.nbytes)
             self.metrics.step_fetches.inc()
         experts_hit = self._record_moe_counts()
-        # 8. host-side per-lane processing, bucketed event order:
-        # verify lanes, plain lanes, then the prefill completion
+        # 8. host-side per-lane processing, in event order: verify
+        # lanes, plain lanes, then the prefill completion
         accepted = 0
         for r, hist0, n_slots, i, toff in emit_spec:
             emitted = 0
@@ -1738,7 +1435,7 @@ class ServingEngine:
                 self._emit_token(r, int(toks[toff]), events,
                                  logprob=float(lps[toff]))
             if self.trace.enabled:
-                self.trace.run_span(r.req_id, "ragged_round", t0,
+                self.trace.run_span(r.req_id, "decode_round", t0,
                                     self._now() - t0,
                                     batch=len(plain_active))
         if pf is not None:
@@ -2088,15 +1785,16 @@ class ServingEngine:
 
     @property
     def _last_logits_probe(self):
-        """Row-0 logits of the last step, fetched on demand —
-        parity-test observability (the hot path no longer fetches
-        logits at all)."""
+        """One row of the last step's logits, fetched on demand: its
+        prefill chunk's last token where it carried a chunk (the row a
+        completed prefill samples from), else its first packed token —
+        parity-test observability (the hot path fetches no logits)."""
         if self._logits_dev is None:
             return None
-        return np.asarray(self._logits_dev, np.float32)[0]
+        return np.asarray(self._logits_dev[self._logits_row], np.float32)
 
     def _fetch_logits(self):
-        """Pull the last step's full [B, V] logits to the host (oracle
+        """Pull the last step's full [T, V] logits to the host (oracle
         sampling / fork seeding) and account the fetch."""
         out = np.asarray(self._logits_dev, np.float32)
         self.metrics.fetch_bytes.inc(out.nbytes)
@@ -2137,9 +1835,8 @@ class ServingEngine:
     def _count_dispatch(self, key):
         """Account one device dispatch and its compiled program class
         (``key`` is the static shape signature that keys the jit trace
-        cache). ``step_program_classes`` is the gauge the ragged path
-        bounds at <= 2; the bucketed path grows one class per decode
-        bucket plus the prefill and verify shapes. Draft-model programs
+        cache). ``step_program_classes`` is the gauge the step's two
+        token capacities bound at <= 2. Draft-model programs
         (the propose scan is its own dispatch by design — different
         model, disposable K/V) count as dispatches but not as step
         classes."""
@@ -2186,39 +1883,6 @@ class ServingEngine:
                           "GSPMD partitioning rule; using the jnp "
                           "gather path"}))
         self.metrics.tp_kernel_fallbacks.inc()
-
-    def _run_step(self, ids, positions, pt, cl, slot_map, last_idx,
-                  samp, sample_capable, multi_pos=False):
-        import jax
-        import jax.numpy as jnp
-        self._tp_kernel_guard()
-        if self._step_fn is None:
-            # bucketed shapes bound this single fn's trace cache to
-            # 2*(log2(max_batch)+2) entries (the static sample_capable
-            # and multi_pos flags at most double it each); weights ride
-            # as arguments. The TP context rides the partial like
-            # model/core — closed over, never traced — so the jit
-            # signature and static argnums are the TP=1 ones.
-            self._step_fn = jax.jit(
-                functools.partial(_paged_step_pure, self.model,
-                                  self._core, self.window, self._tp),
-                static_argnums=(0, 1))
-        warrs = [t._data for t in self.model._gen_state_tensors()]
-        k_ops, v_ops = self.cache.program_operands()
-        tok, lp, logits, k_pages, v_pages = self._step_fn(
-            bool(sample_capable), bool(multi_pos), warrs,
-            jnp.asarray(ids), jnp.asarray(positions), jnp.asarray(pt),
-            jnp.asarray(cl), jnp.asarray(slot_map),
-            jnp.asarray(last_idx),
-            tuple(jnp.asarray(a) for a in samp),
-            k_ops, v_ops)
-        self.cache.store_operands(k_pages, v_pages)
-        self._logits_dev = logits  # NOT fetched on the decode hot path
-        self._count_dispatch(("step", ids.shape, bool(multi_pos),
-                              bool(sample_capable)))
-        if sample_capable:
-            self._count_sort(samp)
-        return tok, lp
 
     def _run_ragged_step(self, ids, positions, pt, cl, ql, qoff,
                          slot_map, samp):
@@ -2271,18 +1935,20 @@ def _counter_sample_row(logits_row, req):
     return int(np.asarray(tok)[0]), float(np.asarray(lp)[0])
 
 
-def _paged_step_pure(model, core, window, tp, sample_capable,
-                     multi_pos, warrs, ids, positions, pt, cl,
-                     slot_map, last_idx, samp, k_pages, v_pages):
-    tensors = model._gen_state_tensors()
+def _draft_catchup_pure(draft, core, window, dwarrs, ids, positions,
+                        pt, cl, slot_map, k_pages, v_pages):
+    """The draft's catchup prefill: the trunk over one rectangular
+    [1, C] chunk, run for the K/V it scatters into the draft's pools.
+    Returns ``(new_k, new_v)``; the hidden state is not an output."""
+    tensors = draft._gen_state_tensors()
     saved = [(t, t._data) for t in tensors]
-    for t, arr in zip(tensors, warrs):
+    for t, arr in zip(tensors, dwarrs):
         t._data = arr
     try:
-        return _paged_step_body(model, core, window, tp,
-                                sample_capable, multi_pos, ids,
-                                positions, pt, cl, slot_map, last_idx,
-                                samp, k_pages, v_pages)
+        _, new_k, new_v = _paged_forward(core, window, ids, positions,
+                                         pt, cl, slot_map, k_pages,
+                                         v_pages)
+        return new_k, new_v
     finally:
         for t, arr in saved:
             t._data = arr
@@ -2291,12 +1957,15 @@ def _paged_step_pure(model, core, window, tp, sample_capable,
 def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
                    k_pages, v_pages, ragged=None, tp=None, stats=None):
     """The transformer trunk over the paged cache: embed, attend (K/V
-    scattered into the page pool), final norm. Shared by the target
-    step program, the draft catchup step, the draft proposal scan, and
-    the unified ragged step. ``ragged=(query_lens, q_offsets)`` flips
-    attention to the token-packed lane layout: ids/positions/slot_map
-    are [1, T] (the scatter is shape-agnostic) while pt/cl are the
-    [L, P]/[L] PER-LANE arrays. Returns ``(hidden [B, S, D] jnp array,
+    scattered into the page pool), final norm. The step runs it with
+    ``ragged=(query_lens, q_offsets)``, the token-packed lane layout:
+    ids/positions/slot_map are [1, T] (the scatter is shape-agnostic)
+    while pt/cl are the [L, P]/[L] PER-LANE arrays. ``ragged=None``,
+    the rectangular [B, S] form over ``paged_attention``, is the draft
+    model's alone (catchup prefill and proposal scan): until the
+    packed layout stops gathering a padded page table per token, a
+    catchup chunk of c tokens would gather c tables where the
+    rectangle gathers one. Returns ``(hidden [B, S, D] jnp array,
     new_k, new_v)``.
 
     ``tp`` (a :class:`~.tp.TPContext`) makes the trunk ONE SPMD
@@ -2316,7 +1985,8 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
     asked for it, with each packed token's page-table row, visible keys
     and validity; ``v_pages`` is then empty and ``new_v`` comes back
     empty. ``stats``, a list, receives such layers' routing counts.
-    LLaMA-shaped layers run the code below, as before (two paged
+    Such a model has no draft form, so its layers see the packed
+    layout only. LLaMA-shaped layers run the code below (two paged
     forwards until D1 gives the block one definition)."""
     from ..core.autograd import no_grad
     from ..core.tensor import Tensor
@@ -2339,7 +2009,7 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
         pos_t = Tensor(positions)
         new_k, new_v = [], []
         if hasattr(core.layers[0], "paged_forward"):
-            per_tok = _per_token_tables(b, s, positions, pt, cl, ragged)
+            per_tok = _per_token_tables(b * s, pt, cl, *ragged)
             for layer, pool in zip(core.layers, k_pages):
                 x, pool = layer.paged_forward(x, positions, pool,
                                               flat_slots, *per_tok,
@@ -2431,74 +2101,20 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
     return x._data, new_k, new_v
 
 
-def _per_token_tables(b, s, positions, pt, cl, ragged):
-    """``(pt_tok [b*s, P], cl_tok [b*s], valid [b*s])``: each packed
-    token's page-table row, the keys it may see and whether it is a real
-    token -- what a layer's own ``paged_forward`` attends with. The
-    rectangular [B, S] step is the ragged one with S tokens a lane."""
+def _per_token_tables(t, pt, cl, ql, qoff):
+    """``(pt_tok [t, P], cl_tok [t], valid [t])``: each packed token's
+    page-table row, the keys it may see and whether it is a real
+    token -- what a layer's own ``paged_forward`` attends with."""
     import jax.numpy as jnp
 
     from .attention import _token_lanes
-    if ragged is None:
-        return (jnp.repeat(pt, s, axis=0),
-                jnp.repeat(cl.astype(jnp.int32), s),
-                jnp.ones((b * s,), jnp.bool_))
-    ql, qoff = ragged
-    lane, _ = _token_lanes(ql, qoff, b * s)
-    valid = jnp.arange(b * s, dtype=jnp.int32) < jnp.sum(
+    lane, _ = _token_lanes(ql, qoff, t)
+    valid = jnp.arange(t, dtype=jnp.int32) < jnp.sum(
         ql.astype(jnp.int32))
     return pt[lane], cl[lane].astype(jnp.int32), valid
 
 
-def _paged_step_body(model, core, window, tp, sample_capable,
-                     multi_pos, ids, positions, pt, cl, slot_map,
-                     last_idx, samp, k_pages, v_pages):
-    import jax.numpy as jnp
-
-    from ..core.autograd import no_grad
-    from ..core.tensor import Tensor
-
-    x, new_k, new_v = _paged_forward(core, window, ids, positions, pt,
-                                     cl, slot_map, k_pages, v_pages,
-                                     tp=tp)
-    from .sampling import fused_sample, fused_sample_multi
-    do_sample, temperature, top_k, top_p, seeds, steps = samp
-    if multi_pos:
-        # speculative verify: logits + the target's own deterministic
-        # sample at EVERY position of the extend (one [B, S] fetch);
-        # the non-speculative path never takes this branch, keeping its
-        # fetch at <= B*8 bytes
-        with no_grad():
-            logits = model.lm_head(Tensor(x))._data
-        if tp is not None:
-            # lm_head shards the vocab columns: gather the partial
-            # (column-sliced, never partially-summed) logits so fused
-            # sampling runs replicated — identical to TP=1
-            logits = tp.replicate(logits)
-        logits = logits.astype(jnp.float32)              # [B, S, V]
-        tokens, logprobs = fused_sample_multi(
-            logits, do_sample, temperature, top_k, top_p, seeds, steps,
-            sample_capable=sample_capable)
-        return tokens, logprobs, logits, new_k, new_v
-    b = ids.shape[0]
-    h_last = x[jnp.arange(b), last_idx]                  # [B, D]
-    with no_grad():
-        logits = model.lm_head(Tensor(h_last[:, None, :]))._data[:, 0]
-    if tp is not None:
-        # the all-gather happens only at the sampled lane: h_last
-        # already dropped the S axis, so this moves [B, V] per step
-        logits = tp.replicate(logits)
-    logits = logits.astype(jnp.float32)
-    # fused on-device sampling: the host fetches [B] ids (+logprobs),
-    # not [B, V] logits; sample_capable is STATIC (greedy-only batches
-    # compile without the top-k/top-p sort)
-    tokens, logprobs = fused_sample(
-        logits, do_sample, temperature, top_k, top_p, seeds, steps,
-        sample_capable=sample_capable)
-    return tokens, logprobs, logits, new_k, new_v
-
-
-# -- the unified ragged step (round 22 / PR 18) ----------------------------
+# -- the step program (round 22 / PR 18) -----------------------------------
 
 def _ragged_step_pure(model, core, window, tp, warrs, ids, positions,
                       pt, cl, ql, qoff, slot_map, samp, k_pages,
@@ -2518,17 +2134,16 @@ def _ragged_step_pure(model, core, window, tp, warrs, ids, positions,
 
 def _ragged_step_body(model, core, window, tp, ids, positions, pt, cl,
                       ql, qoff, slot_map, samp, k_pages, v_pages):
-    """Token-packed unified step: the trunk runs at [1, T], lm_head +
+    """The token-packed step: the trunk runs at [1, T], lm_head +
     fused sampling cover EVERY packed token (each with its own
-    per-token counter key — a verify token j carries steps0+j, exactly
-    fused_sample_multi's flattened key; a prefill chunk's tokens but
-    the prompt's last carry neutral params and their samples are
-    discarded), and the host fetch is [T] ids + [T] logprobs. Always
-    compiled sample-capable: a greedy lane takes fused_sample's argmax
-    and raw logprob, so values match the greedy-compiled bucketed
-    programs bit-for-bit while greedy and sampled steps share ONE
-    class; the sort and the draw run only in a step where a token asks
-    for them (sampling.py)."""
+    per-token counter key — a verify token j carries steps0+j, the
+    key a plain decode lane has at that token index; a prefill chunk's
+    tokens but the prompt's last carry neutral params and their
+    samples are discarded), and the host fetch is [T] ids + [T]
+    logprobs. Always compiled sample-capable: a greedy lane takes
+    fused_sample's argmax and raw logprob, so greedy and sampled steps
+    share ONE class; the sort and the draw run only in a step where a
+    token asks for them (sampling.py)."""
     import jax.numpy as jnp
 
     from ..core.autograd import no_grad
@@ -2547,8 +2162,9 @@ def _ragged_step_body(model, core, window, tp, ids, positions, pt, cl,
     with no_grad():
         logits = model.lm_head(Tensor(x))._data[0]           # [T, V]
     if tp is not None:
-        # partial (vocab-column-sliced) logits -> replicated before the
-        # fused per-token sampling, same as the bucketed step
+        # lm_head shards the vocab columns: gather the partial
+        # (column-sliced, never partially-summed) logits so the fused
+        # per-token sampling runs replicated — identical to TP=1
         logits = tp.replicate(logits)
     logits = logits.astype(jnp.float32)
     tokens, logprobs = fused_sample(

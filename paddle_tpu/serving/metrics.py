@@ -197,10 +197,10 @@ class ServingMetrics:
         self.prefix_drops = Counter()         # dedup drop_prefix pages
         # decode hot path (round 10)
         self.fetch_bytes = Counter()          # host<-device bytes/steps
-        # round 22 (PR 18, unified ragged step): dispatch accounting —
+        # round 22 (PR 18, the token-packed step): dispatch accounting —
         # every device dispatch / host fetch the engine issues, and the
         # number of distinct compiled program classes behind them. The
-        # ragged path's contract is <= 2 classes and ONE dispatch + ONE
+        # step's contract is <= 2 classes and ONE dispatch + ONE
         # fetch per mixed prefill+decode step.
         self.step_dispatches = Counter()      # device dispatches issued
         self.step_fetches = Counter()         # host<-device fetches

@@ -10,8 +10,8 @@ one dispatch + one fetch.
 
 Design constraints (reproducibility rules):
 
-- Everything here is pure jnp — it traces inside the engine's bucketed
-  step program; per-request ``(seed, step)`` ride as int32 ARGUMENTS,
+- Everything here is pure jnp — it traces inside the engine's step
+  program; per-request ``(seed, step)`` ride as int32 ARGUMENTS,
   so no RNG state is baked into the compiled program and the jit cache
   stays bounded (no per-seed recompiles).
 - The RNG is counter-based: lane i draws from
@@ -34,10 +34,11 @@ Design constraints (reproducibility rules):
   neither in the SAME compiled program a sampled batch uses. Nothing
   may ``vmap`` over :func:`fused_sample`: a ``cond`` under ``vmap``
   becomes a ``select`` and runs both branches.
-- ``sample_capable=False`` (a STATIC python flag at the bucketed
-  engine's jit boundary) still compiles the greedy-only variant with
-  no sort and no conditional in it. The trace cache at most doubles
-  (still bounded by 2 * (log2(max_batch) + 2)).
+- ``sample_capable=False`` (a STATIC python flag) compiles the
+  greedy-only variant with no sort and no conditional in it. The
+  engine's step never uses it (one class for greedy and sampled
+  steps); the draft's proposal scan does, from ``any(r.do_sample)``
+  of its batch, so that scan has at most two classes a bucket.
 
 Filter semantics match the host oracle (`engine._sample`, numpy):
 ``top_k <= 0`` or ``>= V`` disables top-k; ``top_p <= 0`` or ``>= 1``
@@ -52,7 +53,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["filter_binds", "fused_sample", "fused_sample_multi"]
+__all__ = ["filter_binds", "fused_sample"]
 
 
 def _lane_keys(seeds, steps):
@@ -148,32 +149,3 @@ def fused_sample(logits, do_sample, temperature, top_k, top_p, seeds,
     return jax.lax.cond(jnp.any(do_sample), draw,
                         lambda: (greedy, _chosen_logprob(lg, greedy)))
 
-
-def fused_sample_multi(logits, do_sample, temperature, top_k, top_p,
-                       seeds, steps0, *, sample_capable=True):
-    """Per-POSITION fused sampling for the speculative verify step.
-
-    ``logits`` is [B, S, V]; the per-lane sampling params are [B] and
-    broadcast over the S positions; position j of lane i draws with the
-    counter key ``fold_in(PRNGKey(seeds[i]), steps0[i] + j)`` — exactly
-    the key the non-speculative engine would use when sampling that
-    request's token ``steps0[i] + j``. That identity is what makes
-    deterministic-sample verification token-exact vs the plain decode
-    loop: the verify step recomputes the SAME samples the one-token-at-
-    a-time engine would have emitted, and acceptance is a pure prefix
-    match against the draft's proposals.
-
-    Returns ``(tokens int32 [B, S], logprobs float32 [B, S])``.
-    """
-    b, s, _ = logits.shape
-    flat = logits.reshape(b * s, logits.shape[-1])
-
-    def rep(a):
-        return jnp.repeat(a, s, axis=0)
-
-    steps = (steps0[:, None]
-             + jnp.arange(s, dtype=jnp.int32)[None, :]).reshape(-1)
-    tok, lp = fused_sample(flat, rep(do_sample), rep(temperature),
-                           rep(top_k), rep(top_p), rep(seeds), steps,
-                           sample_capable=sample_capable)
-    return tok.reshape(b, s), lp.reshape(b, s)

@@ -88,12 +88,10 @@ _KEEP_FINISHED = 1024
 # are markers, not time owners
 _QUEUE_SPANS = ("queued",)
 _PREFILL_SPANS = ("prefill_chunk",)
-# ragged_round is the unified ragged step's plain-decode span (round
-# 22): same coalescing run_span shape as decode_round, emitted by
-# engine._ragged_step so phase attribution survives the one-dispatch
-# refactor (verify lanes keep spec_round, the prefill lane keeps
-# prefill_chunk/recompute)
-_DECODE_SPANS = ("decode_round", "spec_round", "ragged_round")
+# one step program, one span a lane kind: a plain decode lane's is
+# decode_round, a verify lane's spec_round, the prefill lane's
+# prefill_chunk or recompute
+_DECODE_SPANS = ("decode_round", "spec_round")
 _STALL_SPANS = ("recompute",)
 
 

@@ -107,8 +107,7 @@ def _bench_artifact_guard(request):
                        "TestServingPrefixFleetReplay",
                        "TestServingFleetReplay",
                        "TestServingKvtierReplay",
-                       "TestServingDeployReplay",
-                       "TestServingRaggedReplay")
+                       "TestServingDeployReplay")
     if not any(c in request.node.nodeid for c in _replay_classes):
         yield
         return

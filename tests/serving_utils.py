@@ -40,6 +40,62 @@ def wait_until_reserved(replica, timeout=30.0):
                       msg="replica never reported a reservation")
 
 
+def sequential_oracle(m, prompts, max_new):
+    """Greedy streams from ``model.generate()``, one prompt at a time:
+    the reference that shares no paging, scheduling or sampling code
+    with the engine's step."""
+    import numpy as np
+
+    import paddle_tpu as P
+    return [np.asarray(m.generate(P.to_tensor(p[None]),
+                                  max_new_tokens=max_new)._data)[0]
+            for p in prompts]
+
+
+def serve_streams(eng, prompts, req_kws, max_new, alone=False):
+    """Serve ``prompts`` through ``eng``; returns one ``(tokens,
+    logprob bits)`` pair a request, in order. ``alone`` serves each
+    request to its end before the next is admitted."""
+    import numpy as np
+    events = {}
+    prev = eng.on_event
+
+    def on_event(ev):
+        if ev["type"] == "token":
+            events.setdefault(ev["req_id"], []).append(
+                (int(ev["token"]), ev["logprob"]))
+
+    eng.on_event = on_event
+    try:
+        rids = []
+        for p, kw in zip(prompts, req_kws):
+            rids.append(eng.add_request(p, max_new_tokens=max_new,
+                                        logprobs=True, **kw))
+            if alone:
+                eng.run()
+        eng.run()
+    finally:
+        eng.on_event = prev
+    return [([t for t, _ in events[r]],
+             np.asarray([lp for _, lp in events[r]],
+                        np.float32).view(np.uint32).tolist())
+            for r in rids]
+
+
+def served_alone(m, prompts, req_kws, max_new, **ekw):
+    """The lone-request oracle of the counter-RNG contract: each
+    request served by itself (``max_batch=1``, the whole prompt one
+    prefill chunk, no draft model, nothing to preempt it). Token ``t``
+    is a function of (weights, history, seed, ``t``) and of no
+    schedule, so a crowd, a preemption, a chunk size and a speculative
+    round must each reproduce these tokens and logprob bits."""
+    from paddle_tpu.serving import ServingEngine
+    eng = ServingEngine(m, max_batch=1,
+                        prefill_chunk=max(len(p) for p in prompts),
+                        **{**dict(page_size=4, num_pages=64), **ekw})
+    return serve_streams(eng, prompts, req_kws, max_new, alone=True)
+
+
 def hlo_sorts(hlo_text):
     """``(outside, inside)``: the ``sort`` instructions of an HLO module
     text that run whenever the program runs, and those that run only in

@@ -282,7 +282,7 @@ class TestRaggedStepTail:
         for lyr in model.sublayers(include_self=True):
             lyr.__dict__["_has_lazy_params"] = False
         model.eval()
-        eng = ServingEngine(model, ragged=True, page_size=SZ.page_size,
+        eng = ServingEngine(model, page_size=SZ.page_size,
                             num_pages=16, max_batch=SZ.max_batch,
                             prefill_chunk=SZ.prefill_chunk,
                             max_seq_len=SZ.max_seq_len)

@@ -91,7 +91,7 @@ def served(built):
     rng = np.random.default_rng(3)
     prompts = [rng.integers(0, 320, n).astype(np.int32)
                for n in (19, 7, 30, 12, 9)]     # 30 = four chunks of 8
-    eng, out = serve(model, prompts, 10, ragged=True)
+    eng, out = serve(model, prompts, 10)
     return eng, prompts, out
 
 
@@ -138,11 +138,15 @@ def test_routing_is_counted_on_the_device(served):
     assert rec and all(0 < r["experts_hit"] <= 32 for r in rec)
 
 
-def test_the_bucketed_step_serves_the_same_stream(built, served):
+def test_a_request_served_alone_gets_the_same_stream(built, served):
+    """No schedule in a token: the crowd of five (four chunks of 8 for
+    the longest, decode lanes beside them) gives each request what it
+    gets alone, its prompt one chunk."""
     model, _ = built
     _, prompts, out = served
-    _, again = serve(model, prompts[:2], 10, ragged=False)
-    for a, b in zip(again, out[:2]):
+    for p, b in zip(prompts[:2], out[:2]):
+        _, (a,) = serve(model, [p], 10, max_batch=1,
+                        prefill_chunk=len(p))
         assert [t for t, _ in a] == [t for t, _ in b]
         assert np.allclose([lp for _, lp in a], [lp for _, lp in b],
                            atol=1e-5)
@@ -283,7 +287,7 @@ def test_what_the_latent_pool_does_not_build_is_refused(built, kw, names):
         kw = dict(host_pool=HostPagePool(1 << 20),
                   prefix_cache=True)
     with pytest.raises(NotImplementedError, match=names):
-        ServingEngine(model, ragged=True, **{**ENGINE, **kw})
+        ServingEngine(model, **{**ENGINE, **kw})
 
 
 def test_page_shipping_is_refused_by_name(served):

@@ -18,6 +18,7 @@ from paddle_tpu.serving import (EngineDraining, FaultInjected,
                                 RequestState, Scheduler, ServingEngine,
                                 ServingMetrics, paged_attention,
                                 paged_attention_ref)
+from serving_utils import sequential_oracle
 
 
 def tiny_model(seed=0, **kw):
@@ -256,12 +257,15 @@ class TestPagedAttention:
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=1e-5)
 
-    def test_engine_prefill_logits_match_contiguous_cache(self):
+    @pytest.mark.parametrize("n", [9, 11])
+    def test_engine_prefill_logits_match_contiguous_cache(self, n):
         """Acceptance: paged logits vs the contiguous static-cache
-        oracle (models/generation.py path) to 1e-5."""
+        oracle (models/generation.py path) to 1e-5; the prompt's last
+        chunk of 4 holds one token or three (the probe reads the
+        chunk's LAST row of the packed step, not its first)."""
         from paddle_tpu.core.tensor import Tensor
         m = tiny_model(seed=4)
-        prompt = np.random.default_rng(4).integers(0, 97, 9).astype(
+        prompt = np.random.default_rng(4).integers(0, 97, n).astype(
             np.int32)
         caches = m._init_caches(1, len(prompt))
         ref_logits, _ = m._forward_cached(Tensor(prompt[None]), caches, 0)
@@ -359,12 +363,6 @@ class TestScheduler:
 # engine end-to-end
 
 
-def _sequential_oracle(m, prompts, max_new):
-    return [np.asarray(m.generate(P.to_tensor(p[None]),
-                                  max_new_tokens=max_new)._data)[0]
-            for p in prompts]
-
-
 class TestEngineE2E:
     def test_8way_continuous_batching_matches_sequential(self):
         """Acceptance: 8 concurrent requests, batched decode tokens
@@ -377,7 +375,7 @@ class TestEngineE2E:
                             prefill_chunk=8)
         rids = [eng.add_request(p, max_new_tokens=6) for p in prompts]
         res = eng.run()
-        oracle = _sequential_oracle(m, prompts, 6)
+        oracle = sequential_oracle(m, prompts, 6)
         for rid, want in zip(rids, oracle):
             np.testing.assert_array_equal(res[rid]["tokens"], want)
         ex = eng.metrics.export()
@@ -403,7 +401,7 @@ class TestEngineE2E:
         res = eng.run()
         assert eng.metrics.preemptions.value > 0, \
             "config failed to force preemption"
-        oracle = _sequential_oracle(m, prompts, 12)
+        oracle = sequential_oracle(m, prompts, 12)
         for rid, want in zip(rids, oracle):
             np.testing.assert_array_equal(res[rid]["tokens"], want)
 
@@ -664,7 +662,7 @@ class TestServingSweep:
                      "snapshot_weights", "DistillBuffer",
                      "DraftDistiller", "distill_buffer_from_env"):
             assert name in sv.__all__, name
-        # round-22 ragged step surface
+        # round-22: the step's token-packed attention entry
         assert "ragged_paged_attention" in sv.__all__
         # round-23 tensor-parallel surface
         import paddle_tpu.serving.tp  # noqa: F401
@@ -700,8 +698,10 @@ class TestServingSweep:
                      "cache", "scheduler", "cancel", "drain",
                      "start_drain", "draining", "release_live",
                      "on_event", "request", "draft", "spec_k",
-                     "ragged", "tp_degree", "tp_mesh_shape"):
+                     "tp_degree", "tp_mesh_shape"):
             assert hasattr(eng, attr), attr
+        # one step: the switch is gone with the path it selected
+        assert not hasattr(eng, "ragged")
         # TP off by default: degree 1, no mesh advertised
         assert eng.tp_degree == 1 and eng.tp_mesh_shape is None
 
@@ -818,9 +818,7 @@ class TestServingSweep:
                      "PADDLE_TPU_SERVING_DEPLOY_DRAIN_S",
                      "PADDLE_TPU_SERVING_DISTILL",
                      "PADDLE_TPU_SERVING_DISTILL_BUFFER",
-                     "PADDLE_TPU_SERVING_DISTILL_HIST",
-                     # round-22 ragged step knob
-                     "PADDLE_TPU_SERVING_RAGGED"):
+                     "PADDLE_TPU_SERVING_DISTILL_HIST"):
             assert knob in doc, knob
 
 

@@ -586,7 +586,7 @@ class TestPrefixSamplingSweep:
         m = tiny_model(seed=15)
         eng = ServingEngine(m, page_size=4, num_pages=32, max_batch=2,
                             prefill_chunk=8)
-        for attr in ("_build_decode_batch", "_release_waiting_pins",
+        for attr in ("_run_ragged_step", "_release_waiting_pins",
                      "_host_sampling", "_fetch_logits",
                      "_sync_prefix_metrics"):
             assert hasattr(eng, attr), attr

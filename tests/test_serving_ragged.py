@@ -3,8 +3,8 @@
 One token-packed program class for mixed prefill+decode+verify batches:
 ``ragged_paged_attention`` packs every lane's query tokens into a [T]
 axis with per-lane ``(query_len, context_len)`` metadata, and the
-engine's ``ragged=True`` path rides a prefill chunk, the decode batch,
-and speculative verify slots on ONE dispatch + ONE host fetch per step.
+engine's step rides a prefill chunk, the decode batch, and speculative
+verify slots on ONE dispatch + ONE host fetch per step.
 
 Oracle discipline (SURVEY.md §4): the ragged entry is pinned per-lane to
 ``paged_attention_ref`` (the gather oracle that is itself pinned to the
@@ -13,13 +13,12 @@ of the K/V VALUE range, round-15 addenda); the interpret-mode Pallas
 kernel is pinned to the ragged reference INCLUDING the exact bench
 shape (interpret mode only: the chip's compiler refuses the kernel as
 written, tests/test_aot_tpu_compile.py records it).
-Engine exactness is the hard gate: ragged streams must be token-exact
-vs the bucketed engine for greedy AND seeded counter-RNG sampling,
-under preemption, chunked prefill, and speculative decoding (self-draft
-accepts 100%).
+Engine exactness is the hard gate: greedy streams are those of
+``model.generate()`` one request at a time, and every stream, greedy or
+seeded, is the one its request gets served alone (tokens and logprob
+bits), in a crowd, under preemption, at any prefill chunk, and through
+speculative rounds (self-draft accepts 100%).
 """
-import os
-
 import numpy as np
 import pytest
 
@@ -32,6 +31,8 @@ from paddle_tpu.serving import (ServingEngine, paged_attention,
                                 paged_attention_ref,
                                 ragged_paged_attention)
 from paddle_tpu.serving.attention import quantize_q8
+from serving_utils import (sequential_oracle, serve_streams,
+                           served_alone)
 
 
 def tiny_model(seed=0, **kw):
@@ -91,7 +92,7 @@ def _ragged_case(lane_spec, nh=4, nkv=2, d=8, page_size=4, num_pages=64,
 
 def _per_lane_ref(kp, vp, pt, cl, ql, qoff, lane_q, scale, window=None):
     """The oracle: each lane independently through paged_attention_ref
-    at [1, ql] — the shape the bucketed engine would use."""
+    at [1, ql], one rectangular call a lane."""
     outs = []
     for i, qi in enumerate(lane_q):
         o = paged_attention_ref(
@@ -183,7 +184,7 @@ class TestRaggedKernelInterpret:
     def test_kernel_exact_bench_shape(self, monkeypatch):
         """Round-3b addenda: a small-shape smoke does NOT clear a
         kernel config — validate the EXACT shape the bench dispatches.
-        bench_serving --ragged geometry: 8 decode lanes + one
+        bench_serving.py's engine geometry: 8 decode lanes + one
         32-token prefill chunk -> T=40 packed tokens, 9 lanes,
         page_size 16, 4 heads, head_dim 32."""
         spec = [(33 + 2 * i, 1) for i in range(8)] + [(48, 32)]
@@ -217,17 +218,31 @@ class TestRaggedKernelInterpret:
 
 
 # ---------------------------------------------------------------------------
-# engine: ragged step token-exact vs the bucketed engine
+# engine: the step's streams against oracles that share none of its code
+# (greedy: model.generate() one at a time) or none of its schedule
+# (seeded: the same request served alone)
+
+ENG_KW = dict(page_size=4, num_pages=200, max_batch=4, prefill_chunk=8)
 
 
 def run_fleet(m, prompts, req_kws, max_new=6, **ekw):
-    kw = dict(page_size=4, num_pages=200, max_batch=4, prefill_chunk=8)
-    kw.update(ekw)
-    eng = ServingEngine(m, **kw)
+    eng = ServingEngine(m, **{**ENG_KW, **ekw})
     rids = [eng.add_request(p, max_new_tokens=max_new, **r)
             for p, r in zip(prompts, req_kws)]
     res = eng.run()
     return [list(map(int, res[r]["tokens"])) for r in rids], eng
+
+
+def crowd(m, prompts, req_kws, max_new, **ekw):
+    eng = ServingEngine(m, **{**ENG_KW, **ekw})
+    return serve_streams(eng, prompts, req_kws, max_new), eng
+
+
+def assert_greedy_is_generate(m, prompts, req_kws, got, max_new):
+    rows = [i for i, kw in enumerate(req_kws) if not kw.get("do_sample")]
+    want = sequential_oracle(m, [prompts[i] for i in rows], max_new)
+    for i, w in zip(rows, want):
+        assert got[i][0] == list(map(int, w)), i
 
 
 MIXED_REQ = [dict(), dict(do_sample=True, temperature=0.9, seed=7),
@@ -241,9 +256,9 @@ class TestRaggedEngine:
         rng = np.random.default_rng(0)
         prompts = [rng.integers(0, 97, int(rng.integers(3, 14)))
                    .astype(np.int32) for _ in range(6)]
-        base, _ = run_fleet(m, prompts, MIXED_REQ)
-        got, eng = run_fleet(m, prompts, MIXED_REQ, ragged=True)
-        assert base == got
+        got, eng = crowd(m, prompts, MIXED_REQ, 6)
+        assert got == served_alone(m, prompts, MIXED_REQ, 6)
+        assert_greedy_is_generate(m, prompts, MIXED_REQ, got, 6)
         assert eng.metrics.step_program_classes.value <= 2, \
             eng._program_classes
 
@@ -255,13 +270,13 @@ class TestRaggedEngine:
         m = tiny_model(seed=1)
         prompts = [np.random.default_rng(1).integers(0, 97, 3)
                    .astype(np.int32) for _ in range(4)]
-        kws = [dict()] * 4
-        base, _ = run_fleet(m, prompts, kws, max_new=12, num_pages=10)
-        got, eng = run_fleet(m, prompts, kws, max_new=12, num_pages=10,
-                             ragged=True)
+        kws = [dict(), dict(do_sample=True, top_k=7, seed=2), dict(),
+               dict(do_sample=True, temperature=1.2, seed=9)]
+        got, eng = crowd(m, prompts, kws, 12, num_pages=10)
         assert eng.metrics.preemptions.value > 0, \
             "config failed to force preemption"
-        assert base == got
+        assert got == served_alone(m, prompts, kws, 12)
+        assert_greedy_is_generate(m, prompts, kws, got, 12)
 
     def test_prefill_chunk_invariance(self):
         m = tiny_model(seed=2)
@@ -270,23 +285,22 @@ class TestRaggedEngine:
         outs = []
         for chunk in (2, 5, 16):
             got, _ = run_fleet(m, [prompt], [dict()], max_new=6,
-                               prefill_chunk=chunk, ragged=True)
+                               prefill_chunk=chunk)
             outs.append(got[0])
         assert outs[0] == outs[1] == outs[2]
 
     def test_speculative_self_draft_exact_full_acceptance(self):
-        """Verify slots ride the same ragged dispatch; deterministic-
-        sample matching means a self-draft must accept 100% and the
-        streams stay exact vs the bucketed spec engine."""
+        """Verify slots ride the same dispatch; deterministic-sample
+        matching means a self-draft must accept 100% and every stream
+        is the one its request gets served alone with no draft."""
         m = tiny_model(seed=2)
         prompts = [np.random.default_rng(2).integers(0, 97, 5)
                    .astype(np.int32) for _ in range(3)]
         kws = [dict(), dict(do_sample=True, seed=5), dict()]
-        base, _ = run_fleet(m, prompts, kws, max_new=8, draft_model=m,
-                            speculative_k=3)
-        got, eng = run_fleet(m, prompts, kws, max_new=8, draft_model=m,
-                             speculative_k=3, ragged=True)
-        assert base == got
+        got, eng = crowd(m, prompts, kws, 8, draft_model=m,
+                         speculative_k=3)
+        assert got == served_alone(m, prompts, kws, 8)
+        assert_greedy_is_generate(m, prompts, kws, got, 8)
         ex = eng.metrics.export()
         assert ex["spec_draft_tokens"] > 0
         assert ex["spec_accepted_tokens"] == ex["spec_draft_tokens"]
@@ -295,15 +309,41 @@ class TestRaggedEngine:
         assert eng.metrics.step_program_classes.value <= 2, \
             eng._program_classes
 
+    @pytest.mark.parametrize("mode", ["int8_kv", "prefix_cache",
+                                      "sliding_window"])
+    def test_modes_serve_the_lone_requests_streams(self, mode):
+        """The modes the step carries, each under page pressure in a
+        crowd of greedy and seeded lanes: quantize-on-append pools, a
+        shared prefix served from the radix tree, a window that
+        binds."""
+        m = tiny_model(seed=3, **(dict(sliding_window=6)
+                                  if mode == "sliding_window" else {}))
+        rng = np.random.default_rng(3)
+        shared = rng.integers(0, 97, 9).astype(np.int32)
+        prompts = [np.concatenate([shared, rng.integers(
+            0, 97, int(rng.integers(1, 9))).astype(np.int32)])
+            for _ in range(5)]
+        kws = MIXED_REQ[:5]
+        ekw = dict(int8_kv=dict(cache_dtype="int8"),
+                   prefix_cache=dict(prefix_cache=True),
+                   sliding_window={})[mode]
+        got, eng = crowd(m, prompts, kws, 16, num_pages=20, **ekw)
+        assert eng.metrics.preemptions.value > 0
+        if mode == "prefix_cache":
+            assert eng.metrics.prefix_hit_pages.value > 0
+            ekw = {}                 # alone, nothing to share it with
+        assert got == served_alone(m, prompts, kws, 16, **ekw)
+        assert eng.metrics.step_program_classes.value <= 2
+
     def test_mixed_step_one_dispatch_one_fetch(self):
-        """The acceptance criterion, asserted by the new metrics: a
-        step carrying a prefill chunk AND decode lanes issues ONE
-        dispatch + ONE host fetch (per-dispatch fixed cost ~0.79 of a
-        small CPU step — FEASIBILITY.md)."""
+        """The acceptance criterion, asserted by the metrics: a step
+        carrying a prefill chunk AND decode lanes issues ONE dispatch +
+        ONE host fetch (per-dispatch fixed cost ~0.79 of a small CPU
+        step — FEASIBILITY.md), and a whole run compiles at most two
+        program classes."""
         m = tiny_model()
         rng = np.random.default_rng(3)
-        eng = ServingEngine(m, page_size=4, num_pages=200, max_batch=4,
-                            prefill_chunk=8, ragged=True)
+        eng = ServingEngine(m, **ENG_KW)
         eng.add_request(rng.integers(0, 97, 4).astype(np.int32),
                         max_new_tokens=10)
         eng.step()                       # short prompt finishes prefill
@@ -323,50 +363,24 @@ class TestRaggedEngine:
                 assert eng.metrics.step_fetches.value - f0 == 1
         assert mixed > 0, "no mixed prefill+decode step occurred"
         eng.run()
-        assert eng.metrics.step_program_classes.value <= 2
-
-    def test_bucketed_path_counts_more_classes(self):
-        """The win the gauge makes observable: the same workload on the
-        bucketed path compiles strictly more step program classes."""
-        m = tiny_model()
-        rng = np.random.default_rng(4)
-        prompts = [rng.integers(0, 97, int(rng.integers(3, 14)))
-                   .astype(np.int32) for _ in range(6)]
-        _, beng = run_fleet(m, prompts, [dict()] * 6)
-        _, reng = run_fleet(m, prompts, [dict()] * 6, ragged=True)
-        assert reng.metrics.step_program_classes.value <= 2
-        assert beng.metrics.step_program_classes.value \
-            > reng.metrics.step_program_classes.value
-        ex = reng.metrics.export()
+        ex = eng.metrics.export()
         assert ex["step_dispatches"] > 0
         assert ex["step_program_classes"] <= 2
 
-    def test_ragged_env_knob(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_SERVING_RAGGED", "1")
-        eng = ServingEngine(tiny_model(), page_size=4, num_pages=32,
-                            max_batch=2, prefill_chunk=8)
-        assert eng.ragged
-        monkeypatch.setenv("PADDLE_TPU_SERVING_RAGGED", "0")
-        eng = ServingEngine(tiny_model(), page_size=4, num_pages=32,
-                            max_batch=2, prefill_chunk=8)
-        assert not eng.ragged
-
-
-@pytest.mark.slow
-class TestServingRaggedReplay:
-    def test_bench_ragged_smoke_subprocess(self):
-        """bucketed-vs-ragged replay through the repo-root driver
-        (slow: tier-1 runs it via tools/ragged_smoke.sh; the smoke
-        never writes BENCH_serving_ragged.json)."""
-        import json
-        import subprocess
-        import sys
-        root = os.path.join(os.path.dirname(__file__), "..")
-        p = subprocess.run(
-            [sys.executable, "bench_serving.py", "--smoke", "--ragged"],
-            cwd=root, capture_output=True, text=True, timeout=600)
-        assert p.returncode == 0, p.stderr[-2000:]
-        out = json.loads(p.stdout.strip().splitlines()[-1])
-        assert out["metric"].startswith("serving_ragged_speedup")
-        assert out["token_exact_vs_bucketed"] is True
-        assert out["ragged_step_program_classes"] <= 2
+    def test_the_keyword_selects_nothing(self):
+        """``ragged=`` outlives the switch only because the benchmark's
+        drivers pass it: True and absent build the same engine, False
+        names the step that is gone."""
+        m = tiny_model()
+        with pytest.raises(ValueError, match="removed in PR 29"):
+            ServingEngine(m, ragged=False, **ENG_KW)
+        prompts = [np.arange(3, 12, dtype=np.int32)]
+        kws = [dict(do_sample=True, top_p=0.9, seed=4)]
+        a, ea = crowd(m, prompts, kws, 5)
+        b, eb = crowd(m, prompts, kws, 5, ragged=True)
+        assert a == b
+        for attr in ("_ragged_lanes", "_ragged_tok_small",
+                     "_ragged_tok_mixed"):
+            assert getattr(ea, attr) == getattr(eb, attr), attr
+        assert ea._program_classes == eb._program_classes
+        assert not hasattr(ea, "ragged")

@@ -3,10 +3,10 @@
 ``fused_sample`` sorts under a condition on the batch's own sampling
 arguments, and once. Pinned here: (a) the values, bit for bit against a
 frozen copy of the two-sort formula the sampler had, alone and through
-each engine; (b) the ragged step's program holds no ``sort`` outside a
+the engine; (b) the step's program holds no ``sort`` outside a
 ``conditional``; (c) the host-side counter of the steps that sort;
-(d) the readers of the ragged step's logits (a fork at prefill
-completion, the host-sampling oracle) beside a chunk in the step.
+(d) the readers of the step's logits (a fork at prefill completion,
+the host-sampling oracle) beside a chunk in the step.
 """
 import functools
 
@@ -19,7 +19,8 @@ import jax.numpy as jnp
 from paddle_tpu.serving import ServingEngine, sampling
 from paddle_tpu.serving.sampling import _lane_keys, fused_sample
 
-from serving_utils import hlo_sorts, ragged_step_avals, ragged_step_fn
+from serving_utils import (hlo_sorts, ragged_step_avals, ragged_step_fn,
+                           served_alone)
 from test_serving_ragged import run_fleet, tiny_model
 
 
@@ -140,13 +141,13 @@ def test_sampler_program_sorts_once_and_under_a_condition():
 
 
 # ---------------------------------------------------------------------------
-# (b) the ragged step's program
+# (b) the step's program
 
 
 def _engine(m, **kw):
     return ServingEngine(m, **{**dict(page_size=4, num_pages=200,
-                                      max_batch=4, prefill_chunk=8,
-                                      ragged=True), **kw})
+                                      max_batch=4, prefill_chunk=8),
+                               **kw})
 
 
 @pytest.mark.parametrize("mixed", [False, True],
@@ -183,19 +184,15 @@ def _prompts(seed, sizes):
     return [rng.integers(0, 97, n).astype(np.int32) for n in sizes]
 
 
-@pytest.mark.parametrize("ragged", [True, False],
-                         ids=["ragged", "bucketed"])
-def test_greedy_run_never_sorts(ragged):
-    eng = _engine(tiny_model(), ragged=ragged)
+def test_greedy_run_never_sorts():
+    eng = _engine(tiny_model())
     for p in _prompts(0, (19, 5, 11)):
         eng.add_request(p, max_new_tokens=6)
     steps = _drive(eng)
     ex = eng.metrics.export()
-    # a bucketed step may dispatch a chunk and a decode batch
-    assert 6 < len(steps) <= ex["step_dispatches"]
+    assert 6 < len(steps) == ex["step_dispatches"]
     assert sum(steps) == ex["sampler_sort_steps"] == 0
-    if ragged:
-        assert ex["step_program_classes"] == 2    # both capacities ran
+    assert ex["step_program_classes"] == 2        # both capacities ran
 
 
 def test_one_top_p_lane_sorts_in_the_steps_its_sample_is_read():
@@ -223,47 +220,49 @@ def test_one_top_p_lane_sorts_in_the_steps_its_sample_is_read():
 
 
 # ---------------------------------------------------------------------------
-# (d) the readers of the ragged step's logits, beside a chunk
+# (d) the readers of the step's logits, beside a chunk
 
 
 FORK_REQ = [dict(), dict(do_sample=True, temperature=0.9, seed=7, n=3),
             dict(do_sample=True, top_p=0.8, seed=11), dict()]
 
 
-def _fork_fleet(m, **ekw):
+def test_fork_at_prefill_completion_is_token_exact_beside_a_chunk():
     """A decode lane is running when the forking request's last chunk
-    arrives, so that chunk's last token is not the step's first."""
+    arrives, so that chunk's last token is not the step's first. A
+    child is its parent's request with the seed one further: each of
+    the six streams is the one that request gets served alone."""
+    m = tiny_model(seed=3)
     eng = ServingEngine(m, page_size=4, num_pages=200, max_batch=6,
-                        prefill_chunk=8, **ekw)
+                        prefill_chunk=8)
     prompts = _prompts(5, (5, 13, 21, 7))
-    eng.add_request(prompts[0], max_new_tokens=10, **FORK_REQ[0])
+    eng.add_request(prompts[0], max_new_tokens=6, **FORK_REQ[0])
     eng.step()
     for p, kw in zip(prompts[1:], FORK_REQ[1:]):
         eng.add_request(p, max_new_tokens=6, **kw)
     res = eng.run()
-    return [list(map(int, res[r]["tokens"])) for r in sorted(res)], eng
-
-
-def test_fork_at_prefill_completion_is_token_exact_beside_a_chunk():
-    m = tiny_model(seed=3)
-    base, _ = _fork_fleet(m)
-    got, eng = _fork_fleet(m, ragged=True)
-    assert len(base) == len(FORK_REQ) + 2          # the two children
-    assert len({tuple(s) for s in base[1:4]}) > 1  # which diverge
-    assert base == got
+    got = [list(map(int, res[r]["tokens"])) for r in sorted(res)]
+    assert len(got) == len(FORK_REQ) + 2           # the two children
+    child = {k: v for k, v in FORK_REQ[1].items() if k != "n"}
+    alone = served_alone(
+        m, prompts + [prompts[1]] * 2,
+        FORK_REQ[:1] + [child] + FORK_REQ[2:]
+        + [dict(child, seed=8), dict(child, seed=9)], 6)
+    assert got == [toks for toks, _ in alone]
+    assert len({tuple(got[i]) for i in (1, 4, 5)}) > 1   # they diverge
     assert eng.metrics.step_program_classes.value <= 2
 
 
 def test_host_sampling_oracle_is_token_exact_beside_a_chunk(
         monkeypatch):
     """Greedy is exact between the host oracle and the device sampler;
-    the oracle reads ``logits[offset]`` of the ragged step's [T, V]."""
+    the oracle reads ``logits[offset]`` of the step's [T, V]."""
     m = tiny_model(seed=4)
     prompts = _prompts(6, (5, 21, 13, 15))    # last chunks of 5-7
     kws = [dict()] * 4
     base, _ = run_fleet(m, prompts, kws, max_new=7)
     monkeypatch.setenv("PADDLE_TPU_SERVING_HOST_SAMPLE", "1")
-    got, eng = run_fleet(m, prompts, kws, max_new=7, ragged=True)
+    got, eng = run_fleet(m, prompts, kws, max_new=7)
     assert base == got
     # what the oracle fetched: the step's [T, 97] floats, whole
     assert eng.metrics.fetch_bytes.value % (97 * 4 * 4) == 0
@@ -271,7 +270,7 @@ def test_host_sampling_oracle_is_token_exact_beside_a_chunk(
 
 
 # ---------------------------------------------------------------------------
-# (a) again, through the engines: the streams and their log-probabilities
+# (a) again, through the engine: the streams and their log-probabilities
 # with the frozen formula in the sampler's place
 
 
@@ -311,8 +310,9 @@ def _streams(m, **ekw):
 
 
 @pytest.mark.parametrize("ekw", [
-    dict(), dict(ragged=True), dict(ragged=True, speculative_k=3)],
-    ids=["bucketed", "ragged", "ragged_speculative"])
+    dict(), dict(speculative_k=3), dict(cache_dtype="int8"),
+    dict(prefix_cache=True)],
+    ids=["plain", "speculative", "int8_kv", "prefix_cache"])
 def test_engine_streams_are_the_two_sort_samplers(ekw, monkeypatch):
     m = tiny_model(seed=5)
     if "speculative_k" in ekw:

@@ -201,9 +201,9 @@ class TestAllocatorConservationFuzz:
 
 class TestVerifyOracle:
     def test_extend_logits_match_sequential_decode(self):
-        """The [1, k+1] verify step's per-position logits equal k+1
+        """A verify lane's k+1 packed tokens get the logits of k+1
         sequential single-token decode steps over the paged cache at
-        1e-5 — the extend program class IS the verify oracle."""
+        1e-5 — the step's own rows ARE the verify oracle."""
         m = tiny_model(seed=4)
         k = 3
         prompt = np.random.default_rng(4).integers(0, 97, 7).astype(
@@ -225,11 +225,61 @@ class TestVerifyOracle:
         while not any(e["type"] == "token" for e in evs):
             evs += spec.step()             # prefill emits token 1
         spec.step()                        # first draft/verify round
-        ml = np.asarray(spec._logits_dev, np.float32)   # [B, k+1, V]
-        assert ml.ndim == 3 and ml.shape[1] == k + 1
+        ml = np.asarray(spec._logits_dev, np.float32)   # [T, V]
+        # the lone verify lane packs first: rows 0..k are its tokens
+        # (k+1 of them fit the all-decode capacity)
+        assert ml.shape[0] == spec._ragged_tok_small >= k + 1
         for j in range(k + 1):
-            np.testing.assert_allclose(ml[0, j], seq_logits[1 + j],
+            np.testing.assert_allclose(ml[j], seq_logits[1 + j],
                                        atol=1e-5)
+
+
+class TestDraftCatchup:
+    def test_catchup_is_the_trunk_and_writes_the_targets_kv(self):
+        """The draft's catchup program is its trunk over one
+        rectangular chunk: it returns the draft's pools and nothing
+        else (no head, no sample), and with a self-draft the K/V it
+        writes for a history is what the target's step wrote."""
+        import jax
+
+        from paddle_tpu.serving import engine as eng_mod
+        m = tiny_model(seed=6)
+        prompt = np.random.default_rng(6).integers(0, 97, 11).astype(
+            np.int32)                     # two catchup chunks of 8
+        eng = ServingEngine(m, draft_model=m, speculative_k=2,
+                            **ENG_KW)
+        rid = eng.add_request(prompt, max_new_tokens=6)
+        evs = []
+        while not any(e["type"] == "token" for e in evs):
+            evs += eng.step()             # prefill emits token 1
+        d0 = eng.metrics.step_dispatches.value
+        eng.step()                        # catchup, propose, verify
+        # two catchup chunks + the proposal scan + the step
+        assert eng.metrics.step_dispatches.value - d0 == 4
+        assert eng.metrics.step_program_classes.value <= 2
+        sid = eng.request(rid).seq_id
+        n = prompt.size                   # history but its last token
+        tc, dc = eng.cache, eng._draft_cache
+        tpt = tc.page_table(sid, eng.max_pages_per_seq)
+        dpt = dc.page_table(sid, eng.max_pages_per_seq)
+        pos = np.arange(n)
+        for pools_t, pools_d in ((tc.k_pages, dc.k_pages),
+                                 (tc.v_pages, dc.v_pages)):
+            for pt_, pd_ in zip(pools_t, pools_d):
+                want = np.asarray(pt_)[tpt[pos // 4], pos % 4]
+                got = np.asarray(pd_)[dpt[pos // 4], pos % 4]
+                assert np.abs(want).max() > 0
+                np.testing.assert_allclose(got, want, atol=1e-6)
+        k_ops, v_ops = dc.program_operands()
+        i32 = lambda *sh: jax.ShapeDtypeStruct(sh, np.int32)  # noqa: E731
+        out = jax.eval_shape(
+            lambda *a: eng_mod._draft_catchup_pure(
+                m, eng._draft_core, None, *a),
+            [t._data for t in m._gen_state_tensors()], i32(1, 8),
+            i32(1, 8), i32(1, eng.max_pages_per_seq), i32(1), i32(1, 8),
+            k_ops, v_ops)
+        assert [[a.shape for a in pools] for pools in out] == \
+            [[a.shape for a in k_ops], [a.shape for a in v_ops]]
 
 
 # ---------------------------------------------------------------------------

@@ -323,16 +323,16 @@ class TestFlightRecorder:
         eng = make_engine()
         fe = ServingFrontend(eng)
         boom = RuntimeError("forced decode failure")
-        orig = eng._plain_decode
+        orig = eng._ragged_step
 
-        def exploding(reqs, events):
-            if any(r.out_tokens for r in reqs):
+        def exploding(out, events):
+            if any(r.out_tokens for r in out.decode):
                 # the first token lands at prefill completion, so this
                 # fires on the request's FIRST decode round
                 raise boom
-            return orig(reqs, events)
+            return orig(out, events)
 
-        eng._plain_decode = exploding
+        eng._ragged_step = exploding
         with caplog.at_level(logging.ERROR, "paddle_tpu.serving"):
             fe.start()
             stream = fe.submit(rng_prompts(1)[0], max_new_tokens=8)

@@ -29,10 +29,6 @@ bash tools/kvtier_smoke.sh || exit 1
 # kill, version-pinned exactness + distill acceptance gates —
 # runtime-bounded, CPU-only; never banks BENCH_serving_deploy.json.
 bash tools/deploy_smoke.sh || exit 1
-# ragged smoke (ISSUE 18): bucketed-vs-ragged step replay, token-exact
-# + <= 2 step program classes — runtime-bounded, CPU-only; never banks
-# BENCH_serving_ragged.json.
-bash tools/ragged_smoke.sh || exit 1
 # tp smoke (ISSUE 19): TP=1 vs TP=2 SPMD step replay on the 8-device
 # CPU mesh, token-exact across degrees — runtime-bounded, CPU-only;
 # never banks BENCH_serving_tp.json.
