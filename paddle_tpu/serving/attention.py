@@ -11,7 +11,7 @@ Two paths, selected by ``PADDLE_TPU_PAGED_KERNEL``:
   oracle-comparable against the contiguous static-cache path to 1e-5.
 - ``PADDLE_TPU_PAGED_KERNEL=1`` — ONE unified ragged Pallas kernel
   (round 18, replacing the decode-only S=1 stub): the grid streams over
-  packed query TOKENS, each grid cell resolving its own lane's
+  packed query TOKENS, each grid cell handed its own lane's
   (page_table row, context_len, absolute position), so decode lanes
   (q=1), prefill chunks, and speculative-verify bursts (q=k+1) all run
   through the same program. INTERPRET MODE, CPU ONLY: the knob raises on
@@ -23,14 +23,16 @@ Two paths, selected by ``PADDLE_TPU_PAGED_KERNEL``:
   respect the O(block) VMEM invariant (ROADMAP S4).
 
 :func:`ragged_paged_attention` is the token-packed entry point
-(PAPERS.md "Ragged Paged Attention"): ``q [T, H, D]`` carries the
-concatenated query tokens of L lanes, each lane with its own
-``(query_len, context_len, q_offset)``; padding tokens (beyond
-``sum(query_lens)``) attend position 0 of the last lane — garbage but
-NaN-free, masked out by the caller. :func:`paged_attention` keeps the
-rectangular [B, S] surface and, under the kernel gate, routes through
-the SAME ragged kernel (row b = one lane of query_len S) — one gated
-kernel, not two.
+(PAPERS.md "Ragged Paged Attention"): ``q [T, H, D]`` carries the query
+tokens of L lanes in two STATIC regions — a rectangle of ``k1`` rows a
+lane for the decode/verify lanes, then the prefill chunk's rows for the
+last lane — so a row's place says its lane and each lane's padded page
+table is gathered once a call, not once a token (PR 30: a 72-token step
+gathered 72 tables where 9 lanes own 9). Rows past a lane's query_len
+are padding: their output is zero, and the caller discards it.
+:func:`paged_attention` keeps the rectangular [B, S] surface and, under
+the kernel gate, routes through the SAME ragged kernel (row b = one
+lane of query_len S) — one gated kernel, not two.
 
 Both paths accept GQA natively (query heads grouped over KV heads, no
 materialized head repeat) and a Mistral-style sliding ``window``.
@@ -50,6 +52,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = ["paged_attention", "paged_attention_ref",
            "ragged_paged_attention", "quantize_q8"]
@@ -115,56 +118,122 @@ def paged_attention(q, k_pages, v_pages, page_table, context_lens,
                                window=window)
 
 
-def _token_lanes(query_lens, q_offsets, t):
-    """Token-packed lane resolution: map packed query index -> (lane,
-    absolute position). Padding tokens (index >= sum(query_lens)) clamp
-    to the last lane at position 0 — their row only needs to be NaN-free
-    (every lane keeps context_len >= 1 by the engine's padding
-    contract), the caller discards the output."""
-    ql = query_lens.astype(jnp.int32)
-    ends = jnp.cumsum(ql)
-    tok = jnp.arange(t, dtype=jnp.int32)
-    lane = jnp.searchsorted(ends, tok, side="right").astype(jnp.int32)
-    lane = jnp.minimum(lane, ql.shape[0] - 1)
-    pos = q_offsets[lane].astype(jnp.int32) + tok - (ends - ql)[lane]
-    pos = jnp.where(tok < ends[-1], pos, 0)
-    return lane, pos
+def _regions(lanes, t, k1=1):
+    """``(n_rect, r)`` of the step's static layout: the first ``n_rect
+    = lanes - 1`` lanes own ``k1`` rows each, ``r = n_rect * k1`` rows
+    in all (the rectangle); the last lane owns rows ``[r, t)`` (the
+    chunk; none in the decode-only class)."""
+    n_rect = lanes - 1
+    r = n_rect * k1
+    if t < r:
+        raise ValueError(f"{t} packed rows cannot hold {n_rect} lanes "
+                         f"of {k1} rows (the chunk's rows come behind)")
+    return n_rect, r
+
+
+def tables_gathered(lanes, t, k1=1):
+    """Page tables one :func:`ragged_paged_attention` call over ``t``
+    packed rows gathers: one a lane of the rectangle, one more where
+    the chunk has rows. What the engine's ``attn_pages_gathered``
+    counts, kept beside the code that gathers."""
+    n_rect, r = _regions(lanes, t, k1)
+    return n_rect + (t > r)
+
+
+def _rows_of_lanes(x, t, k1=1):
+    """``x [L, ...]`` per lane -> ``[t, ...]`` per packed row, by the
+    step's static layout (:func:`_regions`). A row's lane is its
+    place, so this is a repeat and a broadcast, never a gather."""
+    n_rect, r = _regions(x.shape[0], t, k1)
+    rect = jnp.repeat(x[:n_rect], k1, axis=0) if k1 > 1 else x[:n_rect]
+    chunk = jnp.broadcast_to(x[n_rect], (t - r,) + x.shape[1:])
+    return jnp.concatenate([rect, chunk])
+
+
+def _row_index(lanes, t, k1=1):
+    """``[t]`` int32 (static): a packed row's index within its lane."""
+    n_rect, r = _regions(lanes, t, k1)
+    return np.concatenate([np.tile(np.arange(k1), n_rect),
+                           np.arange(t - r)]).astype(np.int32)
+
+
+def _rows_live(query_lens, t, k1=1):
+    """``[t]`` bool: a packed row is live if it lies inside its lane's
+    ``query_len``; every other row is padding, whose output
+    :func:`ragged_paged_attention` zeroes and the caller discards."""
+    return (_row_index(query_lens.shape[0], t, k1)
+            < _rows_of_lanes(query_lens.astype(jnp.int32), t, k1))
+
+
+def _row_positions(query_lens, q_offsets, t, k1=1):
+    """``[t]`` int32: a live row's absolute position (its lane's
+    offset + its index in the lane), 0 for a padding row: what the
+    per-row kernel attends from (every lane keeps context_len >= 1 by
+    the engine's padding contract, so key 0 is visible to it)."""
+    return jnp.where(
+        _rows_live(query_lens, t, k1),
+        _rows_of_lanes(q_offsets.astype(jnp.int32), t, k1)
+        + _row_index(query_lens.shape[0], t, k1), 0)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table,
                            context_lens, query_lens, q_offsets, *,
-                           scale, window=None, spmd=False):
+                           scale, window=None, spmd=False, k1=1):
     """Token-packed mixed-batch paged attention (one program for
     decode + prefill + verify lanes).
 
-    q [T, H, D] — lane-major packed query tokens (lane 0's query_lens[0]
-    tokens, then lane 1's, ...; trailing padding up to T);
+    q [T, H, D] — two static regions (:func:`_rows_of_lanes`): lane
+    ``i < L - 1`` owns rows ``[i*k1, (i+1)*k1)`` (a decode lane fills
+    one, a verify lane up to ``k1 = speculative_k + 1``), the last lane
+    — the prefill chunk — rows ``[(L-1)*k1, T)``; ``T == (L-1)*k1`` is
+    the decode-only class, with no chunk rows. Rows past a lane's
+    ``query_lens`` are padding;
     page_table [L, P] int32 per LANE (pad = scratch page 0);
     context_lens [L] int32 — valid K tokens per lane INCLUDING any just
     scattered (>= 1 even for padded lanes); query_lens [L] int32 (0 for
     padded lanes); q_offsets [L] int32 — absolute position of each
     lane's first query token. Returns [T, H, D] in q.dtype; padding
-    rows are garbage but finite.
+    rows are zero.
 
-    Default path delegates to :func:`paged_attention_ref` with one row
-    per token (the oracle — identical einsums/mask, so GQA, sliding
-    window, and the int8 (codes, scales) tuple layout are inherited);
-    ``PADDLE_TPU_PAGED_KERNEL=1`` runs the unified interpret-mode
-    Pallas kernel on the same per-token expansion; ``spmd=True``
-    (tensor-parallel step) overrides the knob and stays on the ref
-    path — no Pallas under GSPMD.
+    A row's place says its lane, so the default path gathers each
+    lane's padded page table ONCE: two rectangular calls of
+    :func:`paged_attention_ref` (the oracle — identical einsums/mask,
+    so GQA, sliding window, and the int8 (codes, scales) tuple layout
+    are inherited), ``[L-1, k1]`` over the rectangle and ``[1, T -
+    (L-1)*k1]`` over the chunk. ``PADDLE_TPU_PAGED_KERNEL=1`` runs the
+    unified interpret-mode Pallas kernel on the per-token expansion of
+    the same layout; ``spmd=True`` (tensor-parallel step) overrides the
+    knob and stays on the ref path — no Pallas under GSPMD.
     """
-    t = q.shape[0]
-    lane, pos = _token_lanes(query_lens, q_offsets, t)
-    pt_tok = page_table[lane]
-    cl_tok = context_lens[lane].astype(jnp.int32)
+    t, nh, d = q.shape
+    n_rect, r = _regions(page_table.shape[0], t, k1)
+    cl = context_lens.astype(jnp.int32)
+    qoff = q_offsets.astype(jnp.int32)
     if not spmd and _kernel_requested():
-        return _ragged_attention_kernel(q, k_pages, v_pages, pt_tok,
-                                        cl_tok, pos, scale=scale,
-                                        window=window)
-    return paged_attention_ref(q[:, None], k_pages, v_pages, pt_tok,
-                               cl_tok, pos, scale=scale,
-                               window=window)[:, 0]
+        out = _ragged_attention_kernel(
+            q, k_pages, v_pages, _rows_of_lanes(page_table, t, k1),
+            _rows_of_lanes(cl, t, k1),
+            _row_positions(query_lens, q_offsets, t, k1),
+            scale=scale, window=window)
+    else:
+        parts = []
+        if r:
+            parts.append(paged_attention_ref(
+                q[:r].reshape(n_rect, k1, nh, d), k_pages, v_pages,
+                page_table[:n_rect], cl[:n_rect], qoff[:n_rect],
+                scale=scale, window=window).reshape(r, nh, d))
+        if t > r:
+            parts.append(paged_attention_ref(
+                q[r:][None], k_pages, v_pages, page_table[n_rect:],
+                cl[n_rect:], qoff[n_rect:], scale=scale,
+                window=window)[0])
+        out = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+    # the one rule for padding rows: their output is zero. (In the
+    # rectangular calls such a row sits at its lane's offset + index,
+    # where a sliding window can leave it no visible key: an all-masked
+    # softmax, which the select discards.)
+    return jnp.where(_rows_live(query_lens, t, k1)[:, None, None],
+                     out, 0)
 
 
 def paged_attention_ref(q, k_pages, v_pages, page_table, context_lens,

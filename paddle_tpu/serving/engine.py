@@ -8,10 +8,13 @@ TPU-natively (SURVEY.md §7 static-shape stance):
   token each), speculative-verify lanes (up to k+1) and the prefill
   chunk are packed along one token axis (``attention.py::
   ragged_paged_attention`` lane layout) and run as one dispatch with
-  one host fetch. Lanes are always ``max_batch + 1``; the token
-  capacity is one of TWO static shapes (``max_batch`` for an all-decode
-  step, ``max_batch * (speculative_k + 1) + prefill_chunk`` otherwise),
-  so the engine compiles at most two programs in its lifetime.
+  one host fetch. Lanes are always ``max_batch + 1``; a decode/verify
+  lane owns ``k1 = speculative_k + 1`` rows of a static rectangle and
+  the chunk (the last lane) the rows behind it, so a row's place says
+  its lane and each lane's page table is gathered once a layer. The
+  token capacity is one of TWO static shapes (``max_batch * k1`` for a
+  step with no chunk, ``max_batch * k1 + prefill_chunk`` with one), so
+  the engine compiles at most two programs in its lifetime.
 - Weights enter every compiled step as ARGUMENTS, never baked constants
   (the round-3 HTTP-413 lesson in models/generation.py): weight updates
   flow through with NO recompile and NO stale-constant hazard, and the
@@ -346,14 +349,14 @@ class ServingEngine:
         self._ragged_fn = None        # one jit fn; <= 2 token shapes
         self._ragged_bufs = {}        # per-capacity persistent buffers
         # static geometry: L lanes always (max_batch decode/verify + 1
-        # prefill); token capacity is one of TWO shapes — all-decode
-        # steps pack into max_batch tokens, anything with a prefill
-        # chunk or verify bursts pads to the mixed capacity. That pins
+        # prefill), k1 = speculative_k + 1 rows a decode/verify lane;
+        # token capacity is one of TWO shapes — a step with no prefill
+        # chunk packs into the max_batch * k1 rows of the rectangle,
+        # a step with one adds the chunk's rows behind it. That pins
         # the compiled-program-class count at <= 2.
         self._ragged_lanes = max_batch + 1
-        self._ragged_tok_small = max_batch
-        self._ragged_tok_mixed = (max_batch * (self.spec_k + 1)
-                                  + prefill_chunk)
+        self._ragged_tok_small = max_batch * (self.spec_k + 1)
+        self._ragged_tok_mixed = self._ragged_tok_small + prefill_chunk
         self._program_classes = set()  # static shape keys dispatched
         self.metrics = ServingMetrics()
         # always-on span timeline + flight recorder (round 16): every
@@ -1159,7 +1162,9 @@ class ServingEngine:
         """ONE token-packed dispatch for the whole step: plain decode
         lanes (q=1), speculative-verify lanes (q=k+1), and the prefill
         chunk ride a single compiled program over the
-        ``ragged_paged_attention`` lane layout — one dispatch + one
+        ``ragged_paged_attention`` lane layout (lane i's tokens at
+        rows [i*k1, (i+1)*k1), the chunk's behind the rectangle; rows
+        a lane leaves unused are padding) — one dispatch + one
         host fetch per step (FEASIBILITY.md: per-dispatch overhead
         ~0.79 of a small CPU step; not measured on a chip). A token's
         counter-RNG key is (seed, token-index) and knows no schedule,
@@ -1243,15 +1248,19 @@ class ServingEngine:
         props = None
         if spec_active:
             props = self._stage_draft_propose(spec_active)
-        # 6. pack the token batch. Two static token capacities only
-        # (see __init__): a step fits the small all-decode shape or
-        # pads to the mixed one.
+        # 6. pack the token batch into the step's two static regions:
+        # decode/verify lane i owns rows [i*k1, (i+1)*k1), the chunk
+        # (always the last lane) the rows from chunk_off on, so a
+        # row's place says its lane and the device gathers a lane's
+        # page table once. Two static token capacities only (see
+        # __init__): a step with no chunk takes the decode class.
         n_tok = (sum(a[2] for a in spec_active) + len(plain_active)
                  + (pf[4] if pf is not None else 0))
-        tcap = (self._ragged_tok_small
-                if n_tok <= self._ragged_tok_small
+        chunk_lane = self._ragged_lanes - 1
+        chunk_off = chunk_lane * k1
+        assert len(spec_active) + len(plain_active) <= chunk_lane
+        tcap = (self._ragged_tok_small if pf is None
                 else self._ragged_tok_mixed)
-        assert n_tok <= tcap, (n_tok, tcap)
         b = self._ragged_bufs.get(tcap)
         if b is None:
             nl = self._ragged_lanes
@@ -1288,10 +1297,10 @@ class ServingEngine:
             b["seeds"][:] = 0
             b["steps"][:] = 0
         lane = 0
-        off = 0
         emit_spec = []                    # (req, hist0, n_slots, i, off)
         for i, (r, hist0, n_slots, tslots, dslots) in \
                 enumerate(spec_active):
+            off = lane * k1
             b["pt"][lane] = self.cache.page_table(r.seq_id, mp)
             b["cl"][lane] = hist0 - 1 + n_slots
             b["ql"][lane] = n_slots
@@ -1315,9 +1324,9 @@ class ServingEngine:
                 n_slots, dtype=np.int32)
             emit_spec.append((r, hist0, n_slots, i, off))
             lane += 1
-            off += n_slots
         emit_plain = []                                  # (req, off)
         for r, slot in plain_active:
+            off = lane * k1
             hist_len = r.prompt.size + len(r.out_tokens)
             b["pt"][lane] = self.cache.page_table(r.seq_id, mp)
             b["cl"][lane] = hist_len
@@ -1334,15 +1343,14 @@ class ServingEngine:
             b["steps"][off] = len(r.out_tokens)
             emit_plain.append((r, off))
             lane += 1
-            off += 1
         pf_off = None
         if pf is not None:
             req, start, end, chunk, n, pslots = pf
-            b["pt"][lane] = self.cache.page_table(req.seq_id, mp)
-            b["cl"][lane] = start + n
-            b["ql"][lane] = n
-            b["qoff"][lane] = start
-            sl = slice(off, off + n)
+            b["pt"][chunk_lane] = self.cache.page_table(req.seq_id, mp)
+            b["cl"][chunk_lane] = start + n
+            b["ql"][chunk_lane] = n
+            b["qoff"][chunk_lane] = start
+            sl = slice(chunk_off, chunk_off + n)
             b["ids"][0, sl] = chunk
             b["positions"][0, sl] = start + np.arange(n,
                                                       dtype=np.int32)
@@ -1353,7 +1361,7 @@ class ServingEngine:
             # chunk asks the sampler for no sort and no draw; every
             # other token keeps the neutral params and its greedy
             # output is discarded
-            pf_off = off + n - 1
+            pf_off = chunk_off + n - 1
             if end >= req.prompt.size + len(req.out_tokens):
                 b["do_sample"][pf_off] = req.do_sample
                 b["temperature"][pf_off] = req.temperature
@@ -1361,8 +1369,6 @@ class ServingEngine:
                 b["top_p"][pf_off] = req.top_p
                 b["seeds"][pf_off] = req.device_seed
                 b["steps"][pf_off] = len(req.out_tokens)
-            lane += 1
-            off += n
         # 7. ONE dispatch, ONE [T]+[T] host fetch
         tok_d, lp_d = self._run_ragged_step(
             b["ids"], b["positions"], b["pt"], b["cl"], b["ql"],
@@ -1370,6 +1376,14 @@ class ServingEngine:
             (b["do_sample"], b["temperature"], b["top_k"], b["top_p"],
              b["seeds"], b["steps"]))
         self._logits_row = pf_off if pf_off is not None else 0
+        # what the padded tables cost: page-table entries a layer's
+        # attention gathered in this step's class (counted where the
+        # gather is: _step_tables_gathered) against the pages its live
+        # lanes hold
+        self.metrics.attn_pages_gathered.inc(mp * _step_tables_gathered(
+            self._core, self._ragged_lanes, tcap, k1))
+        self.metrics.attn_pages_live.inc(int(np.sum(
+            -(-b["cl"][b["ql"] > 0] // self.cache.page_size))))
         if spec_active:
             self.metrics.spec_rounds.inc()
             self.metrics.spec_draft_tokens.inc(
@@ -1461,7 +1475,7 @@ class ServingEngine:
         if self.trace.enabled:
             self.trace.flight.record(
                 "ragged_step", tokens=int(n_tok), cap=int(tcap),
-                lanes=int(lane), spec=len(emit_spec),
+                lanes=int(lane) + (pf is not None), spec=len(emit_spec),
                 plain=len(emit_plain),
                 prefill=(pf[0].req_id if pf is not None else None),
                 experts_hit=experts_hit)
@@ -1899,7 +1913,8 @@ class ServingEngine:
             # argmax and the raw logprob either way.
             self._ragged_fn = jax.jit(
                 functools.partial(_ragged_step_pure, self.model,
-                                  self._core, self.window, self._tp))
+                                  self._core, self.window, self._tp,
+                                  k1=self.spec_k + 1))
         warrs = [t._data for t in self.model._gen_state_tensors()]
         k_ops, v_ops = self.cache.program_operands()
         tok, lp, logits, k_pages, v_pages, moe_counts = self._ragged_fn(
@@ -1958,15 +1973,16 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
                    k_pages, v_pages, ragged=None, tp=None, stats=None):
     """The transformer trunk over the paged cache: embed, attend (K/V
     scattered into the page pool), final norm. The step runs it with
-    ``ragged=(query_lens, q_offsets)``, the token-packed lane layout:
+    ``ragged=(query_lens, q_offsets, k1)``, the token-packed lane
+    layout (``k1`` rows a decode/verify lane, then the chunk's rows):
     ids/positions/slot_map are [1, T] (the scatter is shape-agnostic)
-    while pt/cl are the [L, P]/[L] PER-LANE arrays. ``ragged=None``,
-    the rectangular [B, S] form over ``paged_attention``, is the draft
-    model's alone (catchup prefill and proposal scan): until the
-    packed layout stops gathering a padded page table per token, a
-    catchup chunk of c tokens would gather c tables where the
-    rectangle gathers one. Returns ``(hidden [B, S, D] jnp array,
-    new_k, new_v)``.
+    while pt/cl are the [L, P]/[L] PER-LANE arrays, each lane's table
+    gathered once a layer. ``ragged=None``, the rectangular [B, S]
+    form over ``paged_attention``, is the draft model's alone (catchup
+    prefill and proposal scan); the packed layout gathers by lane too
+    since PR 30, so the draft could take it at no extra gather
+    (ROADMAP D-queue; not switched). Returns ``(hidden [B, S, D] jnp
+    array, new_k, new_v)``.
 
     ``tp`` (a :class:`~.tp.TPContext`) makes the trunk ONE SPMD
     program over the mesh.  The constraints below are the whole
@@ -2008,8 +2024,9 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
             x = Tensor(tp.replicate(x._data))
         pos_t = Tensor(positions)
         new_k, new_v = [], []
-        if hasattr(core.layers[0], "paged_forward"):
-            per_tok = _per_token_tables(b * s, pt, cl, *ragged)
+        if _brings_paged_forward(core):
+            ql, _, k1 = ragged
+            per_tok = _per_token_tables(b * s, pt, cl, ql, k1)
             for layer, pool in zip(core.layers, k_pages):
                 x, pool = layer.paged_forward(x, positions, pool,
                                               flat_slots, *per_tok,
@@ -2073,11 +2090,11 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
                     q._data, kp, vp, pt, cl, positions[:, 0],
                     scale=1.0 / (hd ** 0.5), window=window, spmd=spmd)
             else:
-                ql, qoff = ragged
+                ql, qoff, k1 = ragged
                 out = ragged_paged_attention(
                     q._data[0], kp, vp, pt, cl, ql, qoff,
                     scale=1.0 / (hd ** 0.5), window=window,
-                    spmd=spmd)[None]
+                    spmd=spmd, k1=k1)[None]
             ao = Tensor(out).reshape([b, s, nh * hd])
             if spmd:
                 # o_proj contracts over the head dim — gather the
@@ -2101,24 +2118,43 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
     return x._data, new_k, new_v
 
 
-def _per_token_tables(t, pt, cl, ql, qoff):
+def _brings_paged_forward(core):
+    """Whether the layers attend through their own ``paged_forward``
+    over per-row tables (:func:`_per_token_tables`) and not through
+    ``ragged_paged_attention`` over per-lane ones."""
+    return hasattr(core.layers[0], "paged_forward")
+
+
+def _step_tables_gathered(core, lanes, t, k1):
+    """Page tables a layer's attention gathers in a step of ``t``
+    packed rows, by the branch :func:`_paged_forward` takes: one a
+    lane and region (``attention.py::tables_gathered``), or one a row
+    where the layers bring their own ``paged_forward`` and attend with
+    :func:`_per_token_tables` (ROADMAP R3)."""
+    from .attention import tables_gathered
+    return (t if _brings_paged_forward(core)
+            else tables_gathered(lanes, t, k1))
+
+
+def _per_token_tables(t, pt, cl, ql, k1=1):
     """``(pt_tok [t, P], cl_tok [t], valid [t])``: each packed token's
     page-table row, the keys it may see and whether it is a real
-    token -- what a layer's own ``paged_forward`` attends with."""
+    token -- what a layer's own ``paged_forward`` attends with. A
+    row's lane is its place in the step's two regions, so the tables
+    are repeats and broadcasts (``attention.py::_rows_of_lanes``)."""
     import jax.numpy as jnp
 
-    from .attention import _token_lanes
-    lane, _ = _token_lanes(ql, qoff, t)
-    valid = jnp.arange(t, dtype=jnp.int32) < jnp.sum(
-        ql.astype(jnp.int32))
-    return pt[lane], cl[lane].astype(jnp.int32), valid
+    from .attention import _rows_live, _rows_of_lanes
+    return (_rows_of_lanes(pt, t, k1),
+            _rows_of_lanes(cl.astype(jnp.int32), t, k1),
+            _rows_live(ql, t, k1))
 
 
 # -- the step program (round 22 / PR 18) -----------------------------------
 
 def _ragged_step_pure(model, core, window, tp, warrs, ids, positions,
                       pt, cl, ql, qoff, slot_map, samp, k_pages,
-                      v_pages):
+                      v_pages, k1=1):
     tensors = model._gen_state_tensors()
     saved = [(t, t._data) for t in tensors]
     for t, arr in zip(tensors, warrs):
@@ -2126,16 +2162,17 @@ def _ragged_step_pure(model, core, window, tp, warrs, ids, positions,
     try:
         return _ragged_step_body(model, core, window, tp, ids,
                                  positions, pt, cl, ql, qoff, slot_map,
-                                 samp, k_pages, v_pages)
+                                 samp, k_pages, v_pages, k1)
     finally:
         for t, arr in saved:
             t._data = arr
 
 
 def _ragged_step_body(model, core, window, tp, ids, positions, pt, cl,
-                      ql, qoff, slot_map, samp, k_pages, v_pages):
-    """The token-packed step: the trunk runs at [1, T], lm_head +
-    fused sampling cover EVERY packed token (each with its own
+                      ql, qoff, slot_map, samp, k_pages, v_pages, k1=1):
+    """The token-packed step: the trunk runs at [1, T] (``k1`` rows a
+    decode/verify lane, then the chunk's rows: ``_ragged_step``),
+    lm_head + fused sampling cover EVERY packed row (each with its own
     per-token counter key — a verify token j carries steps0+j, the
     key a plain decode lane has at that token index; a prefill chunk's
     tokens but the prompt's last carry neutral params and their
@@ -2152,7 +2189,7 @@ def _ragged_step_body(model, core, window, tp, ids, positions, pt, cl,
     stats = []
     x, new_k, new_v = _paged_forward(core, window, ids, positions, pt,
                                      cl, slot_map, k_pages, v_pages,
-                                     ragged=(ql, qoff), tp=tp,
+                                     ragged=(ql, qoff, k1), tp=tp,
                                      stats=stats)
     # sparse-expert layers' routing counts of this step, summed over
     # layers: int32 [4] (MOE_COUNTS), None for a model without them

@@ -209,6 +209,12 @@ class ServingMetrics:
         # sampling row with a binding top-k or top-p (the sampler's
         # sort ran), counted on the host from the arrays it packs
         self.sampler_sort_steps = Counter()
+        # what the padded page tables cost (PR 30), counted on the host
+        # from the arrays it packs: page-table entries the step's
+        # attention gathers (tables gathered x table width) against the
+        # pages its live lanes hold (ceil(context_len / page_size))
+        self.attn_pages_gathered = Counter()
+        self.attn_pages_live = Counter()
         self.prefix_hit_pages = Counter()     # prompt pages served from
         self.prefix_miss_pages = Counter()    # the radix tree vs prefilled
         self.prefix_evictions = Counter()     # cached pages LRU-reclaimed

@@ -88,12 +88,45 @@ def served_alone(m, prompts, req_kws, max_new, **ekw):
     prefill chunk, no draft model, nothing to preempt it). Token ``t``
     is a function of (weights, history, seed, ``t``) and of no
     schedule, so a crowd, a preemption, a chunk size and a speculative
-    round must each reproduce these tokens and logprob bits."""
+    round must each reproduce these tokens and logprob bits (to the
+    bit where the token's products keep their row counts, within
+    :func:`assert_streams_within_ulps` where they do not)."""
     from paddle_tpu.serving import ServingEngine
     eng = ServingEngine(m, max_batch=1,
                         prefill_chunk=max(len(p) for p in prompts),
                         **{**dict(page_size=4, num_pages=64), **ekw})
     return serve_streams(eng, prompts, req_kws, max_new, alone=True)
+
+
+def logprob_ulps(got, want):
+    """The largest distance in f32 ulps between the log-probabilities
+    of two ``serve_streams`` results, over each request's tokens up to
+    the first that differs (``None`` where there are none): the bit
+    patterns, read as sign and magnitude, order as the values do."""
+    import numpy as np
+
+    def ordered(bits):
+        b = np.asarray(bits, np.int64)
+        return np.where(b >> 31, -(b & 0x7FFFFFFF), b)
+
+    worst = None
+    for (ta, a), (tb, b) in zip(got, want):
+        n = next((i for i, (x, y) in enumerate(zip(ta, tb)) if x != y),
+                 min(len(ta), len(tb)))
+        if n:
+            d = int(np.abs(ordered(a[:n]) - ordered(b[:n])).max())
+            worst = d if worst is None else max(worst, d)
+    return worst
+
+
+def assert_streams_within_ulps(got, want, ulps):
+    """Two ``serve_streams`` results: the tokens exactly, the
+    log-probabilities within ``ulps`` f32 ulps. For the comparisons in
+    which a token meets products of another row count (see
+    ``tests/test_serving_ragged.py::CROSS_SHAPE_ULPS``); every other
+    comparison of streams is ``==`` on tokens and logprob bits."""
+    assert [t for t, _ in got] == [t for t, _ in want]
+    assert logprob_ulps(got, want) <= ulps
 
 
 def hlo_sorts(hlo_text):
@@ -166,4 +199,4 @@ def ragged_step_fn(engine):
     from paddle_tpu.serving import engine as eng_mod
     return jax.jit(functools.partial(
         eng_mod._ragged_step_pure, engine.model, engine._core,
-        engine.window, None))
+        engine.window, None, k1=engine.spec_k + 1))

@@ -238,6 +238,36 @@ class TestServingAttention:
             i32(lanes), i32(lanes), i32(lanes))
         assert _kernels(c) == 0      # a gather, not a kernel (ROADMAP S4)
 
+    def test_ragged_gather_is_a_lanes_not_a_tokens(self, one):
+        """The chunk-carrying class at the pool geometry: a lane's page
+        table is gathered once, so the chip's compiler leaves no array
+        led by T x pages (the per-token form's gathered pages and their
+        f32 copies: ``f32[4608,16,32,128]`` in the backlog cell's
+        traces to PR 29), and the call's temporaries are under a
+        quarter of that form's."""
+        from paddle_tpu.serving.attention import (paged_attention_ref,
+                                                  ragged_paged_attention)
+        t, lanes, pages, pool, i32 = self._operands(one)
+
+        def per_token(q, kp, vp, pt, cl, ql, qo):
+            lane = jnp.minimum(jnp.arange(t), lanes - 1)
+            return paged_attention_ref(
+                q[:, None], kp, vp, pt[lane], cl[lane], qo[lane],
+                scale=D ** -0.5)[:, 0]
+
+        def by_lane(q, kp, vp, pt, cl, ql, qo):
+            return ragged_paged_attention(q, kp, vp, pt, cl, ql, qo,
+                                          scale=D ** -0.5)
+
+        old, new = (_compile(
+            f, _sds((t, H, D), BF16, one), pool, pool, i32(lanes, pages),
+            i32(lanes), i32(lanes), i32(lanes)) for f in (per_token,
+                                                          by_lane))
+        assert f"[{t * pages}," in old.as_text()
+        assert f"[{t * pages}," not in new.as_text()
+        assert (new.memory_analysis().temp_size_in_bytes * 4
+                < old.memory_analysis().temp_size_in_bytes)
+
     @pytest.mark.xfail(strict=True, raises=ValueError,
                        reason="Mosaic refuses the ragged paged kernel as "
                               "written: (1, P) page-table block not "
