@@ -6,7 +6,9 @@ benchmark that submits and then blocks on its stream, stamping each token
 event on the client's clock. An open loop starts a caller when its request
 is due on the mix's schedule, whatever the engine does (a ``submit`` that
 waits for the front-end holds up no other request); a closed loop's
-callers each send their next request when the last one has finished.
+callers each send their next request when the last one has finished, and
+every round is ordered: caller ``k`` of ``C`` sends the plan's requests
+``k, k + C, k + 2C, ...``, and the first round is admitted in that order.
 """
 from __future__ import annotations
 
@@ -86,13 +88,14 @@ class Load:
         self.fe, self.plan, self.mix = frontend, plan, mix
         self.records: list[ReqRecord] = []
         self.errors: list[str] = []
-        self._plan_lock = threading.Lock()
         self._stop = threading.Event()
         self._give_up_at = float("inf")
         self._callers: list[threading.Thread] = []
-        if mix["loop"] == "closed":
-            self._threads = [self._thread(self._closed_caller)
-                             for _ in range(int(mix["clients"]))]
+        self.closed = mix["loop"] == "closed"
+        if self.closed:
+            n = int(mix["clients"])
+            self._threads = [self._thread(self._closed_caller, k, n)
+                             for k in range(n)]
         else:
             self._threads = [self._thread(self._open_schedule)]
 
@@ -107,13 +110,24 @@ class Load:
             self.errors.append(f"{fn.__name__}: {e!r}")
 
     def start(self):
+        """A closed loop's first round is admitted in the plan's order: a
+        caller is started when the one before it has been admitted.
+        Started together, the callers wait for the front-end's lock while
+        the loop thread steps, and the lock hands them over in no order:
+        which of the first sizes hold the lanes then differs from run to
+        run, and with it the window's share of chunk steps (measured,
+        PERF.md section 6, PR 27: ten runs of one mix read 578-583
+        tokens/s eight times and 569, 573 twice, by that alone)."""
+        sched = self.fe.engine.scheduler
         self.t_start = clock()
-        for t in self._threads:
+        for i, t in enumerate(self._threads):
             t.start()
-
-    def _planned(self):
-        with self._plan_lock:
-            return self.plan.next()
+            # no request of the first round finishes inside it (at the
+            # rehearsal's sizes one may: the deadline lets the next start)
+            give_up = clock() + 2.0
+            while (self.closed and clock() < give_up and not self.errors
+                   and len(sched.waiting) + len(sched.live_requests()) <= i):
+                time.sleep(0.0005)
 
     # -- one request, from its caller's thread ------------------------------------
     def _call(self, planned, due):
@@ -151,7 +165,7 @@ class Load:
     # -- open loop: independent users on the mix's schedule -----------------------
     def _open_schedule(self):
         while True:
-            planned = self._planned()
+            planned = self.plan.next()
             due = self.t_start + planned.due
             if self._stop.wait(max(0.0, due - clock())):
                 return
@@ -160,10 +174,14 @@ class Load:
             t.start()
 
     # -- closed loop: callers that wait for their answer --------------------------
-    def _closed_caller(self):
+    def _closed_caller(self, k: int, n: int):
+        """Caller ``k`` of ``n``: its requests are the plan's ``k, k + n,
+        k + 2n, ...`` whatever order the others finish in."""
+        index = k
         while not self._stop.is_set():
-            if self._call(self._planned(), clock()).error:
+            if self._call(self.plan.at(index), clock()).error:
                 self._stop.wait(0.01)       # refused: ask again, not spin
+            index += n
 
     def finish(self, wait_s: float) -> float:
         """Stop sending, wait for every open request (at most ``wait_s``

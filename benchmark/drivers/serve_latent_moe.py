@@ -8,11 +8,11 @@ and the routing counters beside the engine's others.
 from __future__ import annotations
 
 import gc
-import time
 
 from ..harness import check, check_latent_moe, traffic, weights_latent_moe
 from ..harness.window import Run, Tracer, sleep_until
 from .serve import COUNTERS, Load, clock, sweep_view, warm_up
+
 
 def build_model(cfg: dict, seed: int):
     """(model, the benchmark's weights): described under ``LazyGuard``,
@@ -43,29 +43,6 @@ def build_model(cfg: dict, seed: int):
     return model, w
 
 
-class OrderedLoad(Load):
-    """``Load`` whose closed loop's first round is admitted in the plan's
-    order: a caller is started when the one before it has been admitted.
-    Started together, 48 callers wait for the front-end's lock while the
-    loop thread steps, and the lock hands them over in no order: which 32
-    of the first 48 sizes hold the lanes then differs from run to run,
-    and with it the window's share of chunk steps (measured, PERF.md
-    section 6: ten runs of one mix read 578-583 tokens/s eight times and
-    569, 573 twice, by that alone)."""
-
-    def start(self):
-        sched = self.fe.engine.scheduler
-        self.t_start = clock()
-        for i, t in enumerate(self._threads):
-            t.start()
-            # no request of the first round finishes inside it (at the
-            # rehearsal's sizes one may: the deadline lets the next start)
-            give_up = clock() + 2.0
-            while (clock() < give_up and not self.errors
-                   and len(sched.waiting) + len(sched.live_requests()) <= i):
-                time.sleep(0.0005)
-
-
 def _snapshot(engine) -> dict:
     m = engine.metrics
     snap = {k: float(getattr(m, k).value) for k in COUNTERS + m.MOE_COUNTS}
@@ -92,7 +69,7 @@ def run(cell, args, ctx) -> dict:
     seconds = float(args.seconds)
     if args.trace:
         seconds = min(seconds, float(mix.get("trace_seconds", 6.0)))
-    load = OrderedLoad(frontend, plan, mix)
+    load = Load(frontend, plan, mix)
 
     run_ = Run(cfg=cfg, mix=mix, peaks=ctx["peaks"], chips=cell.chips)
     load.start()

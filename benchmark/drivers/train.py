@@ -10,11 +10,18 @@ optimizer's state after the first and after the last of them, and hands
 the same object and the same feed to the window. The mix makes one
 optimizer step a call: the state after step 1 is then the state after the
 window's own first call, and no second program is built for the check.
+
+The window dispatches ``calls_ahead`` calls ahead of the one it waits for
+(some seconds of steps: a host that stands still for a second or two
+leaves the device fed) and reads losses after it. It closes so: when its
+time is up nothing more is sent, all that was sent is waited for, and the
+clock is read after that wait.
 """
 from __future__ import annotations
 
 import gc
 import time
+from collections import deque
 
 import numpy as np
 
@@ -204,18 +211,25 @@ def run(cell, args, ctx) -> dict:
     ctx["compiles"].mark()
     tracer.start()
     run_.t0 = t_first = clock()
-    outs, in_flight = [], None
+    # every step sent counts, over all the time to the last one's end
+    ahead = int(mix["calls_ahead"])
+    outs, sent, t_full = [], deque(), None
     while clock() - t_first < seconds:
         with annotate("data_fetch"):
             xs = next(feed)
         with annotate("train_batch_loop"):
             out = m.train_batch_loop([xs], [xs])
-        if in_flight is not None:       # one call ahead of the device
-            with annotate("wait_previous_call"):
-                in_flight._data.block_until_ready()
-        in_flight = out
+        sent.append(out)
         outs.append(out)
-    in_flight._data.block_until_ready()
+        if len(sent) > ahead:
+            if t_full is None:
+                t_full = clock()
+            with annotate("wait_previous_call"):
+                sent.popleft()._data.block_until_ready()
+    t_sent = clock()
+    with annotate("wait_all_sent"):
+        for out in sent:
+            out._data.block_until_ready()
     run_.t1 = t_last = clock()
     tracer.stop()
     run_.compiles_in_window = ctx["compiles"].since_mark()
@@ -229,12 +243,17 @@ def run(cell, args, ctx) -> dict:
     run_.trace = tracer.reduce(cell.chips)
 
     feed.close()
-    del m, model, opt, feed, in_flight, out, outs, xs
+    del m, model, opt, feed, sent, out, outs, xs
     gc.collect()
     numbers = compare(prog, cfg, mix, args.seed, n_check,
                       control=cfg.get("control_precision", "int8")
                       if ctx.get("control") else None)
     bad = int(np.sum(~np.isfinite(losses)))      # steps whose loss is no number
     numbers["window_losses_not_finite"] = float(bad)
+    numbers["_info"] = {      # how far ahead the host got, and how soon
+        "steps": calls * n_call, "calls_ahead": ahead,
+        "ahead_full_after_s": None if t_full is None else t_full - t_first,
+        "sent_all_after_s": t_sent - t_first,
+        "window_s": t_last - t_first}
     return {"run": run_, "numbers": numbers, "attempted": calls * n_call,
             "failed": bad}

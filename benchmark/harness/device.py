@@ -25,12 +25,18 @@ PEAKS = {
 }
 
 
-def require(chips: int, rehearse: bool) -> tuple[dict, dict | None]:
+def require(chips: int, rehearse: bool,
+            client_options: dict | None = None) -> tuple[dict, dict | None]:
     """(device record, peaks). Exits non-zero, printing no result, when
     JAX finds no accelerator of the table or fewer chips than the cell
     asks for. ``rehearse`` lets the CPU tests through: peaks are then
-    ``None`` and no device metric is ever computed."""
+    ``None`` and no device metric is ever computed. ``client_options``
+    (a mix's, e.g. how many computations the runtime lets a process have
+    in flight) are laid over the PJRT client's own before it is made."""
     import jax
+    if client_options and not rehearse:
+        jax.config.update("jax_pjrt_client_create_options", laid_over(
+            jax.config.jax_pjrt_client_create_options, client_options))
     if rehearse:
         jax.config.update("jax_platforms", "cpu")
         try:
@@ -54,6 +60,15 @@ def require(chips: int, rehearse: bool) -> tuple[dict, dict | None]:
                  f"reports {len(devs)}")
     record["count"] = chips
     return record, PEAKS[d.device_kind]
+
+
+def laid_over(have: str | dict | None, more: dict) -> dict:
+    """The client's options as JAX holds them (``"k1:v1;k2:v2"``, a dict
+    or nothing) with a mix's laid over them. A mix's values keep their
+    type: the client takes a whole number only as one."""
+    if isinstance(have, str):
+        have = dict(o.split(":", 1) for o in have.split(";") if o)
+    return {**(have or {}), **more}
 
 
 def memory_peak_bytes(chips: int) -> int:

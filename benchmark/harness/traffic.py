@@ -22,6 +22,16 @@ pool takes z at the ``pool`` evenly spaced quantiles of N(0,1), so a small
 pool still stands for the whole distribution, and pairs prompt and output
 lengths in an order drawn from ``pool_seed``. Size the pool to about what
 one window consumes: then every run does nearly the same work.
+
+A planned request is a function of (mix, seed, index) alone
+(``Plan.at(i)``). An open loop takes them in order, each at its due
+time (``next()``). A closed loop deals them out: caller ``k`` of ``C``
+sends the requests ``k, k + C, k + 2C, ...`` whatever the others do, and
+the first round is admitted in index order (``drivers/serve.py::Load``).
+So a caller's work is its own in every run. (What a closed-loop cell's
+rate still follows is the host's speed: a step 5 % longer sends 5 %
+fewer requests in the window and delivers 5 % fewer tokens, PERF.md
+section 6, PR 32.)
 """
 from __future__ import annotations
 
@@ -79,35 +89,43 @@ def gap_pool(mix: dict) -> np.ndarray:
 
 class Plan:
     """An endless sequence of planned requests: the size pool and the gap
-    pool in their own order, cycled; token ids from ``seed``."""
+    pool in their own order, cycled; token ids from ``seed`` and the
+    request's index, so that ``at(i)`` is the same whoever asks, and
+    whenever."""
 
     def __init__(self, mix: dict, seed: int, vocab: int, max_seq_len: int):
         self.mix, self.vocab = mix, int(vocab)
-        self.rng = np.random.default_rng([int(seed), 0xBE7C])
+        self.seed = int(seed)
         self.sizes = size_pool(mix)
         for p, o, _ in self.sizes:
             if p + o > max_seq_len:
                 raise ValueError(
                     f"mix asks for prompt {p} + output {o} tokens, past "
                     f"max_seq_len {max_seq_len}: no operation may fail")
-        self.gaps = gap_pool(mix) if mix["loop"] == "open" else None
+        self.dues = (np.cumsum(gap_pool(mix)) if mix["loop"] == "open"
+                     else None)
         sp = mix.get("shared_prefix")
+        rng = np.random.default_rng([self.seed, 0xBE7C])
         self.prefixes = [] if not sp else [
-            self.rng.integers(0, self.vocab, int(sp["tokens"]),
-                              dtype=np.int32)
+            rng.integers(0, self.vocab, int(sp["tokens"]), dtype=np.int32)
             for _ in range(int(sp["prompts"]))]
         self._n = 0
-        self._clock = 0.0
 
-    def next(self) -> Planned:
-        i = self._n % len(self.sizes)
+    def at(self, index: int) -> Planned:
+        """Request ``index`` of the plan."""
+        index = int(index)
+        cycle, i = divmod(index, len(self.sizes))
         plen, olen, pid = self.sizes[i]
-        prompt = self.rng.integers(0, self.vocab, plen, dtype=np.int32)
+        rng = np.random.default_rng([self.seed, 0xBE7C, index])
+        prompt = rng.integers(0, self.vocab, plen, dtype=np.int32)
         if pid >= 0:
             pre = self.prefixes[pid]
             prompt[:len(pre)] = pre
-        if self.gaps is not None:
-            self._clock += float(self.gaps[i])
-        out = Planned(self._n, self._clock, prompt, olen, pid)
+        due = 0.0 if self.dues is None else float(
+            cycle * self.dues[-1] + self.dues[i])
+        return Planned(index, due, prompt, olen, pid)
+
+    def next(self) -> Planned:
+        out = self.at(self._n)
         self._n += 1
         return out

@@ -62,13 +62,14 @@ def main(argv=None) -> int:
     faulthandler.dump_traceback_later(1150, exit=True)
     from .harness import check, device, spec
     cell = spec.load(args.workload)
-    record, peaks = device.require(cell.chips, args.rehearse)
-    if not args.rehearse:
-        device.enable_compile_cache(cell.root)
     cfg, mix = spec.sizes(cell, args.rehearse)
     for kv in args.set:
         key, value = kv.split("=", 1)
         mix[key] = json.loads(value)
+    record, peaks = device.require(cell.chips, args.rehearse,
+                                   mix.get("client_options"))
+    if not args.rehearse:
+        device.enable_compile_cache(cell.root)
     ctx = {"t_process": _T_PROCESS, "peaks": peaks,
            "cfg": cfg, "mix": mix, "compiles": device.CompileCounter(),
            "memory_peak": lambda: device.memory_peak_bytes(cell.chips),

@@ -2,6 +2,8 @@
 for both published configurations, percentiles and lateness on a
 synthetic schedule that holds a stall, the traffic generator."""
 import json
+import threading
+import time
 from pathlib import Path
 
 import numpy as np
@@ -146,6 +148,23 @@ def test_train_rate_is_all_tokens_over_all_the_time():
     assert readers.train_tok_s(run) == pytest.approx(16 * 4096 / 6.0)
 
 
+@pytest.mark.parametrize("have, want", [
+    (None, {"max_inflight_computations": 256}),
+    ("ml_framework_name:JAX;ml_framework_version:0.9.0",
+     {"ml_framework_name": "JAX", "ml_framework_version": "0.9.0",
+      "max_inflight_computations": 256}),
+    ({"max_inflight_computations": 32, "a": "b"},
+     {"max_inflight_computations": 256, "a": "b"})])
+def test_a_mixes_client_options_lie_over_the_clients_own(have, want):
+    from benchmark.harness import device
+    mix = json.loads((ROOT / "benchmark" / "traffic" / "s4096.json")
+                     .read_text())
+    got = device.laid_over(have, mix["client_options"])
+    assert got == want
+    # a whole number stays one: the client refuses it as a string
+    assert isinstance(got["max_inflight_computations"], int)
+
+
 def test_a_failed_request_is_later_than_any_that_succeeded():
     recs = _schedule()
     recs[5].stamps, recs[5].error = [], "Rejected('full')"
@@ -165,14 +184,15 @@ def test_a_reader_with_nothing_to_read_returns_nothing():
 
 
 MIXES = {n: json.loads((ROOT / "benchmark/traffic" / f"{n}.json").read_text())
-         for n in ("backlog", "chat")}
+         for n in ("backlog", "chat", "longout")}
+MAX_SEQ = {"backlog": 1024, "chat": 1024, "longout": 2048}
 
 
 @pytest.mark.parametrize("name", sorted(MIXES))
 def test_every_seed_offers_the_same_sizes_on_the_same_schedule(name):
-    mix = MIXES[name]
-    a = traffic.Plan(mix, 1, 32000, 1024)
-    b = traffic.Plan(mix, 3_000_000_019, 32000, 1024)
+    mix, cap = MIXES[name], MAX_SEQ[name]
+    a = traffic.Plan(mix, 1, 32000, cap)
+    b = traffic.Plan(mix, 3_000_000_019, 32000, cap)
     n = mix["pool"]
     ra, rb = [a.next() for _ in range(n)], [b.next() for _ in range(n)]
     sizes = lambda rs: [(len(r.prompt), r.max_new, r.due) for r in rs]  # noqa
@@ -180,9 +200,99 @@ def test_every_seed_offers_the_same_sizes_on_the_same_schedule(name):
     assert any((x.prompt != y.prompt).any() for x, y in zip(ra, rb))
     spec = mix["prompt_len"]
     assert all(spec["min"] <= len(r.prompt) <= spec["max"] for r in ra)
-    assert all(len(r.prompt) + r.max_new <= 1024 for r in ra)
-    again = traffic.Plan(mix, 1, 32000, 1024)
+    assert all(len(r.prompt) + r.max_new <= cap for r in ra)
+    again = traffic.Plan(mix, 1, 32000, cap)
     assert all((x.prompt == again.next().prompt).all() for x in ra)
+
+
+def _same(x, y):
+    return (x.index, x.due, x.max_new, x.prefix_id) == (
+        y.index, y.due, y.max_new, y.prefix_id) and (
+        x.prompt == y.prompt).all()
+
+
+@pytest.mark.parametrize("name", sorted(MIXES))
+def test_a_planned_request_is_a_function_of_its_index(name):
+    """``Plan.at(i)`` equals the ``i``-th ``next()``, in any order of
+    asking and past the pool's end (the pools cycle)."""
+    mix, cap = MIXES[name], MAX_SEQ[name]
+    n = mix["pool"] + 5
+    inorder = traffic.Plan(mix, 2147483747, 32000, cap)
+    seq = [inorder.next() for _ in range(n)]
+    plan = traffic.Plan(mix, 2147483747, 32000, cap)
+    for i in np.random.default_rng(3).permutation(n):
+        assert _same(plan.at(i), seq[i])
+    assert _same(plan.next(), seq[0])        # asking moved nothing
+    assert len(seq[mix["pool"]].prompt) == len(seq[0].prompt)
+    assert (seq[mix["pool"]].prompt != seq[0].prompt).any()
+    if mix["loop"] == "open":
+        assert seq[mix["pool"]].due == pytest.approx(
+            seq[0].due + mix["pool"] / mix["rate_per_s"])
+
+
+class _Frontend:
+    """Stands where ``ServingFrontend`` stands for ``Load``: counts as
+    admitted whatever was submitted, and answers once the first round
+    (two callers) is in, as no request finishes inside a cell's."""
+
+    def __init__(self, hold):
+        self.hold, self.lock, self.sent = hold, threading.Lock(), []
+        self.engine = self
+        self.scheduler = self
+        self.waiting = []
+
+    def live_requests(self):
+        return self.sent
+
+    def submit(self, prompt, max_new_tokens, logprobs):
+        time.sleep(self.hold(len(prompt)))   # who finishes first differs
+        with self.lock:
+            self.sent.append(len(prompt))
+        return self
+
+    def events(self, timeout, idle_s):
+        while len(self.sent) < 2:
+            time.sleep(0.0005)
+        yield {"type": "token", "token": 1, "logprob": 0.0}
+        yield {"type": "finish"}
+
+
+@pytest.mark.parametrize("name", ["backlog", "longout"])
+def test_a_closed_loop_deals_every_round_out_in_order(name):
+    """Caller ``k`` of ``C`` sends the plan's ``k, k + C, k + 2C, ...``:
+    the same sizes and ids in every run, whichever caller finishes first
+    (two interleavings: the long prompts slow, then the short ones); and
+    the first round is admitted in index order."""
+    from benchmark.drivers.serve import Load
+    pytest.importorskip("paddle_tpu.serving.frontend")
+    mix = dict(MIXES[name], clients=2)
+    plan = traffic.Plan(mix, 2147483747, 32000, MAX_SEQ[name])
+    median = mix["prompt_len"]["median"]
+    seen = []
+    for hold in (lambda n: 0.004 if n > median else 0.0,
+                 lambda n: 0.0 if n > median else 0.004):
+        fe = _Frontend(hold)
+        load = Load(fe, traffic.Plan(mix, 2147483747, 32000, MAX_SEQ[name]),
+                    mix)
+        load.start()
+        while min(sum(1 for r in list(load.records) if r.index % 2 == k
+                      and r.finished) for k in (0, 1)) < 4:
+            time.sleep(0.001)
+        load.finish(wait_s=5.0)
+        assert not load.errors
+        by_caller = {k: [r for r in load.records if r.index % 2 == k]
+                     for k in (0, 1)}
+        for k, recs in by_caller.items():
+            idx = [r.index for r in recs]
+            assert idx == list(range(k, k + 2 * len(idx), 2))  # no gap
+            for r in recs:
+                assert (r.prompt == plan.at(r.index).prompt).all()
+                assert r.max_new == plan.at(r.index).max_new
+        assert fe.sent[:2] == [len(plan.at(0).prompt),
+                               len(plan.at(1).prompt)]
+        seen.append({k: [(len(r.prompt), r.max_new) for r in recs[:4]]
+                     for k, recs in by_caller.items()})
+    assert seen[0] == seen[1]
 
 
 def test_open_loop_arrivals_hold_the_rate_and_share_prefixes():

@@ -116,6 +116,21 @@ def test_half_batch_fault_planted_in_the_reference_fails_too(runs):
     assert line["fault_half_batch"]["correct"] is False
 
 
+def test_the_train_window_closes_after_all_that_was_sent(runs):
+    """Calls are dispatched ahead of the one waited for; when the time is
+    up nothing more is sent and the clock is read once all that was sent
+    is done, so every step counted lies inside the time it is held to."""
+    cell = _cell_for("train")
+    line, _ = _line(runs, cell)
+    info = line["info"]
+    mix = json.loads((ROOT / "benchmark" / "traffic" / (next(
+        w["traffic"] for w in BENCH["workloads"] if w["name"] == cell)
+        + ".json")).read_text())
+    assert info["calls_ahead"] == mix["calls_ahead"] >= 1
+    assert info["steps"] == line["attempted"]
+    assert 1.0 <= info["sent_all_after_s"] <= info["window_s"]
+
+
 def test_no_accelerator_means_no_result():
     p = subprocess.run(
         BENCH["command"] + ["--workload", CELLS[0], "--seed", "1",
