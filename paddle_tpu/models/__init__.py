@@ -9,6 +9,7 @@ from .llama import (LlamaConfig, LlamaForCausalLM,  # noqa: F401
                     flops_per_token)
 from .latent_moe import (LatentMoEConfig,  # noqa: F401
                          LatentMoEForCausalLM)
+from .sambay import SambaYConfig, SambaYForCausalLM  # noqa: F401
 from .t5 import (T5Config, T5ForConditionalGeneration,  # noqa: F401
                  T5Model)
 from .whisper import (WhisperConfig, WhisperModel,  # noqa: F401

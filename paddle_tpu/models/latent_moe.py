@@ -25,8 +25,9 @@ Per token, pre-norm residual, RMSNorm, no biases:
   instantiated: it is a training loss or a self-draft (ROADMAP R5).
 
 Serving: ``ServingEngine(model, ragged=True)``; the engine asks each
-layer for its ``paged_forward`` over a latent page pool
-(``PagedKVCache(latent_dim=...)``).
+layer for its ``paged_forward(x, step)`` (``serving/attention.py::
+PagedStep``: the one protocol of layers that bring their own) over the
+latent page pool it owns (``paged_cache``).
 """
 from __future__ import annotations
 
@@ -305,6 +306,7 @@ class LatentMoEDecoderLayer(Layer):
     def __init__(self, cfg: LatentMoEConfig, layer_idx: int):
         super().__init__()
         self.cfg = cfg
+        self.layer_idx = layer_idx
         self.input_layernorm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
         self.self_attn = LatentAttention(cfg)
         self.post_attention_layernorm = RMSNorm(cfg.hidden_size,
@@ -322,31 +324,36 @@ class LatentMoEDecoderLayer(Layer):
         else:
             self.mlp = SwiGLU(cfg.hidden_size, cfg.intermediate_size)
 
-    # what a serving cache holds for this layer: one latent entry a token
+    # what a serving cache holds for this layer: one latent entry a
+    # token, in a full pool of its own
     @property
-    def paged_latent_dim(self):
-        return self.cfg.latent_dim
+    def paged_cache(self):
+        from ..serving.kv_cache import LayerCache
+        return LayerCache(pool="full", n_kv_heads=1,
+                          head_dim=self.cfg.latent_dim, latent=True)
 
     def forward(self, x, position_ids=None):
         h = x + self.self_attn(self.input_layernorm(x), position_ids)
         return h + self.mlp(self.post_attention_layernorm(h))
 
-    def paged_forward(self, x, positions, pool, slots, pt_tok, cl_tok,
-                      valid=None, stats=None):
-        """The block over a latent page pool (the serving engine's
-        call). ``valid`` [B*S] marks the packed tokens that are not
-        padding; ``stats``, a list, receives this layer's routing counts
-        (:meth:`DroplessMoE.forward_counted`)."""
-        a, pool = self.self_attn.paged_forward(
-            self.input_layernorm(x), positions, pool, slots, pt_tok, cl_tok)
+    def paged_forward(self, x, step):
+        """The block over its latent page pool (the serving engine's
+        call; ``step``: ``serving/attention.py::PagedStep``). The
+        routing counts of an expert layer go to ``step.stats``
+        (:meth:`DroplessMoE.forward_counted`, over the rows that are
+        not padding)."""
+        pt_tok, cl_tok, valid = step.per_token
+        a, step.pools[self.layer_idx] = self.self_attn.paged_forward(
+            self.input_layernorm(x), step.positions,
+            step.pools[self.layer_idx], step.slots, pt_tok, cl_tok)
         h = x + a
         y = self.post_attention_layernorm(h)
-        if self.is_moe and stats is not None:
+        if self.is_moe and step.stats is not None:
             out, counts = self.mlp.forward_counted(y, valid)
-            stats.append(counts)
+            step.stats.append(counts)
         else:
             out = self.mlp(y)
-        return h + out, pool
+        return h + out
 
 
 class LatentMoEForCausalLM(Layer):

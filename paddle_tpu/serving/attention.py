@@ -55,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["paged_attention", "paged_attention_ref",
-           "ragged_paged_attention", "quantize_q8"]
+           "ragged_paged_attention", "quantize_q8", "PagedStep"]
 
 
 def quantize_q8(x):
@@ -174,6 +174,76 @@ def _row_positions(query_lens, q_offsets, t, k1=1):
         _rows_live(query_lens, t, k1),
         _rows_of_lanes(q_offsets.astype(jnp.int32), t, k1)
         + _row_index(query_lens.shape[0], t, k1), 0)
+
+
+class PagedStep:
+    """What the packed step hands a layer that brings its own
+    ``paged_forward(x, step)``: ONE protocol for every such layer. A
+    layer may own a pool (``pools[i]``, one array of one entry a token:
+    read it, write this step's rows, put the new array back), read another layer's pool and write none
+    (``pools[j]``, after layer ``j`` ran), own a window pool
+    (``window_pools[i]``, its own short table) or a lane state
+    (``states[i]``: arrays by lane slot), and hand an activation on to
+    later layers (``carried``).
+
+    Per LANE (``L`` lanes; lane ``i < L - 1`` owns rows ``[i * k1,
+    (i + 1) * k1)``, the last lane -- the chunk -- the rows behind):
+    ``pt [L, P]`` the full pools' page table, ``cl [L]`` the keys a lane
+    may see, ``ql [L]`` its live rows, ``qoff [L]`` its first row's
+    position (0: the row starts a sequence, whose lane state is nought),
+    ``lane_slot [L]`` its slot in the state arrays (0: scratch),
+    ``wpt [L, W]`` / ``wbase [L]`` its window-pool pages, oldest first,
+    and the position of their first slot. Per packed ROW: ``positions
+    [1, T]``, ``slots [T]`` / ``wslots [T]`` the flat slot its entry is
+    written to in a full / window pool, and, built on first use,
+    ``per_token`` = ``(pt_tok [T, P], cl_tok [T], valid [T])``.
+    ``stats``, a list, receives sparse-expert layers' routing counts."""
+
+    def __init__(self, positions, slots, pt, cl, ql, qoff, k1, pools,
+                 extra=None, stats=None):
+        self.positions, self.slots = positions, slots
+        self.pt, self.cl = pt, cl.astype(jnp.int32)
+        self.ql, self.qoff = ql.astype(jnp.int32), qoff.astype(jnp.int32)
+        self.k1 = k1
+        self.t = int(slots.shape[0])
+        self.pools = dict(pools)
+        extra = extra or {}
+        self.window_pools = dict(extra.get("window_pools", {}))
+        self.states = dict(extra.get("states", {}))
+        self.lane_slot = extra.get("lane_slot")
+        self.wslots = extra.get("wslots")
+        self.wpt, self.wbase = extra.get("wpt"), extra.get("wbase")
+        self.carried = {}
+        self.stats = stats
+        self._per_token = self._valid = None
+
+    @property
+    def per_token(self):
+        if self._per_token is None:
+            self._per_token = (
+                _rows_of_lanes(self.pt, self.t, self.k1),
+                _rows_of_lanes(self.cl, self.t, self.k1),
+                self.valid)
+        return self._per_token
+
+    @property
+    def valid(self):
+        """``[T]`` bool: the rows that are not padding."""
+        if self._valid is None:
+            self._valid = _rows_live(self.ql, self.t, self.k1)
+        return self._valid
+
+    def regions(self):
+        """The step's static regions as ``(rows, lanes, n, s)``: slices
+        of the packed rows and of the lanes, ``n`` lanes of ``s`` rows
+        each -- the rectangle, then the chunk where the class has one."""
+        n_rect, r = _regions(self.pt.shape[0], self.t, self.k1)
+        out = [(slice(0, r), slice(0, n_rect), n_rect, self.k1)] if r \
+            else []
+        if self.t > r:
+            out.append((slice(r, self.t), slice(n_rect, n_rect + 1), 1,
+                        self.t - r))
+        return out
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table,

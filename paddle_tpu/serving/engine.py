@@ -109,8 +109,8 @@ import numpy as np
 
 from .chaos import ChaosConfig, ChaosInjector
 from .distill import distill_buffer_from_env
-from .kv_cache import (SCRATCH_PAGE, GeometryMismatch, OutOfPages,
-                       PagedKVCache)
+from .kv_cache import (SCRATCH_PAGE, GeometryMismatch, LayerCache,
+                       OutOfPages, PagedKVCache)
 from .kvtier import KVTier, host_pool_from_env
 from .metrics import ServingMetrics
 from .sampling import filter_binds
@@ -157,19 +157,33 @@ class ServingEngine:
         return cfg, core
 
     @staticmethod
-    def _cache_geometry(cfg, core):
-        """What one token holds in a layer's cache: ``(n_kv_heads,
-        head_dim, latent_dim)``. A model whose layers bring a
-        ``paged_forward`` over a latent pool says so through
-        ``paged_latent_dim`` (one entry of that width a token a layer,
-        shared by every head); a LLaMA-shaped one holds K and V by
-        head."""
-        latent = getattr(core.layers[0], "paged_latent_dim", None)
-        if latent is not None:
-            return 1, int(latent), int(latent)
+    def _cache_layout(cfg, core):
+        """The cache's make-up, a :class:`~.kv_cache.LayerCache` a
+        layer, asked of EVERY layer (a model's first layer may own no
+        pool): a layer that brings its own ``paged_forward`` says what
+        it keeps through ``paged_cache``; a LLaMA-shaped one owns a
+        full pool of K and V by head."""
         nh = cfg.num_attention_heads
         nkv = getattr(cfg, "num_key_value_heads", None) or nh
-        return nkv, cfg.hidden_size // nh, None
+        plain = LayerCache(pool="full", n_kv_heads=nkv,
+                           head_dim=cfg.hidden_size // nh)
+        return tuple(getattr(layer, "paged_cache", plain)
+                     for layer in core.layers)
+
+    @classmethod
+    def _cache_geometry(cls, cfg, core):
+        """What one token holds in a full pool: ``(n_kv_heads,
+        head_dim, latent_dim)``, the one geometry of the layers that
+        own one (a latent pool: one entry of ``latent_dim`` a token a
+        layer, shared by every head)."""
+        geo = {(lc.n_kv_heads, lc.head_dim, lc.latent)
+               for lc in cls._cache_layout(cfg, core) if lc.pool == "full"}
+        if len(geo) != 1:
+            raise NotImplementedError(
+                f"the layers' full pools have {len(geo)} geometries "
+                f"({sorted(geo)}): one allocator serves one")
+        (nkv, hd, latent), = geo
+        return nkv, hd, (hd if latent else None)
 
     @staticmethod
     def _resolve_cache_dtype(cache_dtype, cfg):
@@ -228,6 +242,8 @@ class ServingEngine:
         self._core = core
         nh = cfg.num_attention_heads
         nkv, hd, latent_dim = self._cache_geometry(cfg, core)
+        layout = self._cache_layout(cfg, core)
+        n_full = sum(lc.pool == "full" for lc in layout)
         self.max_seq_len = int(max_seq_len
                                or cfg.max_position_embeddings)
         maxpos = getattr(cfg, "max_position_embeddings", None)
@@ -260,14 +276,28 @@ class ServingEngine:
             prefix_cache = os.environ.get(
                 "PADDLE_TPU_SERVING_PREFIX_CACHE") == "1"
         self.cache = PagedKVCache(
-            cfg.num_hidden_layers, nkv, hd, page_size=page_size,
+            n_full, nkv, hd, page_size=page_size,
             num_pages=num_pages,
             hbm_budget_bytes=(int(hbm_budget_mb * 2 ** 20)
                               if hbm_budget_mb is not None else None),
             dtype=cache_dtype, prefix_cache=bool(prefix_cache),
-            tp_degree=self.tp_degree, latent_dim=latent_dim)
+            tp_degree=self.tp_degree, latent_dim=latent_dim,
+            layout=layout, max_lanes=max_batch,
+            prefill_chunk=prefill_chunk)
         self.max_pages_per_seq = math.ceil(
             self.max_seq_len / self.cache.page_size)
+        # lane state or window pools beside the pages: what is not built
+        # for them refuses by name (the cache itself refuses int8,
+        # tp_degree, the prefix cache, forks and page shipping)
+        if self.cache.mixed and (draft_model is not None or speculative_k):
+            raise NotImplementedError(
+                "speculative decoding (speculative_k > 0 / draft_model) "
+                "beside a lane state is not built: a rejected draft "
+                "needs the state rolled back")
+        # layers that gather the full pools' tables in a step: the
+        # owners and the layers that read another's
+        self._pool_readers = sum(lc.pool == "full" or lc.reads is not None
+                                 for lc in layout)
         # where the pools actually live (advertised in /healthz): a
         # process-backed fleet worker defaults to cpu, and a router
         # must be able to see that without touching jax itself
@@ -369,6 +399,8 @@ class ServingEngine:
         self.metrics.kv_page_bytes.set(self.cache.bytes_total
                                        / self.cache.num_pages)
         self.metrics.cache_bytes_per_token.set(self.cache.bytes_per_token)
+        self.metrics.state_bytes_per_lane.set(
+            self.cache.state_bytes_per_lane)
         # routing counts of sparse-expert layers, summed on the device
         # by the ragged step and fetched with its tokens
         self._moe_counts_dev = None
@@ -465,6 +497,14 @@ class ServingEngine:
                 "migration, not the prefill side")
         if not 0.0 <= float(top_p) <= 1.0:
             raise ValueError(f"top_p={top_p} outside [0, 1]")
+        if self.cache.mixed and (n > 1 or prefill_only):
+            raise NotImplementedError(
+                ("n > 1 (a fork needs the parent's lane state and window "
+                 "pages copied)" if n > 1 else
+                 "prefill_only (disagg: the lane state and the window "
+                 "pools do not ship)")
+                + " is not built for a cache with lane state or window "
+                "pools beside its pages")
         now = self._now()
         req = Request(prompt=prompt, max_new_tokens=int(max_new_tokens),
                       arrival=now,
@@ -1279,6 +1319,16 @@ class ServingEngine:
                 "seeds": np.zeros(tcap, np.int32),
                 "steps": np.zeros(tcap, np.int32),
             }
+            if self.cache.mixed:
+                # a lane's slot in the state arrays (0: the scratch
+                # lane), its window-pool pages and where they start,
+                # a row's slot in the window pools (0: scratch)
+                b["lane_slot"] = np.zeros(nl, np.int32)
+                b["wpt"] = np.full(
+                    (nl, max(self.cache.window_pages_per_lane, 1)),
+                    SCRATCH_PAGE, np.int32)
+                b["wbase"] = np.zeros(nl, np.int32)
+                b["wslots"] = np.zeros(tcap, np.int32)
         else:
             # full padding reset: lane composition changes every step
             # (padded lanes keep context 1 / scratch pages / neutral
@@ -1296,6 +1346,11 @@ class ServingEngine:
             b["top_p"][:] = 1.0
             b["seeds"][:] = 0
             b["steps"][:] = 0
+            if self.cache.mixed:
+                b["lane_slot"][:] = 0
+                b["wpt"][:] = SCRATCH_PAGE
+                b["wbase"][:] = 0
+                b["wslots"][:] = 0
         lane = 0
         emit_spec = []                    # (req, hist0, n_slots, i, off)
         for i, (r, hist0, n_slots, tslots, dslots) in \
@@ -1341,6 +1396,9 @@ class ServingEngine:
             b["top_p"][off] = r.top_p
             b["seeds"][off] = r.device_seed
             b["steps"][off] = len(r.out_tokens)
+            if self.cache.mixed:
+                self._pack_lane_extras(b, lane, r.seq_id,
+                                       slice(off, off + 1))
             emit_plain.append((r, off))
             lane += 1
         pf_off = None
@@ -1355,6 +1413,8 @@ class ServingEngine:
             b["positions"][0, sl] = start + np.arange(n,
                                                       dtype=np.int32)
             b["slot_map"][0, sl] = pslots
+            if self.cache.mixed:
+                self._pack_lane_extras(b, chunk_lane, req.seq_id, sl)
             # only the chunk's LAST token's sample is ever consumed,
             # and only at prefill completion: it takes the request's
             # params in the prompt's last chunk alone, so an earlier
@@ -1374,16 +1434,24 @@ class ServingEngine:
             b["ids"], b["positions"], b["pt"], b["cl"], b["ql"],
             b["qoff"], b["slot_map"],
             (b["do_sample"], b["temperature"], b["top_k"], b["top_p"],
-             b["seeds"], b["steps"]))
+             b["seeds"], b["steps"]),
+            {k: b[k] for k in ("lane_slot", "wpt", "wbase", "wslots")}
+            if self.cache.mixed else {})
         self._logits_row = pf_off if pf_off is not None else 0
         # what the padded tables cost: page-table entries a layer's
         # attention gathered in this step's class (counted where the
         # gather is: _step_tables_gathered) against the pages its live
         # lanes hold
-        self.metrics.attn_pages_gathered.inc(mp * _step_tables_gathered(
-            self._core, self._ragged_lanes, tcap, k1))
-        self.metrics.attn_pages_live.inc(int(np.sum(
-            -(-b["cl"][b["ql"] > 0] // self.cache.page_size))))
+        live = b["ql"] > 0
+        full_live = int(np.sum(-(-b["cl"][live] // self.cache.page_size)))
+        if self.cache.mixed:
+            self._count_mixed_step(b, live, full_live, tcap, k1,
+                                   pf is not None and pf[1] == 0)
+        else:
+            self.metrics.attn_pages_gathered.inc(
+                mp * _step_tables_gathered(self._core, self._ragged_lanes,
+                                           tcap, k1))
+            self.metrics.attn_pages_live.inc(full_live)
         if spec_active:
             self.metrics.spec_rounds.inc()
             self.metrics.spec_draft_tokens.inc(
@@ -1479,6 +1547,39 @@ class ServingEngine:
                 plain=len(emit_plain),
                 prefill=(pf[0].req_id if pf is not None else None),
                 experts_hit=experts_hit)
+
+    def _pack_lane_extras(self, b, lane, seq_id, rows):
+        """A lane's part of a mixed cache into the step's buffers: its
+        state slot, its window-pool table, and the window-pool slots of
+        the rows it sends (those its last allocation reserved)."""
+        b["lane_slot"][lane] = self.cache.lane_slot(seq_id)
+        if self.cache.window_layers:
+            b["wpt"][lane], b["wbase"][lane] = \
+                self.cache.window_table(seq_id)
+            b["wslots"][rows] = self.cache.window_slots(seq_id)
+
+    def _count_mixed_step(self, b, live, full_live, tcap, k1, starts):
+        """A step's counts where the layers differ (lane state, window
+        pools, layers that read another's pool), from what it packed:
+        page-table entries gathered are summed over EVERY layer that
+        attends (a reader of another layer's pool gathers too; a window
+        layer its own short table), pages live over every pool."""
+        from .attention import tables_gathered
+        c, m = self.cache, self.metrics
+        tables = tables_gathered(self._ragged_lanes, tcap, k1)
+        lanes = int(live.sum())
+        nw, ns = len(c.window_layers), len(c.state_layers)
+        held = int(np.sum(b["wpt"][live] != SCRATCH_PAGE)) if nw else 0
+        m.attn_pages_gathered.inc(
+            tables * (self._pool_readers * self.max_pages_per_seq
+                      + nw * c.window_pages_per_lane))
+        m.attn_pages_live.inc(len(c.full_layers) * full_live + nw * held)
+        m.window_pages_held.inc(nw * held)
+        m.window_layer_steps.inc(nw * lanes)
+        m.ssm_layer_steps.inc(ns)
+        m.ssm_lane_scans.inc(ns * lanes)
+        m.ssm_rows_scanned.inc(ns * int(b["ql"].sum()))
+        m.ssm_state_resets.inc(int(starts))
 
     # -- KV page migration (disaggregated serving, round 14) ---------------
     def export_request(self, req_id, skip_pages=0):
@@ -1899,7 +2000,7 @@ class ServingEngine:
         self.metrics.tp_kernel_fallbacks.inc()
 
     def _run_ragged_step(self, ids, positions, pt, cl, ql, qoff,
-                         slot_map, samp):
+                         slot_map, samp, lane_extras):
         import jax
         import jax.numpy as jnp
         self._tp_kernel_guard()
@@ -1917,12 +2018,19 @@ class ServingEngine:
                                   k1=self.spec_k + 1))
         warrs = [t._data for t in self.model._gen_state_tensors()]
         k_ops, v_ops = self.cache.program_operands()
-        tok, lp, logits, k_pages, v_pages, moe_counts = self._ragged_fn(
-            warrs, jnp.asarray(ids), jnp.asarray(positions),
-            jnp.asarray(pt), jnp.asarray(cl), jnp.asarray(ql),
-            jnp.asarray(qoff), jnp.asarray(slot_map),
-            tuple(jnp.asarray(a) for a in samp), k_ops, v_ops)
+        # a mixed cache's window pools and lane states, with the lanes'
+        # slots and window tables (empty where every layer owns a full
+        # pool: nothing more enters the program)
+        extra = dict(self.cache.extra_operands(),
+                     **{k: jnp.asarray(a) for k, a in lane_extras.items()})
+        tok, lp, logits, k_pages, v_pages, moe_counts, extra = \
+            self._ragged_fn(
+                warrs, jnp.asarray(ids), jnp.asarray(positions),
+                jnp.asarray(pt), jnp.asarray(cl), jnp.asarray(ql),
+                jnp.asarray(qoff), jnp.asarray(slot_map),
+                tuple(jnp.asarray(a) for a in samp), k_ops, v_ops, extra)
         self.cache.store_operands(k_pages, v_pages)
+        self.cache.store_extra(extra)
         self._logits_dev = logits          # [T, V], fetched on demand
         self._moe_counts_dev = moe_counts  # fetched with the tokens
         self._count_dispatch(("ragged", ids.shape[1]))
@@ -1960,9 +2068,9 @@ def _draft_catchup_pure(draft, core, window, dwarrs, ids, positions,
     for t, arr in zip(tensors, dwarrs):
         t._data = arr
     try:
-        _, new_k, new_v = _paged_forward(core, window, ids, positions,
-                                         pt, cl, slot_map, k_pages,
-                                         v_pages)
+        _, new_k, new_v, _ = _paged_forward(core, window, ids, positions,
+                                            pt, cl, slot_map, k_pages,
+                                            v_pages)
         return new_k, new_v
     finally:
         for t, arr in saved:
@@ -1970,7 +2078,8 @@ def _draft_catchup_pure(draft, core, window, dwarrs, ids, positions,
 
 
 def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
-                   k_pages, v_pages, ragged=None, tp=None, stats=None):
+                   k_pages, v_pages, ragged=None, tp=None, stats=None,
+                   extra=None):
     """The transformer trunk over the paged cache: embed, attend (K/V
     scattered into the page pool), final norm. The step runs it with
     ``ragged=(query_lens, q_offsets, k1)``, the token-packed lane
@@ -1982,7 +2091,7 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
     prefill and proposal scan); the packed layout gathers by lane too
     since PR 30, so the draft could take it at no extra gather
     (ROADMAP D-queue; not switched). Returns ``(hidden [B, S, D] jnp
-    array, new_k, new_v)``.
+    array, new_k, new_v, new_extra)``.
 
     ``tp`` (a :class:`~.tp.TPContext`) makes the trunk ONE SPMD
     program over the mesh.  The constraints below are the whole
@@ -1996,14 +2105,20 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
     before down_proj's contraction — the inline mirrors
     ``down_proj(swiglu(gate_proj(x), up_proj(x)))`` exactly.
 
-    A layer that brings its own ``paged_forward`` (latent attention over
-    a latent page pool, sparse experts: ``models/latent_moe.py``) is
-    asked for it, with each packed token's page-table row, visible keys
-    and validity; ``v_pages`` is then empty and ``new_v`` comes back
-    empty. ``stats``, a list, receives such layers' routing counts.
-    Such a model has no draft form, so its layers see the packed
-    layout only. LLaMA-shaped layers run the code below (two paged
-    forwards until D1 gives the block one definition)."""
+    A layer that brings its own ``paged_forward(x, step)`` (latent
+    attention and sparse experts, ``models/latent_moe.py``; state-space,
+    window, shared-pool and gated-memory layers, ``models/sambay.py``)
+    is asked for it under ONE protocol, :class:`~.attention.PagedStep`
+    (:func:`_layers_own_forward`): it may own a pool, read another
+    layer's, own a window pool or a lane state, and hand an activation
+    on. ``k_pages`` holds the full pools of the layers that own one,
+    in layer order, one entry a token (``v_pages`` is empty); ``extra`` the window pools, the lane states and the lanes'
+    slots and window tables (``PagedKVCache.extra_operands`` and the
+    step's buffers), empty where every layer owns a full pool.
+    ``stats``, a list, receives such layers' routing counts. Such a
+    model has no draft form, so its layers see the packed layout only.
+    LLaMA-shaped layers run the code below (two paged forwards until D1
+    gives the block one definition)."""
     from ..core.autograd import no_grad
     from ..core.tensor import Tensor
     from ..incubate.nn.functional import (
@@ -2025,14 +2140,9 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
         pos_t = Tensor(positions)
         new_k, new_v = [], []
         if _brings_paged_forward(core):
-            ql, _, k1 = ragged
-            per_tok = _per_token_tables(b * s, pt, cl, ql, k1)
-            for layer, pool in zip(core.layers, k_pages):
-                x, pool = layer.paged_forward(x, positions, pool,
-                                              flat_slots, *per_tok,
-                                              stats=stats)
-                new_k.append(pool)
-            return core.norm(x)._data, new_k, new_v
+            return _layers_own_forward(core, x, positions, flat_slots, pt,
+                                       cl, ragged, k_pages, v_pages,
+                                       extra or {}, stats)
         for layer, kp, vp in zip(core.layers, k_pages, v_pages):
             at = layer.self_attn
             nh, nkv, hd = at.num_heads, at.num_kv_heads, at.head_dim
@@ -2115,46 +2225,66 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
                 h = x + at.o_proj(ao)
                 x = h + layer.mlp(layer.post_attention_layernorm(h))
         x = core.norm(x)
-    return x._data, new_k, new_v
+    return x._data, new_k, new_v, {}
 
 
 def _brings_paged_forward(core):
-    """Whether the layers attend through their own ``paged_forward``
-    over per-row tables (:func:`_per_token_tables`) and not through
-    ``ragged_paged_attention`` over per-lane ones."""
-    return hasattr(core.layers[0], "paged_forward")
+    """Whether the layers run through their own ``paged_forward(x,
+    step)`` (:func:`_layers_own_forward`) and not through the
+    LLaMA-shaped block of :func:`_paged_forward`."""
+    return all(hasattr(layer, "paged_forward") for layer in core.layers)
+
+
+def _layers_own_forward(core, x, positions, flat_slots, pt, cl, ragged,
+                        k_pages, v_pages, extra, stats):
+    """The trunk of a model whose layers bring their own
+    ``paged_forward(x, step)``: the step's operands are laid out by
+    layer index in a :class:`~.attention.PagedStep`, every layer is
+    asked in order, and what they left there is gathered up again in
+    the operands' order."""
+    from .attention import PagedStep
+    layout = [layer.paged_cache for layer in core.layers]
+    full = [i for i, lc in enumerate(layout) if lc.pool == "full"]
+    wins = [i for i, lc in enumerate(layout) if lc.pool == "window"]
+    stts = [i for i, lc in enumerate(layout) if lc.state]
+    ql, qoff, k1 = ragged
+    if v_pages:
+        raise NotImplementedError(
+            "a layer that brings its own paged_forward keeps one entry a "
+            "token in a pool (LayerCache(latent=True)), not K and V apart")
+    step = PagedStep(
+        positions, flat_slots, pt, cl, ql, qoff, k1,
+        pools=dict(zip(full, k_pages)),
+        extra=dict(extra,
+                   window_pools=dict(zip(wins, extra.get("window", ()))),
+                   states=dict(zip(stts, extra.get("state", ())))),
+        stats=stats)
+    for layer in core.layers:
+        x = layer.paged_forward(x, step)
+    new_extra = {}
+    if wins or stts:
+        new_extra = {"window": [step.window_pools[i] for i in wins],
+                     "state": [tuple(step.states[i]) for i in stts]}
+    new_k = [step.pools[i] for i in full]
+    return core.norm(x)._data, new_k, [], new_extra
 
 
 def _step_tables_gathered(core, lanes, t, k1):
     """Page tables a layer's attention gathers in a step of ``t``
-    packed rows, by the branch :func:`_paged_forward` takes: one a
-    lane and region (``attention.py::tables_gathered``), or one a row
-    where the layers bring their own ``paged_forward`` and attend with
-    :func:`_per_token_tables` (ROADMAP R3)."""
+    packed rows, where every layer owns a full pool: one a lane and
+    region (``attention.py::tables_gathered``), or one a row where the
+    layers attend with ``PagedStep.per_token`` (the latent layers:
+    ROADMAP R3). (Where the layers differ: ``_count_mixed_step``.)"""
     from .attention import tables_gathered
     return (t if _brings_paged_forward(core)
             else tables_gathered(lanes, t, k1))
-
-
-def _per_token_tables(t, pt, cl, ql, k1=1):
-    """``(pt_tok [t, P], cl_tok [t], valid [t])``: each packed token's
-    page-table row, the keys it may see and whether it is a real
-    token -- what a layer's own ``paged_forward`` attends with. A
-    row's lane is its place in the step's two regions, so the tables
-    are repeats and broadcasts (``attention.py::_rows_of_lanes``)."""
-    import jax.numpy as jnp
-
-    from .attention import _rows_live, _rows_of_lanes
-    return (_rows_of_lanes(pt, t, k1),
-            _rows_of_lanes(cl.astype(jnp.int32), t, k1),
-            _rows_live(ql, t, k1))
 
 
 # -- the step program (round 22 / PR 18) -----------------------------------
 
 def _ragged_step_pure(model, core, window, tp, warrs, ids, positions,
                       pt, cl, ql, qoff, slot_map, samp, k_pages,
-                      v_pages, k1=1):
+                      v_pages, extra=None, k1=1):
     tensors = model._gen_state_tensors()
     saved = [(t, t._data) for t in tensors]
     for t, arr in zip(tensors, warrs):
@@ -2162,14 +2292,15 @@ def _ragged_step_pure(model, core, window, tp, warrs, ids, positions,
     try:
         return _ragged_step_body(model, core, window, tp, ids,
                                  positions, pt, cl, ql, qoff, slot_map,
-                                 samp, k_pages, v_pages, k1)
+                                 samp, k_pages, v_pages, extra, k1)
     finally:
         for t, arr in saved:
             t._data = arr
 
 
 def _ragged_step_body(model, core, window, tp, ids, positions, pt, cl,
-                      ql, qoff, slot_map, samp, k_pages, v_pages, k1=1):
+                      ql, qoff, slot_map, samp, k_pages, v_pages,
+                      extra=None, k1=1):
     """The token-packed step: the trunk runs at [1, T] (``k1`` rows a
     decode/verify lane, then the chunk's rows: ``_ragged_step``),
     lm_head + fused sampling cover EVERY packed row (each with its own
@@ -2187,10 +2318,9 @@ def _ragged_step_body(model, core, window, tp, ids, positions, pt, cl,
     from ..core.tensor import Tensor
 
     stats = []
-    x, new_k, new_v = _paged_forward(core, window, ids, positions, pt,
-                                     cl, slot_map, k_pages, v_pages,
-                                     ragged=(ql, qoff, k1), tp=tp,
-                                     stats=stats)
+    x, new_k, new_v, new_extra = _paged_forward(
+        core, window, ids, positions, pt, cl, slot_map, k_pages, v_pages,
+        ragged=(ql, qoff, k1), tp=tp, stats=stats, extra=extra)
     # sparse-expert layers' routing counts of this step, summed over
     # layers: int32 [4] (MOE_COUNTS), None for a model without them
     moe_counts = sum(stats[1:], stats[0]) if stats else None
@@ -2207,7 +2337,7 @@ def _ragged_step_body(model, core, window, tp, ids, positions, pt, cl,
     tokens, logprobs = fused_sample(
         logits, do_sample, temperature, top_k, top_p, seeds, steps,
         sample_capable=True)
-    return tokens, logprobs, logits, new_k, new_v, moe_counts
+    return tokens, logprobs, logits, new_k, new_v, moe_counts, new_extra
 
 
 # -- the fused draft-proposal scan (speculative decoding, round 12) --------
@@ -2250,9 +2380,9 @@ def _spec_draft_body(draft, core, window, sample_capable, ids0, pos0,
     def step(carry, xs):
         j, slots = xs
         kps, vps, tok = carry
-        x, nk, nv = _paged_forward(core, window, tok,
-                                   (pos0 + j)[:, None], pt, cl0 + j,
-                                   slots[:, None], kps, vps)
+        x, nk, nv, _ = _paged_forward(core, window, tok,
+                                      (pos0 + j)[:, None], pt, cl0 + j,
+                                      slots[:, None], kps, vps)
         with no_grad():
             logits = draft.lm_head(Tensor(x[:, -1:]))._data[:, 0]
         nxt, _ = fused_sample(

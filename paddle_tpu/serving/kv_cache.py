@@ -65,14 +65,46 @@ from __future__ import annotations
 
 import math
 from collections import deque
+from dataclasses import dataclass
 
 import numpy as np
 
-__all__ = ["PagedKVCache", "OutOfPages", "SCRATCH_PAGE",
+__all__ = ["PagedKVCache", "OutOfPages", "SCRATCH_PAGE", "LayerCache",
            "GeometryMismatch", "PrefixDrift"]
 
 # page 0 is never handed to a sequence: padded lanes scatter/gather there
 SCRATCH_PAGE = 0
+
+
+@dataclass(frozen=True)
+class LayerCache:
+    """What ONE layer keeps for a sequence: the cache's make-up is a
+    tuple of these, one a layer, taken from the model.
+
+    - ``pool="full"``: a pool of ``num_pages`` pages addressed by the
+      sequence's one page table, a key a token for the whole length
+      (K and V ``[.., n_kv_heads, head_dim]``; ``latent``: one entry
+      ``[.., head_dim]`` a token in one array, whatever the layer
+      keeps in it: a compressed latent, or keys and values joined).
+    - ``pool="window"`` (``latent`` entries only): a pool of its own in
+      which a lane keeps only
+      the pages a later row may still see (``window`` keys back): the
+      lane's last ``ceil((window + prefill_chunk) / page) + 1`` pages,
+      the rest released as the lane advances.
+    - ``state``: fixed-size arrays a LANE (not a page): ``((name,
+      shape, dtype), ...)``, dtype ``None`` = the cache's; held
+      ``[max_lanes + 1, *shape]``, slot 0 the scratch lane.
+    - ``reads``: the index of the layer whose full pool this one
+      attends; it owns nothing.
+    - all defaults: the layer keeps nothing for a sequence.
+    """
+    pool: str | None = None
+    n_kv_heads: int = 0
+    head_dim: int = 0
+    latent: bool = False
+    window: int = 0
+    state: tuple = ()
+    reads: int | None = None
 
 
 class OutOfPages(RuntimeError):
@@ -135,7 +167,8 @@ class PagedKVCache:
 
     def __init__(self, n_layers, n_kv_heads, head_dim, *, page_size=16,
                  num_pages=None, hbm_budget_bytes=None, dtype="float32",
-                 prefix_cache=False, tp_degree=1, latent_dim=None):
+                 prefix_cache=False, tp_degree=1, latent_dim=None,
+                 layout=None, max_lanes=None, prefill_chunk=None):
         import jax.numpy as jnp
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
@@ -237,12 +270,177 @@ class PagedKVCache:
         # rc-0 cached pages spill their wire payload to the host tier
         # instead of vanishing (kvtier.KVTier; strictly best-effort)
         self._tier = None
+        self._init_mixed(layout, max_lanes, prefill_chunk)
+
+    # -- a make-up in which the layers differ -------------------------------
+    def _init_mixed(self, layout, max_lanes, prefill_chunk):
+        """``layout`` (a :class:`LayerCache` a layer, from the model)
+        says which layers own one of the ``n_layers`` full pools built
+        above, which a window pool, which a lane state, and which read
+        another layer's pool. ``None``: every layer owns a full pool.
+        ``mixed`` is true where something beside full pools is kept;
+        such a cache has ONE allocator and one page table a sequence
+        for the full pools, a second free list for the window pools'
+        pages (every window layer uses the same page ids: they advance
+        together), and a lane slot a sequence for the states. The
+        window pools and the states are sized for ``max_lanes``
+        sequences at once, so they are a FIXED cost a lane that
+        admission counts in lanes, not in pages."""
+        import jax.numpy as jnp
+        n = self.n_layers
+        self.layout = tuple(layout) if layout is not None else tuple(
+            LayerCache(pool="full", n_kv_heads=self.n_kv_heads,
+                       head_dim=self.head_dim, latent=self.latent)
+            for _ in range(n))
+        self.full_layers = [i for i, lc in enumerate(self.layout)
+                            if lc.pool == "full"]
+        self.window_layers = [i for i, lc in enumerate(self.layout)
+                              if lc.pool == "window"]
+        self.state_layers = [i for i, lc in enumerate(self.layout)
+                             if lc.state]
+        if len(self.full_layers) != n:
+            raise ValueError(
+                f"the layout has {len(self.full_layers)} full pools, the "
+                f"cache was built with {n}")
+        self.mixed = bool(self.window_layers or self.state_layers)
+        self.w_pages, self.lane_state = [], []
+        self.window = 0
+        self.window_pages_per_lane = 0
+        self.state_bytes_per_lane = 0
+        if not self.mixed:
+            return
+        for refused, what in (
+                (self.quantized, "an int8 cache"),
+                (self.tp_degree > 1,
+                 "tensor parallelism (tp_degree > 1)"),
+                (self.prefix_cache_enabled,
+                 "prefix_cache=True: the tree keys on pages, and a hit "
+                 "needs the lane state at the boundary")):
+            if refused:
+                raise NotImplementedError(
+                    "a cache with window pools or lane state beside its "
+                    f"pages is not built for {what}")
+        if not max_lanes or not prefill_chunk:
+            raise ValueError("a mixed layout is sized from max_lanes and "
+                             "prefill_chunk")
+        self.max_lanes = int(max_lanes)
+        ps = self.page_size
+        if self.window_layers:
+            geo = {(lc.head_dim, lc.window, lc.latent)
+                   for lc in map(self.layout.__getitem__,
+                                 self.window_layers)}
+            if len(geo) != 1 or not next(iter(geo))[2]:
+                raise NotImplementedError(
+                    "window layers keep one entry a token (latent=True) "
+                    f"of one width and one window, not {sorted(geo)}")
+            (whd, self.window, _), = geo
+            # the keys a chunk's rows may see start window - 1 before
+            # its first row: ceil((window + chunk) / page) pages, and one
+            # more because neither end need lie on a page boundary
+            self.window_pages_per_lane = math.ceil(
+                (self.window + int(prefill_chunk)) / ps) + 1
+            wn = self.max_lanes * self.window_pages_per_lane + 1
+            self.w_pages = [jnp.zeros((wn, ps, whd), self.dtype)
+                            for _ in self.window_layers]
+            self._wfree = deque(range(1, wn))     # page 0 = scratch
+            self._wtables: dict[object, list] = {}  # seq -> [first, pages]
+            self._wslots: dict[object, np.ndarray] = {}
+            self.state_bytes_per_lane += (
+                len(self.window_layers) * self.window_pages_per_lane
+                * ps * whd * self.dtype.itemsize)
+        for i in self.state_layers:
+            arrs = []
+            for _, shape, dt in self.layout[i].state:
+                dt = jnp.dtype(dt) if dt is not None else self.dtype
+                arrs.append(jnp.zeros((self.max_lanes + 1,) + tuple(shape),
+                                      dt))
+                self.state_bytes_per_lane += (
+                    int(np.prod(shape)) * dt.itemsize)
+            self.lane_state.append(tuple(arrs))
+        self._lane_free = deque(range(1, self.max_lanes + 1))  # 0 = scratch
+        self._lane_slot: dict[object, int] = {}
+
+    def _refuse_mixed(self, what):
+        if self.mixed:
+            raise NotImplementedError(
+                f"{what} is not built for a cache with window pools or "
+                "lane state beside its pages: its payload is pages of "
+                "one pool geometry")
+
+    def can_hold_lanes(self, n=1):
+        """Whether ``n`` more sequences fit the part of the cache that
+        is a fixed cost a lane (lane-state slots, window pages)."""
+        return not self.mixed or len(self._lane_free) >= n
+
+    def lane_slot(self, seq_id):
+        """The sequence's slot in the lane-state arrays."""
+        return self._lane_slot[seq_id]
+
+    def window_pages_held(self, seq_id):
+        """Pages of a window pool the sequence holds now."""
+        return len(self._wtables[seq_id][1]) if self.window_layers else 0
+
+    def window_table(self, seq_id):
+        """``(row int32 [window_pages_per_lane], base)``: the window
+        pools' pages the sequence holds, oldest first (pad = scratch),
+        and the position of the first slot of the first of them."""
+        first, pages = self._wtables[seq_id]
+        row = np.full(self.window_pages_per_lane, SCRATCH_PAGE, np.int32)
+        row[:len(pages)] = pages
+        return row, first * self.page_size
+
+    def window_slots(self, seq_id):
+        """Flat window-pool slots of the tokens the sequence's last
+        :meth:`append_slots` reserved."""
+        return self._wslots[seq_id]
+
+    def _append_window(self, seq_id, start, n_tokens):
+        """The window pools' side of :meth:`append_slots`: release the
+        pages no row from ``start`` on may see (every key of theirs lies
+        more than ``window - 1`` behind ``start``), then take pages for
+        positions ``start .. start + n_tokens - 1``. Cannot run out: the
+        pools hold ``window_pages_per_lane`` pages for each of
+        ``max_lanes`` sequences, and a lane slot was taken first."""
+        ps = self.page_size
+        tab = self._wtables[seq_id]
+        keep_from = max(0, start - self.window + 1) // ps
+        while tab[1] and tab[0] < keep_from:
+            self._wfree.append(tab[1].pop(0))
+            tab[0] += 1
+        if not tab[1]:
+            tab[0] = max(tab[0], start // ps)
+        slots = np.empty(n_tokens, np.int32)
+        for i in range(n_tokens):
+            pos = start + i
+            if pos // ps - tab[0] >= len(tab[1]):
+                tab[1].append(self._wfree.popleft())
+            slots[i] = tab[1][pos // ps - tab[0]] * ps + pos % ps
+        if len(tab[1]) > self.window_pages_per_lane:  # pragma: no cover
+            raise AssertionError(
+                f"sequence {seq_id!r} holds {len(tab[1])} window pages, "
+                f"over the bound {self.window_pages_per_lane}")
+        self._wslots[seq_id] = slots
+
+    def extra_operands(self):
+        """What a step program takes beside :meth:`program_operands`:
+        the window pools and the lane states, by layer (an empty dict
+        where every layer owns a full pool)."""
+        if not self.mixed:
+            return {}
+        return {"window": list(self.w_pages),
+                "state": list(self.lane_state)}
+
+    def store_extra(self, new):
+        if self.mixed:
+            self.w_pages = list(new["window"])
+            self.lane_state = [tuple(s) for s in new["state"]]
 
     def attach_tier(self, tier):
         """Bind a :class:`~.kvtier.KVTier` so prefix-cache evictions
         spill to the host tier.  ``None`` detaches."""
         if tier is not None:
             self._refuse_latent("kvtier (host/disk page tiers)")
+            self._refuse_mixed("kvtier (host/disk page tiers)")
         self._tier = tier
 
     def _refuse_latent(self, what):
@@ -341,6 +539,13 @@ class PagedKVCache:
         """Register an empty sequence (pages arrive via append_slots)."""
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id!r} already allocated")
+        if self.mixed:
+            if not self._lane_free:
+                raise OutOfPages(1, 0)     # lanes, not pages: admission
+            #                                counts them (can_hold_lanes)
+            self._lane_slot[seq_id] = self._lane_free.popleft()
+            if self.window_layers:
+                self._wtables[seq_id] = [0, []]
         self._tables[seq_id] = []
         self._lens[seq_id] = 0
 
@@ -350,6 +555,8 @@ class PagedKVCache:
         copy-on-writes it. O(pages) host work, zero device copies."""
         if child_id in self._tables:
             raise ValueError(f"sequence {child_id!r} already allocated")
+        self._refuse_mixed("fork (n > 1: the child would need the "
+                           "parent's lane state and window pages copied)")
         table = self._tables[parent_id]
         for p in table:
             self._rc[p] += 1
@@ -373,6 +580,13 @@ class PagedKVCache:
             if self._rc[p] == 0 and p not in self._cached:
                 self._free.append(p)
         del self._lens[seq_id]
+        if self.mixed:
+            # the slot's arrays are not zeroed here: the step starts a
+            # lane whose first row is position 0 from a zero state
+            self._lane_free.append(self._lane_slot.pop(seq_id))
+            if self.window_layers:
+                self._wfree.extend(self._wtables.pop(seq_id)[1])
+                self._wslots.pop(seq_id, None)
 
     # -- allocation --------------------------------------------------------
     def append_slots(self, seq_id, n_tokens):
@@ -421,6 +635,8 @@ class PagedKVCache:
             slots[i] = table[pos // self.page_size] * self.page_size \
                 + pos % self.page_size
         self._lens[seq_id] = ln + n_tokens
+        if self.window_layers:
+            self._append_window(seq_id, ln, n_tokens)
         return slots, copies
 
     def free_tail(self, seq_id, new_len):
@@ -437,6 +653,8 @@ class PagedKVCache:
         """
         if seq_id not in self._tables:
             raise KeyError(f"free_tail: unknown sequence {seq_id!r}")
+        self._refuse_mixed("free_tail (a rejected draft needs the lane "
+                           "state rolled back)")
         new_len = int(new_len)
         ln = self._lens[seq_id]
         if new_len < 0 or new_len > ln:
@@ -676,6 +894,7 @@ class PagedKVCache:
         re-prefill fallback covers it.
         """
         self._refuse_latent("pagewire / disagg page shipping (export_pages)")
+        self._refuse_mixed("pagewire / disagg page shipping (export_pages)")
         if seq_id not in self._tables:
             raise KeyError(f"export_pages: unknown sequence {seq_id!r}")
         table = self._tables[seq_id]
@@ -711,6 +930,7 @@ class PagedKVCache:
         failures roll back fully (no sequence state left behind).
         """
         self._refuse_latent("pagewire / disagg page shipping (import_pages)")
+        self._refuse_mixed("pagewire / disagg page shipping (import_pages)")
         self.check_geometry(meta)
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id!r} already allocated")

@@ -212,7 +212,10 @@ class ServingMetrics:
         # what the padded page tables cost (PR 30), counted on the host
         # from the arrays it packs: page-table entries the step's
         # attention gathers (tables gathered x table width) against the
-        # pages its live lanes hold (ceil(context_len / page_size))
+        # pages its live lanes hold (ceil(context_len / page_size)): of
+        # one layer where every layer owns a full pool; where the layers
+        # differ, summed over every layer that attends (a reader of
+        # another layer's pool gathers too) against every pool's pages
         self.attn_pages_gathered = Counter()
         self.attn_pages_live = Counter()
         self.prefix_hit_pages = Counter()     # prompt pages served from
@@ -263,6 +266,23 @@ class ServingMetrics:
         # what one cached token costs across every layer (a latent pool:
         # (rank + rope) x itemsize x layers; K and V by head otherwise)
         self.cache_bytes_per_token = Gauge()
+        # what a lane's FIXED part costs where the layers differ (lane
+        # states and the window pools' pages a lane may hold): nought
+        # where every layer owns a full pool
+        self.state_bytes_per_lane = Gauge()
+        # state-space and window layers in the step, counted on the host
+        # from what it packs (engine._count_mixed_step)
+        self.ssm_layer_steps = Counter()      # state layers x steps
+        self.ssm_lane_scans = Counter()       # ... x live lanes: a
+        #                                       state read and written
+        self.ssm_rows_scanned = Counter()     # ... x live packed rows
+        self.ssm_state_resets = Counter()     # lanes started from a
+        #                                       zero state (position 0)
+        self.window_pages_held = Counter()    # window pages held, summed
+        #                                       over live lanes, a window
+        #                                       layer a step
+        self.window_layer_steps = Counter()   # window layers x live
+        #                                       lanes x steps
 
     # the order incubate/moe.py::routing_counts packs its int32 [4] in
     MOE_COUNTS = ("moe_assignments", "moe_experts_hit",

@@ -14,7 +14,9 @@ Policy, per iteration (``schedule(now)``):
    head of the admitted-but-unprefilled queue) rides along, so admission
    never starves decode latency and compile shapes stay at two classes.
 4. **Admission by free-page watermark** — a waiting request is admitted
-   only when the available pages (free list + reclaimable cached pages)
+   only when a lane is free (and, where the cache keeps a fixed part a
+   lane beside its pages -- lane state, window pools -- a slot of that:
+   ``cache.can_hold_lanes``) and the available pages (free list + reclaimable cached pages)
    cover its FULL token history plus a reserved watermark (head-room
    that keeps running decodes from thrashing the preemption path on
    every page boundary). With the prefix cache on, admission first runs
@@ -264,6 +266,13 @@ class Scheduler:
             req = self.waiting[0]
             slots = len(self.prefill_queue) + len(self.running)
             if slots + req.n > self.max_batch:
+                break
+            promised = sum(1 for r in self.prefill_queue
+                           if not self.cache.has_seq(r.seq_id))
+            if not self.cache.can_hold_lanes(promised + req.n):
+                # the part of a mixed cache that is a fixed cost a lane
+                # (lane state, window pools) is counted in lanes; an
+                # admitted request takes its slot at its first chunk
                 break
             hist = req.token_history()
             if self.cache.prefix_cache_enabled \
