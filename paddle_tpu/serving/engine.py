@@ -636,6 +636,8 @@ class ServingEngine:
         list (requests are requeued for recompute, generated tokens
         kept), so the engine stays reusable: a later run() retries them
         and — greedy or seeded — reproduces the uninterrupted streams.
+        A step program that failed after it was handed the pools costs
+        the prefix tree besides (``release_live``).
         """
         steps = 0
         try:
@@ -780,7 +782,9 @@ class ServingEngine:
         """Error path: free every live request's pages and requeue the
         requests (front of queue, recompute-style — generated tokens
         kept) so a failed run() leaves the allocator clean and the
-        engine reusable."""
+        engine reusable. Where the failed step had already been handed
+        the pools, they are gone with it: the caches are rebuilt empty
+        (``PagedKVCache.recover_lost_pools``), prefix tree and all."""
         for r in self.scheduler.live_requests():
             if self.cache.has_seq(r.seq_id):
                 self.cache.free_seq(r.seq_id)
@@ -796,15 +800,25 @@ class ServingEngine:
             if self.cache.has_seq(r.seq_id):
                 self.cache.free_seq(r.seq_id)
             self._free_draft_seq(r.seq_id)
-        # WAITING requests hold pages too: add_request pins the matched
-        # prefix (acquire_prefix) before the request is ever scheduled,
-        # so a loop failure landing between admit and first schedule
-        # would leak those pins forever. Free the seq and leave the
-        # request queued — _admit re-matches the prefix on admission
-        # (the recompute path) whenever the seq is gone.
         for rid in list(self._held):
             self.release_request(rid)
         self._release_chaos_spike()
+        # a step that failed AFTER its dispatch took the pools with it
+        # (they are donated): every sequence is released by now, so the
+        # caches come back empty and usable, and the requeued requests
+        # recompute into them
+        for what, cache in (("the cache", self.cache),
+                            ("the draft's cache", self._draft_cache)):
+            lost = cache.recover_lost_pools() if cache is not None else None
+            if lost is not None:
+                _log.error(json.dumps({
+                    "event": "pools_lost",
+                    "detail": f"a step program failed after {what}'s "
+                              "pools were donated to it; they are built "
+                              "anew (zeros), every live request is "
+                              "requeued for recompute and the prefix "
+                              f"tree's {lost} cached page(s) count as "
+                              "evicted"}))
 
     def _maybe_inject_fault(self):
         """Chaos fault hook, evaluated at the step BOUNDARY (before any
@@ -1111,9 +1125,12 @@ class ServingEngine:
             # program to TP=1), a self-draft's sharded tensors fall to
             # GSPMD auto.  Either way the verify step's deterministic-
             # sample matching keeps the EMITTED stream token-exact.
+            # The draft's pools are donated as the step's are: a
+            # program that returns a pool was given it.
             self._draft_fn = jax.jit(
                 functools.partial(_draft_catchup_pure, self.draft,
-                                  self._draft_core, self._draft_window))
+                                  self._draft_core, self._draft_window),
+                donate_argnums=(6, 7))
         dc = self._draft_cache
         dwarrs = [t._data for t in self.draft._gen_state_tensors()]
         k_ops, v_ops = dc.program_operands()
@@ -1137,7 +1154,7 @@ class ServingEngine:
             self._propose_fn = jax.jit(
                 functools.partial(_spec_draft_pure, self.draft,
                                   self._draft_core, self._draft_window),
-                static_argnums=(0,))
+                static_argnums=(0,), donate_argnums=(8, 9))
         dc = self._draft_cache
         dwarrs = [t._data for t in self.draft._gen_state_tensors()]
         k_ops, v_ops = dc.program_operands()
@@ -1999,38 +2016,52 @@ class ServingEngine:
                           "gather path"}))
         self.metrics.tp_kernel_fallbacks.inc()
 
-    def _run_ragged_step(self, ids, positions, pt, cl, ql, qoff,
-                         slot_map, samp, lane_extras):
+    def _step_program(self):
+        """The engine's one step function, built once. ONE jit fn; the
+        token capacity in {small, mixed} bounds its trace cache at two
+        entries — the <= 2-program-class contract. The sampler is
+        always compiled sample-capable, so greedy and sampled steps
+        share a class: a batch's sort and draw run under conditions on
+        its own sampling arguments (sampling.py), and a greedy lane
+        takes the argmax and the raw logprob either way.
+
+        The cache's state (the K and V pools, with their scale rows
+        where the cache is int8; a mixed cache's window pools and lane
+        states) is DONATED: the program owns what it is handed, writes
+        the step's rows in place, and its outputs are the same buffers
+        (docs/SERVING.md "Who owns the pools"). A jit of a
+        ``functools.partial`` has no name of its own: the program is
+        ``jit__unknown``, which the benchmark's mixes match on."""
         import jax
-        import jax.numpy as jnp
-        self._tp_kernel_guard()
         if self._ragged_fn is None:
-            # ONE jit fn; the token capacity in {small, mixed} bounds
-            # its trace cache at two entries — the <= 2-program-class
-            # contract. The sampler is always compiled sample-capable,
-            # so greedy and sampled steps share a class: a batch's sort
-            # and draw run under conditions on its own sampling
-            # arguments (sampling.py), and a greedy lane takes the
-            # argmax and the raw logprob either way.
             self._ragged_fn = jax.jit(
                 functools.partial(_ragged_step_pure, self.model,
                                   self._core, self.window, self._tp,
-                                  k1=self.spec_k + 1))
+                                  k1=self.spec_k + 1),
+                donate_argnums=(9, 10, 11))     # k_pages, v_pages, extra
+        return self._ragged_fn
+
+    def _run_ragged_step(self, ids, positions, pt, cl, ql, qoff,
+                         slot_map, samp, lane_extras):
+        import jax.numpy as jnp
+        self._tp_kernel_guard()
         warrs = [t._data for t in self.model._gen_state_tensors()]
-        k_ops, v_ops = self.cache.program_operands()
-        # a mixed cache's window pools and lane states, with the lanes'
-        # slots and window tables (empty where every layer owns a full
-        # pool: nothing more enters the program)
-        extra = dict(self.cache.extra_operands(),
-                     **{k: jnp.asarray(a) for k, a in lane_extras.items()})
+        # what the program is handed and gives back: the pools and, of
+        # a mixed cache, the window pools and lane states (``extra``:
+        # empty where every layer owns a full pool); the lanes' slots
+        # and window tables are the step's own host arrays
+        state = (*self.cache.program_operands(),
+                 self.cache.extra_operands())
         tok, lp, logits, k_pages, v_pages, moe_counts, extra = \
-            self._ragged_fn(
+            self._step_program()(
                 warrs, jnp.asarray(ids), jnp.asarray(positions),
                 jnp.asarray(pt), jnp.asarray(cl), jnp.asarray(ql),
                 jnp.asarray(qoff), jnp.asarray(slot_map),
-                tuple(jnp.asarray(a) for a in samp), k_ops, v_ops, extra)
+                tuple(jnp.asarray(a) for a in samp), *state,
+                {k: jnp.asarray(a) for k, a in lane_extras.items()})
         self.cache.store_operands(k_pages, v_pages)
         self.cache.store_extra(extra)
+        self.metrics.pool_bytes_donated.set(_bytes_handed_over(state))
         self._logits_dev = logits          # [T, V], fetched on demand
         self._moe_counts_dev = moe_counts  # fetched with the tokens
         self._count_dispatch(("ragged", ids.shape[1]))
@@ -2282,9 +2313,22 @@ def _step_tables_gathered(core, lanes, t, k1):
 
 # -- the step program (round 22 / PR 18) -----------------------------------
 
+def _bytes_handed_over(state):
+    """Bytes of the cache state a program took for its own: the operands
+    its dispatch left deleted (donated). Host arithmetic over shapes;
+    nought where a program was handed nothing."""
+    import jax
+    return sum(a.nbytes for a in jax.tree.leaves(state) if a.is_deleted())
+
+
 def _ragged_step_pure(model, core, window, tp, warrs, ids, positions,
                       pt, cl, ql, qoff, slot_map, samp, k_pages,
-                      v_pages, extra=None, k1=1):
+                      v_pages, extra=None, lanes=None, k1=1):
+    """``extra``: a mixed cache's window pools and lane states
+    (``PagedKVCache.extra_operands``), returned updated; ``lanes``: the
+    lanes' slots and window tables of this step, host arrays that are
+    not state (a caller that lowers the step by hand may pass both in
+    ``extra``)."""
     tensors = model._gen_state_tensors()
     saved = [(t, t._data) for t in tensors]
     for t, arr in zip(tensors, warrs):
@@ -2292,7 +2336,8 @@ def _ragged_step_pure(model, core, window, tp, warrs, ids, positions,
     try:
         return _ragged_step_body(model, core, window, tp, ids,
                                  positions, pt, cl, ql, qoff, slot_map,
-                                 samp, k_pages, v_pages, extra, k1)
+                                 samp, k_pages, v_pages,
+                                 {**(extra or {}), **(lanes or {})}, k1)
     finally:
         for t, arr in saved:
             t._data = arr

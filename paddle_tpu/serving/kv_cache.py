@@ -229,7 +229,12 @@ class PagedKVCache:
                 f"({per_page} bytes/page across {n_layers} layers)")
         self.num_pages = num_pages
         self.bytes_total = num_pages * per_page
-        # device buffers: per layer, [num_pages, page_size, n_kv, hd]
+        # device buffers: per layer, [num_pages, page_size, n_kv, hd].
+        # The cache OWNS them: a step program is handed them (donated)
+        # through program_operands() / extra_operands() and gives them
+        # back through store_operands() / store_extra(); nothing else
+        # keeps a pool across a step (docs/SERVING.md "Who owns the
+        # pools")
         shape = ((num_pages, self.page_size, self.head_dim) if self.latent
                  else (num_pages, self.page_size, self.n_kv_heads,
                        self.head_dim))
@@ -709,6 +714,45 @@ class PagedKVCache:
         self.k_scales = [s for _, s in new_k]
         self.v_pages = [p for p, _ in new_v]
         self.v_scales = [s for _, s in new_v]
+
+    def recover_lost_pools(self):
+        """The failure path's: a step program that fails after it was
+        handed the pools takes them with it (they were donated, so the
+        arrays held here are deleted). Brings the cache back usable:
+        fresh zero pools, window pools and lane states, each placed as
+        the lost one was, and an empty prefix tree — the cached pages'
+        bytes are gone, so they count as evictions (and are not
+        spilled: there is nothing to spill). Called once every sequence
+        is released (``ServingEngine.release_live``). Returns the cached
+        pages lost, or ``None`` where no pool was lost: a step that
+        failed before its dispatch costs nothing."""
+        import jax
+        import jax.numpy as jnp
+        arrays = jax.tree.leaves(
+            (self._all_pools(), self.w_pages, self.lane_state))
+        if not any(a.is_deleted() for a in arrays):
+            return None
+        if self._tables:
+            raise RuntimeError(
+                f"recover_lost_pools: {len(self._tables)} sequence(s) "
+                "still map pages whose bytes are lost; release them first")
+        for a in arrays:            # a pool that survived holds half a page
+            if not a.is_deleted():
+                a.delete()
+
+        def fresh(a):
+            return jnp.zeros(a.shape, a.dtype, device=a.sharding)
+
+        self._store_pools([fresh(a) for a in self._all_pools()])
+        self.w_pages = [fresh(a) for a in self.w_pages]
+        self.lane_state = [tuple(fresh(a) for a in s)
+                           for s in self.lane_state]
+        lost = len(self._cached)
+        self._free.extend(self._cached)
+        self._cached = {}
+        self._prefix_root = _RadixNode(None, None, None, 0)
+        self.prefix_evictions += lost
+        return lost
 
     def page_table(self, seq_id, max_pages):
         """Padded int32 page-table row for the fixed-shape step program

@@ -270,6 +270,11 @@ class ServingMetrics:
         # states and the window pools' pages a lane may hold): nought
         # where every layer owns a full pool
         self.state_bytes_per_lane = Gauge()
+        # bytes of cache state (pools, scale rows, window pools, lane
+        # states) the last step handed to its program for good: the
+        # operands its dispatch left deleted. Nought: the step copies
+        # every pool it writes
+        self.pool_bytes_donated = Gauge()
         # state-space and window layers in the step, counted on the host
         # from what it packs (engine._count_mixed_step)
         self.ssm_layer_steps = Counter()      # state layers x steps

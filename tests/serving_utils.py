@@ -172,31 +172,26 @@ def hlo_sorts(hlo_text):
 def ragged_step_avals(engine, tcap, sds=None):
     """The operands ``engine._run_ragged_step`` hands the step program
     at token capacity ``tcap``, as shapes (``sds(shape, dtype)`` makes
-    one: ``jax.ShapeDtypeStruct``, or one with a described sharding)."""
+    one: ``jax.ShapeDtypeStruct``, or one with a described sharding):
+    the weights, the packed step, the cache's state (the pools, with
+    their scale rows where the cache is int8; a mixed cache's window
+    pools and lane states) and the lanes' slots and window tables."""
     import jax
     import jax.numpy as jnp
     sds = sds or jax.ShapeDtypeStruct
-    lanes = engine._ragged_lanes
+    lanes, cache = engine._ragged_lanes, engine.cache
     i32 = lambda *s: sds(s, jnp.int32)  # noqa: E731
     f32 = lambda *s: sds(s, jnp.float32)  # noqa: E731
-    k_ops, v_ops = engine.cache.program_operands()
-    return ([sds(t._data.shape, t._data.dtype)
-             for t in engine.model._gen_state_tensors()],
+    shapes = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: sds(a.shape, a.dtype), tree)
+    k_ops, v_ops = shapes(cache.program_operands())
+    return (shapes([t._data for t in engine.model._gen_state_tensors()]),
             i32(1, tcap), i32(1, tcap),
             i32(lanes, engine.max_pages_per_seq), i32(lanes), i32(lanes),
             i32(lanes), i32(1, tcap),
             (sds((tcap,), jnp.bool_), f32(tcap), i32(tcap), f32(tcap),
              i32(tcap), i32(tcap)),
-            [sds(a.shape, a.dtype) for a in k_ops],
-            [sds(a.shape, a.dtype) for a in v_ops])
-
-
-def ragged_step_fn(engine):
-    import functools
-
-    import jax
-
-    from paddle_tpu.serving import engine as eng_mod
-    return jax.jit(functools.partial(
-        eng_mod._ragged_step_pure, engine.model, engine._core,
-        engine.window, None, k1=engine.spec_k + 1))
+            k_ops, v_ops, shapes(cache.extra_operands()),
+            dict(lane_slot=i32(lanes),
+                 wpt=i32(lanes, max(cache.window_pages_per_lane, 1)),
+                 wbase=i32(lanes), wslots=i32(tcap)) if cache.mixed else {})

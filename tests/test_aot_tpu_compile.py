@@ -299,8 +299,7 @@ class TestRaggedStepTail:
         from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
         from paddle_tpu.serving import ServingEngine
 
-        from serving_utils import (hlo_sorts, ragged_step_avals,
-                                   ragged_step_fn)
+        from serving_utils import hlo_sorts, ragged_step_avals
         with P.LazyGuard():
             model = LlamaForCausalLM(LlamaConfig(
                 vocab_size=SZ.vocab, hidden_size=SZ.hidden,
@@ -320,8 +319,60 @@ class TestRaggedStepTail:
             eng, eng._ragged_tok_mixed,
             lambda shape, dt: _sds(tuple(shape), dt, one))
         avals[0][:] = [_sds(a.shape, BF16, one) for a in avals[0]]
-        c = ragged_step_fn(eng).lower(*avals).compile()   # about 25 s
+        c = eng._step_program().lower(*avals).compile()   # about 25 s
         assert hlo_sorts(c.as_text()) == (0, 1)
+
+
+class TestStepOwnsItsPools:
+    """The engine's own step function (``ServingEngine._step_program``,
+    the jit ``_run_ragged_step`` dispatches, not one made here) compiled
+    for the described chip: every byte of the cache's state goes in
+    donated and comes out in the same buffer, whatever the cache is
+    made of and in both step classes. Tiny widths: what is read is the
+    compiler's aliasing, not a size (the cells' sizes: PERF.md §4)."""
+
+    @staticmethod
+    def _llama(**ekw):
+        import paddle_tpu as P
+        from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+        from paddle_tpu.serving import ServingEngine
+        P.seed(0)
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=97, hidden_size=32, intermediate_size=64,
+            num_hidden_layers=2, num_attention_heads=4,
+            max_position_embeddings=64))
+        model.eval()
+        return ServingEngine(model, page_size=4, num_pages=32, max_batch=4,
+                             prefill_chunk=8, **ekw)
+
+    @pytest.mark.parametrize("chunk", [False, True],
+                             ids=["decode_class", "chunk_class"])
+    @pytest.mark.parametrize("cache", ["dense", "int8", "latent", "mixed"])
+    def test_every_byte_of_cache_state_is_aliased(self, one, cache, chunk):
+        from serving_utils import ragged_step_avals
+        from test_serving_ragged import DONATION_CASES
+        eng = {"dense": self._llama,
+               "int8": lambda: self._llama(cache_dtype="int8"),
+               "latent": DONATION_CASES["latent"][0],
+               "mixed": DONATION_CASES["mixed"][0]}[cache]()
+        c = eng.cache
+        assert (c.quantized, c.latent and not c.mixed, c.mixed) == (
+            cache == "int8", cache == "latent", cache == "mixed")
+        avals = ragged_step_avals(
+            eng, eng._ragged_tok_mixed if chunk else eng._ragged_tok_small,
+            lambda shape, dt: _sds(tuple(shape), dt, one))
+        state = jax.tree.leaves(avals[9:12])
+        assert len(state) == len(jax.tree.leaves(
+            (c.program_operands(), c.extra_operands())))
+        compiled = eng._step_program().lower(*avals).compile()
+        assert "jit__unknown" in compiled.as_text()[:200]
+        # the state's bytes as the chip holds them (tiny widths pad to
+        # the device's tiles): what a program of those operands alone
+        # is handed
+        held = jax.jit(lambda *s: s).lower(
+            *state).compile().memory_analysis().argument_size_in_bytes
+        assert held >= sum(a.size * a.dtype.itemsize for a in state) > 0
+        assert compiled.memory_analysis().alias_size_in_bytes == held
 
 
 def test_paged_kernel_knob_raises_off_cpu(monkeypatch):

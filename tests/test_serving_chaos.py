@@ -15,6 +15,7 @@ import sys
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -304,6 +305,107 @@ class TestEngineChaos:
                        == eng.cache.allocatable_pages, timeout=10)
         finally:
             fe.drain()
+        verify_engine_quiescent(eng)
+
+
+class TestStepFailsAfterDispatch:
+    """A step program that fails once it was handed the pools takes
+    them with it (they are donated: PR 34). The engine comes back with
+    empty caches and an empty prefix tree, and the next ``run()``
+    recomputes every requeued request to the uninterrupted streams."""
+
+    @pytest.mark.parametrize("ekw", [
+        dict(prefix_cache=True), dict(prefix_cache=True,
+                                      cache_dtype="int8"),
+        dict(speculative_k=2)], ids=["prefix_cache", "int8_kv", "draft"])
+    def test_lost_pools_come_back_empty_and_the_streams_whole(
+            self, ekw, caplog):
+        rng = np.random.default_rng(6)
+        head = rng.integers(0, 97, 9).astype(np.int32)
+        prompts = [np.concatenate([head, p]) for p in rng_prompts(5, seed=6)]
+        ekw = dict(ekw, max_batch=4)
+        if "speculative_k" in ekw:
+            ekw["draft_model"] = tiny_model(seed=3)
+        want = oracle_tokens(prompts, 8, engine_kw=dict(ekw))
+        eng = make_engine(**ekw)
+        first = [eng.add_request(p, max_new_tokens=8) for p in prompts[:2]]
+        done = eng.run()                 # fills the prefix tree, if any
+        assert eng.cache.cached_pages > 0 or not ekw.get("prefix_cache")
+        evicted = eng.cache.prefix_evictions
+        rest = [eng.add_request(p, max_new_tokens=8) for p in prompts[2:]]
+        real, calls = eng._step_program(), []
+
+        def fails_after_dispatch(*operands):
+            out = real(*operands)        # the pools are the program's now
+            calls.append(1)
+            if len(calls) == 4:
+                calls.append(eng.cache.cached_pages)
+                raise RuntimeError("the device fell over")
+            return out
+
+        eng._ragged_fn = fails_after_dispatch
+        with caplog.at_level("ERROR", logger="paddle_tpu.serving"):
+            with pytest.raises(RuntimeError, match="fell over"):
+                eng.run()
+        assert "pools_lost" in caplog.text
+        c, cached = eng.cache, calls[-1]
+        # the step's pools are new and empty; the draft's were not in
+        # the failed program and are as its last program left them
+        for cache, lost in ((c, True), (eng._draft_cache, False)):
+            if cache is not None:
+                pools = jax.tree.leaves(cache.program_operands())
+                assert pools and not any(a.is_deleted() for a in pools)
+                assert not lost or not any(np.asarray(a).any()
+                                           for a in pools)
+                assert not cache.live_seqs()
+        assert c.cached_pages == 0 and c.prefix_tree_depth == 0
+        assert c.prefix_evictions == evicted + cached
+        assert c.available_pages == c.allocatable_pages
+        # requeued, generated tokens kept; the next run recomputes them
+        assert eng.scheduler.queue_depth() == len(rest)
+        eng._ragged_fn = real
+        res = {**done, **eng.run()}
+        assert [res[r]["tokens"] for r in first + rest] == want
+        assert sum(res[r]["preemptions"] for r in rest) > 0
+        verify_engine_quiescent(eng)
+
+
+    def test_a_failed_draft_program_costs_the_drafts_pools_alone(
+            self, caplog):
+        """The proposal scan is handed the draft's pools the same way:
+        when it fails after dispatch the draft's cache is built anew,
+        the target's pools and prefix tree are untouched."""
+        prompts = rng_prompts(3, seed=8)
+        ekw = dict(speculative_k=2, draft_model=tiny_model(seed=3),
+                   prefix_cache=True, max_batch=4)
+        want = oracle_tokens(prompts, 8, engine_kw=dict(ekw))
+        eng = make_engine(**ekw)
+        first = eng.add_request(prompts[0], max_new_tokens=8)
+        done = eng.run()                 # builds the draft's programs
+        rest = [eng.add_request(p, max_new_tokens=8) for p in prompts[1:]]
+        real, calls = eng._propose_fn, []
+
+        def fails_after_dispatch(*operands):
+            out = real(*operands)
+            calls.append(1)
+            if len(calls) == 2:
+                raise RuntimeError("the device fell over")
+            return out
+
+        eng._propose_fn = fails_after_dispatch
+        with caplog.at_level("ERROR", logger="paddle_tpu.serving"):
+            with pytest.raises(RuntimeError, match="fell over"):
+                eng.run()
+        assert "the draft's cache" in caplog.text
+        assert "after the cache's" not in caplog.text
+        cached = eng.cache.cached_pages
+        assert cached > 0                # the target's tree stands
+        pools = jax.tree.leaves(eng._draft_cache.program_operands())
+        assert not any(a.is_deleted() or np.asarray(a).any()
+                       for a in pools)
+        eng._propose_fn = real
+        res = {**done, **eng.run()}
+        assert [res[r]["tokens"] for r in [first] + rest] == want
         verify_engine_quiescent(eng)
 
 

@@ -23,6 +23,9 @@ speculative rounds (self-draft accepts 100%); where a recompute or a
 verify round runs a token through a product of another row count, the
 tokens and the logprobs within ``CROSS_SHAPE_ULPS``.
 """
+import functools
+import warnings
+
 import numpy as np
 import pytest
 
@@ -31,6 +34,7 @@ import jax.numpy as jnp
 
 import paddle_tpu as P
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.serving import engine as eng_mod
 from paddle_tpu.serving import (ServingEngine, paged_attention,
                                 paged_attention_ref,
                                 ragged_paged_attention)
@@ -223,10 +227,10 @@ class TestRaggedOracle:
         rectangle's and the chunk's gathers, [lanes, pages, ...], and
         no [T, pages, ...] one; XLA:CPU's temporaries of the attention
         call are under a quarter of the per-token form's."""
-        from serving_utils import ragged_step_avals, ragged_step_fn
+        from serving_utils import ragged_step_avals
         eng = ServingEngine(tiny_model(), **ENG_KW)
         t, mp = eng._ragged_tok_mixed, eng.max_pages_per_seq
-        text = ragged_step_fn(eng).lower(
+        text = eng._step_program().lower(
             *ragged_step_avals(eng, t)).as_text()
         assert t == 12 and f"tensor<4x{mp}x4x4x8xf32>" in text
         assert f"tensor<1x{mp}x4x4x8xf32>" in text
@@ -385,6 +389,141 @@ MIXED_REQ = [dict(), dict(do_sample=True, temperature=0.9, seed=7),
              dict(do_sample=True, top_p=0.8, seed=11), dict()]
 
 
+# -- the step owns its pools (PR 34): each case builds an engine and
+# drives it to a comparable result; ``undonated`` takes donation out
+
+def undonated(eng):
+    """The engine's programs as plain jits of the same pure functions:
+    what the step was before it was handed its pools for good."""
+    eng._ragged_fn = jax.jit(functools.partial(
+        eng_mod._ragged_step_pure, eng.model, eng._core, eng.window,
+        eng._tp, k1=eng.spec_k + 1))
+    if eng.draft is not None:
+        eng._draft_fn = jax.jit(functools.partial(
+            eng_mod._draft_catchup_pure, eng.draft, eng._draft_core,
+            eng._draft_window))
+        eng._propose_fn = jax.jit(functools.partial(
+            eng_mod._spec_draft_pure, eng.draft, eng._draft_core,
+            eng._draft_window), static_argnums=(0,))
+    return eng
+
+
+def _llama_case(max_new=8, n_prompts=5, shared=0, seed=3, draft_seed=None,
+                **ekw):
+    def build():
+        kw = {**ENG_KW, **ekw}
+        if draft_seed is not None:    # its own weights: some rounds reject
+            kw["draft_model"] = tiny_model(seed=draft_seed)
+        return ServingEngine(tiny_model(seed=seed), **kw)
+
+    def drive(eng):
+        rng = np.random.default_rng(seed)
+        head = rng.integers(0, 97, shared).astype(np.int32)
+        prompts = [np.concatenate([head, rng.integers(
+            0, 97, int(rng.integers(2, 11))).astype(np.int32)])
+            for _ in range(n_prompts)]
+        return serve_streams(eng, prompts, MIXED_REQ[:n_prompts], max_new)
+    return build, drive
+
+
+def _latent_case():
+    def build():
+        from benchmark.harness import weights_latent_moe as W
+        from paddle_tpu.models import (LatentMoEConfig,
+                                       LatentMoEForCausalLM)
+        from test_latent_moe import CFG, ENGINE, place
+        with P.LazyGuard():
+            model = LatentMoEForCausalLM(
+                LatentMoEConfig.from_published(CFG))
+        place(model, W.make(11, CFG), W.program_names(CFG))
+        model.eval()
+        return ServingEngine(model, eos_token_id=None, **ENGINE)
+
+    def drive(eng):
+        assert eng.cache.latent and not eng.cache.v_pages
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(0, 320, n).astype(np.int32)
+                   for n in (11, 5, 19)]
+        return serve_streams(eng, prompts, MIXED_REQ[:3], 6)
+    return build, drive
+
+
+def _mixed_case():
+    def build():
+        import test_sambay
+        model, _ = test_sambay.build()
+        return ServingEngine(model, ragged=True, **test_sambay.ENGINE)
+
+    def drive(eng):
+        from test_sambay import prompt
+        assert eng.cache.mixed and eng.cache.w_pages \
+            and eng.cache.lane_state
+        prompts = [prompt(n, seed=n) for n in (19, 11, 27)]
+        return serve_streams(eng, prompts, MIXED_REQ[:3], 6)
+    return build, drive
+
+
+def _fork_case():
+    """n = 3: the children share the prompt's pages and the first
+    append into the shared tail page copies it (``apply_copies``, eager,
+    between the steps, on the pools the last step gave back)."""
+    build, _ = _llama_case()
+
+    def drive(eng):
+        events = []
+        eng.on_event = events.append
+        eng.add_request(np.arange(5, 16, dtype=np.int32), max_new_tokens=7,
+                        do_sample=True, temperature=0.9, seed=5, n=3,
+                        logprobs=True)
+        eng.run()
+        assert eng.metrics.cow_copies.value > 0
+        streams = {}
+        for ev in events:
+            if ev["type"] == "token":
+                streams.setdefault(ev["req_id"], []).append(
+                    (int(ev["token"]), np.float32(
+                        ev["logprob"]).view(np.uint32).item()))
+        assert len(streams) == 3
+        return [streams[r] for r in sorted(streams)]
+    return build, drive
+
+
+def _export_case():
+    """A live sequence's pages exported between two steps (the gather
+    reads the pools the last step gave back), then the run goes on."""
+    build, _ = _llama_case()
+
+    def drive(eng):
+        rid = eng.add_request(np.arange(3, 22, dtype=np.int32),
+                              max_new_tokens=9, logprobs=True)
+        for _ in range(5):
+            eng.step()
+        seq = eng.request(rid).seq_id
+        meta, k, v = eng.cache.export_pages(seq)
+        assert meta["n_pages"] > 0
+        eng.step()
+        again = eng.cache.export_pages(seq)
+        res = eng.run()
+        return ([a.tolist() for a in k + v],
+                [a.tolist() for a in again[1] + again[2]],
+                list(map(int, res[rid]["tokens"])))
+    return build, drive
+
+
+DONATION_CASES = {
+    "dense": _llama_case(),
+    "int8": _llama_case(cache_dtype="int8"),
+    "latent": _latent_case(),
+    "mixed": _mixed_case(),
+    "prefix_cache": _llama_case(shared=9, prefix_cache=True, max_new=12,
+                                num_pages=24),
+    "fork_copy_on_write": _fork_case(),
+    "page_export_between_steps": _export_case(),
+    "speculative_k2_with_a_draft": _llama_case(draft_seed=9,
+                                               speculative_k=2),
+}
+
+
 class TestRaggedEngine:
     def test_token_exactness_greedy_and_seeded(self):
         m = tiny_model()
@@ -505,6 +644,35 @@ class TestRaggedEngine:
         assert_same_but_for_recompute(
             eng, got, served_alone(m, prompts, kws, 16, **ekw))
         assert eng.metrics.step_program_classes.value <= 2
+
+    @pytest.mark.parametrize("case", sorted(DONATION_CASES))
+    def test_donated_pools_serve_the_undonated_streams(self, case):
+        """The step is handed its pools for good (donated) and writes
+        its rows in place: the same operations on the same values, one
+        buffer fewer. Against the same engine with donation taken out
+        (the same pure functions under a plain jit): tokens and logprob
+        bits equal, and whatever else the case reads between steps;
+        ``pool_bytes_donated`` is every byte of the cache's state, and
+        no step leaves a donated buffer unused."""
+        build, drive = DONATION_CASES[case]
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            eng = build()
+            handed = sum(a.nbytes for a in jax.tree.leaves(
+                (eng.cache.program_operands(), eng.cache.extra_operands())))
+            got = drive(eng)
+            plain = undonated(build())
+            want = drive(plain)
+        assert got == want
+        assert handed > 0
+        assert eng.metrics.pool_bytes_donated.value == handed
+        assert plain.metrics.pool_bytes_donated.value == 0
+        assert not [w for w in caught if "donated" in str(w.message)], \
+            [str(w.message) for w in caught]
+        # the cache holds live arrays after the run: nothing it owns was
+        # left behind in a program
+        assert not any(a.is_deleted() for a in jax.tree.leaves(
+            (eng.cache.program_operands(), eng.cache.extra_operands())))
 
     def test_mixed_step_one_dispatch_one_fetch(self):
         """The acceptance criterion, asserted by the metrics: a step

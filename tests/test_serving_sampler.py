@@ -19,8 +19,7 @@ import jax.numpy as jnp
 from paddle_tpu.serving import ServingEngine, sampling
 from paddle_tpu.serving.sampling import _lane_keys, fused_sample
 
-from serving_utils import (hlo_sorts, ragged_step_avals, ragged_step_fn,
-                           served_alone)
+from serving_utils import hlo_sorts, ragged_step_avals, served_alone
 from test_serving_ragged import run_fleet, tiny_model
 
 
@@ -155,7 +154,7 @@ def _engine(m, **kw):
 def test_ragged_program_sorts_only_under_a_condition(mixed):
     eng = _engine(tiny_model())
     tcap = eng._ragged_tok_mixed if mixed else eng._ragged_tok_small
-    low = ragged_step_fn(eng).lower(*ragged_step_avals(eng, tcap))
+    low = eng._step_program().lower(*ragged_step_avals(eng, tcap))
     outside, inside = hlo_sorts(low.as_text(dialect="hlo"))
     assert (outside, inside) == (0, 1)
     assert low.out_info[2].shape == (tcap, 97)
