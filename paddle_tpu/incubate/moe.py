@@ -332,19 +332,42 @@ class MoELayer(Layer):
 
 # -- dropless routing over the experts held here ---------------------------
 
-def dropless_route(y, w_router, bias, top_k, scale, norm_topk_prob=True):
-    """The ``noaux_tc`` sigmoid router without group limits, in float32:
-    ``s = sigmoid(y W_r)``; the ``top_k`` of ``s + bias`` are chosen (the
-    correction bias moves the choice, never the weight); the weights are
-    the chosen ``s``, normalised to sum 1 where asked, times ``scale``.
+def dropless_route(y, w_router, bias, top_k, scale, norm_topk_prob=True,
+                   n_group=1, topk_group=1):
+    """The ``noaux_tc`` sigmoid router, in float32: ``s = sigmoid(y
+    W_r)``; the ``top_k`` of ``s + bias`` are chosen (the correction bias
+    moves the choice, never the weight); the weights are the chosen
+    ``s``, normalised to sum 1 where asked, times ``scale``. With
+    ``n_group > 1`` the choice is group-limited (DeepSeek-V3's): the
+    experts fall into ``n_group`` groups of consecutive ids, a group
+    scores the sum of its two best ``s + bias``, and the ``top_k`` are
+    taken among the experts of the ``topk_group`` best groups alone.
     y [T, H] -> (expert ids [T, k] int32, weights [T, k] float32)."""
     s = jax.nn.sigmoid(jnp.matmul(y.astype(jnp.float32),
                                   w_router.astype(jnp.float32)))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    choice = s + bias.astype(jnp.float32)
+    if n_group > 1:
+        choice = group_limited(choice, n_group, topk_group)
+    _, idx = jax.lax.top_k(choice, top_k)
     g = jnp.take_along_axis(s, idx, -1)
     if norm_topk_prob:
         g = g / (jnp.sum(g, -1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), g * scale
+
+
+def group_limited(choice, n_group, topk_group):
+    """``choice [T, E]`` with every expert outside the ``topk_group``
+    best of ``n_group`` groups set to -inf; a group scores the sum of
+    its two best entries."""
+    with jax.named_scope("moe_group_route"):
+        t, e = choice.shape
+        grouped = choice.reshape(t, n_group, e // n_group)
+        score = jnp.sum(jax.lax.top_k(grouped, 2)[0], -1)     # [T, G]
+        _, best = jax.lax.top_k(score, topk_group)
+        keep = jnp.any(jax.nn.one_hot(best, n_group, dtype=jnp.bool_),
+                       axis=1)                                 # [T, G]
+        return jnp.where(keep[:, :, None], grouped,
+                         -jnp.inf).reshape(t, e)
 
 
 def dropless_experts(y, idx, gates, w_gate, w_up, w_down, first=0):
@@ -383,11 +406,12 @@ def routing_counts(idx, first, count, valid=None):
 
 
 @functools.lru_cache(maxsize=32)
-def _dropless_fn(top_k, scale, norm, first):
+def _dropless_fn(top_k, scale, norm, first, groups=(1, 1)):
     def f(y, w_router, bias, w_gate, w_up, w_down):
         shape = y.shape
         y = y.reshape(-1, shape[-1])
-        idx, gates = dropless_route(y, w_router, bias, top_k, scale, norm)
+        idx, gates = dropless_route(y, w_router, bias, top_k, scale, norm,
+                                    *groups)
         return dropless_experts(y, idx, gates, w_gate, w_up, w_down,
                                 first).reshape(shape)
     return mark_stable(f)
@@ -425,10 +449,17 @@ class DroplessMoE(Layer):
 
     def __init__(self, d_model, d_expert, num_experts, top_k, *,
                  routed_scaling_factor=1.0, norm_topk_prob=True,
-                 shared_width=0, experts_held=None):
+                 shared_width=0, experts_held=None, n_group=1,
+                 topk_group=1):
         super().__init__()
         from ..nn import Linear
         first, count = experts_held or (0, num_experts)
+        if num_experts % n_group or not 1 <= topk_group <= n_group \
+                or top_k > topk_group * num_experts // n_group:
+            raise ValueError(
+                f"n_group={n_group}, topk_group={topk_group}: "
+                f"{num_experts} experts in whole groups, and {top_k} "
+                "chosen among the kept groups' experts")
         if not (0 <= first and count >= 1
                 and first + count <= num_experts):
             raise ValueError(
@@ -440,6 +471,7 @@ class DroplessMoE(Layer):
         self.first, self.count = int(first), int(count)
         self.scale = float(routed_scaling_factor)
         self.norm_topk_prob = bool(norm_topk_prob)
+        self.groups = (int(n_group), int(topk_group))
         self.router = Linear(d_model, num_experts, bias_attr=False)
         self.e_score_correction_bias = self.create_parameter(
             (num_experts,), default_initializer=I.Constant(0.0))
@@ -459,7 +491,8 @@ class DroplessMoE(Layer):
 
     def forward(self, y):
         out = apply(_dropless_fn(self.top_k, self.scale,
-                                 self.norm_topk_prob, self.first),
+                                 self.norm_topk_prob, self.first,
+                                 self.groups),
                     y, *self._operands(), name="dropless_moe")
         if self.shared_experts is not None:
             out = out + self.shared_experts(y)
@@ -474,6 +507,6 @@ class DroplessMoE(Layer):
         idx, _ = dropless_route(
             ya.reshape(-1, ya.shape[-1]), self.router.weight._data,
             self.e_score_correction_bias._data, self.top_k, self.scale,
-            self.norm_topk_prob)
+            self.norm_topk_prob, *self.groups)
         return self.forward(y), routing_counts(idx, self.first,
                                                self.count, valid)

@@ -10,6 +10,7 @@ from .llama import (LlamaConfig, LlamaForCausalLM,  # noqa: F401
 from .latent_moe import (LatentMoEConfig,  # noqa: F401
                          LatentMoEForCausalLM)
 from .sambay import SambaYConfig, SambaYForCausalLM  # noqa: F401
+from .kda_moe import KdaMoEConfig, KdaMoEForCausalLM  # noqa: F401
 from .t5 import (T5Config, T5ForConditionalGeneration,  # noqa: F401
                  T5Model)
 from .whisper import (WhisperConfig, WhisperModel,  # noqa: F401

@@ -8,7 +8,8 @@ their ``config.json`` (``kv_lora_rank``, ``q_lora_rank``,
 
 Per token, pre-norm residual, RMSNorm, no biases:
 
-- **Latent attention.** ``c_q = RMSNorm(y W_qa)``, ``q = c_q W_qb`` split
+- **Latent attention.** ``c_q = RMSNorm(y W_qa)``, ``q = c_q W_qb`` (or
+  ``q = y W_q`` where ``q_lora_rank`` is null: a full-rank query) split
   per head into ``q_nope | q_rope``; ``[c | k_r] = y W_kva``,
   ``c_kv = RMSNorm(c)``, ``k_rope = RoPE(k_r)`` (one for all heads, RoPE
   over interleaved pairs ``(2i, 2i+1)``); ``[k_nope_h | v_h] = c_kv W_kvb``.
@@ -20,7 +21,8 @@ Per token, pre-norm residual, RMSNorm, no biases:
   against ``c_kv``, ``sum p c_kv`` through ``W_UV_h``), a re-association
   of the same sums.
 - **Leading dense layers** (``first_k_dense_replace``): SwiGLU.
-- **Expert layers**: :class:`~paddle_tpu.incubate.moe.DroplessMoE`.
+- **Expert layers**: :class:`~paddle_tpu.incubate.moe.DroplessMoE`
+  (group-limited routing where ``n_group`` > 1).
 - The multi-token-prediction module (``num_nextn_predict_layers``) is not
   instantiated: it is a training loss or a self-draft (ROADMAP R5).
 
@@ -55,7 +57,7 @@ class LatentMoEConfig:
     moe_intermediate_size: int = 768
     num_hidden_layers: int = 40
     num_attention_heads: int = 32
-    q_lora_rank: int = 1536
+    q_lora_rank: int | None = 1536       # None: a full-rank query
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -89,16 +91,12 @@ class LatentMoEConfig:
             unbuilt.append(f"scoring_func={self.scoring_func!r}")
         if self.topk_method != "noaux_tc":
             unbuilt.append(f"topk_method={self.topk_method!r}")
-        if self.n_group != 1 or self.topk_group != 1:
-            unbuilt.append("group-limited routing (n_group/topk_group > 1)")
         if self.rope_scaling:
             unbuilt.append("rope_scaling")
         if not self.rope_interleave:
             unbuilt.append("rope_interleave=False")
         if self.tie_word_embeddings:
             unbuilt.append("tie_word_embeddings")
-        if not self.q_lora_rank:
-            unbuilt.append("q_lora_rank=None (a full-rank query)")
         if self.moe_layer_freq != 1:
             unbuilt.append(f"moe_layer_freq={self.moe_layer_freq}")
         if unbuilt:
@@ -204,14 +202,18 @@ def expanded_attention(q, kv, ln_w, w_kvb, positions, cfg):
 
 
 def absorbed_paged_attention(q, kv, ln_w, w_kvb, positions, pool, slots,
-                             pt_tok, cl_tok, cfg):
-    """The absorbed form over a latent page pool, one row a packed token:
-    q [T, nh*(nope+rope)], kv [T, rank+rope], positions [T] (absolute),
-    pool [NP, PS, rank+rope], slots [T] (flat slot each token's entry is
-    written to), pt_tok [T, P] / cl_tok [T] (each token's page-table row
-    and the keys it may see, itself included). Returns ([T, nh*v], pool).
-    The pool's entries are read as stored; the two contractions over the
-    context accumulate in float32."""
+                             regions, cfg):
+    """The absorbed form over a latent page pool: q [T, nh*(nope+rope)],
+    kv [T, rank+rope], positions [T] (absolute), pool [NP, PS,
+    rank+rope], slots [T] (flat slot each row's entry is written to).
+    ``regions``: ``(rows, pt, qoff, cl, s)`` a slice of the packed rows
+    that is ``N`` lanes of ``s`` rows each, with each lane's page-table
+    row ``pt [N, P]``, its first row's position ``qoff [N]`` and the keys
+    it may see ``cl [N]``, itself included. A lane's padded table is
+    gathered once for its ``s`` rows; one row a lane (a table row a
+    token) gathers it once a token. Returns ([T, nh*v], pool). The pool's
+    entries are read as stored; the two contractions over the context
+    accumulate in float32."""
     r = cfg.kv_lora_rank
     npg, ps, width = pool.shape
     entry = _latent_entry(kv, ln_w, positions, cfg)
@@ -224,17 +226,28 @@ def absorbed_paged_attention(q, kv, ln_w, w_kvb, positions, pool, slots,
                        preferred_element_type=jnp.float32)
     qq = jnp.concatenate([q_abs, q_rope.astype(jnp.float32)],
                          -1).astype(pool.dtype)            # [T, nh, width]
-    ctx = pool[pt_tok].reshape(pt_tok.shape[0], -1, width)  # [T, S, width]
-    sc = jnp.einsum("thc,tsc->ths", qq, ctx,
-                    preferred_element_type=jnp.float32)
-    sc = sc / jnp.sqrt(jnp.float32(cfg.qk_nope_head_dim
-                                   + cfg.qk_rope_head_dim))
-    kpos = jnp.arange(ctx.shape[1], dtype=jnp.int32)
-    ok = (kpos[None, :] <= positions[:, None]) \
-        & (kpos[None, :] < cl_tok[:, None])
-    p = jax.nn.softmax(jnp.where(ok[:, None, :], sc, -jnp.inf), -1)
-    o_lat = jnp.einsum("ths,tsc->thc", p.astype(pool.dtype), ctx[..., :r],
+    nh = qq.shape[1]
+    scale = jnp.sqrt(jnp.float32(cfg.qk_nope_head_dim
+                                 + cfg.qk_rope_head_dim))
+    parts = []
+    for rows, pt, qoff, cl, s in regions:
+        # a lane's s rows x nh heads as one axis: with one row a token
+        # this is the per-token form's own program, operation for
+        # operation
+        n, t = pt.shape[0], pt.shape[1] * ps
+        ctx = pool[pt].reshape(n, t, width)                # [N, S, width]
+        sc = jnp.einsum("nmc,ntc->nmt",
+                        qq[rows].reshape(n, s * nh, width), ctx,
+                        preferred_element_type=jnp.float32) / scale
+        qpos = qoff[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
+        kpos = jnp.arange(t, dtype=jnp.int32)[None, None]
+        ok = (kpos <= qpos[:, :, None]) & (kpos < cl[:, None, None])
+        sc = jnp.where(ok[:, :, None], sc.reshape(n, s, nh, t), -jnp.inf)
+        p = jax.nn.softmax(sc.reshape(n, s * nh, t), -1)
+        o = jnp.einsum("nmt,ntc->nmc", p.astype(pool.dtype), ctx[..., :r],
                        preferred_element_type=jnp.float32)
+        parts.append(o.reshape(n * s, nh, r))
+    o_lat = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
     out = jnp.einsum("thc,chd->thd", o_lat.astype(w_uv.dtype), w_uv,
                      preferred_element_type=jnp.float32)
     return out.reshape(out.shape[0], -1).astype(q.dtype), pool
@@ -263,9 +276,13 @@ class LatentAttention(Layer):
         self.cfg = cfg
         h, nh = cfg.hidden_size, cfg.num_attention_heads
         qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-        self.q_a_proj = Linear(h, cfg.q_lora_rank, bias_attr=False)
-        self.q_a_layernorm = RMSNorm(cfg.q_lora_rank, cfg.rms_norm_eps)
-        self.q_b_proj = Linear(cfg.q_lora_rank, nh * qk, bias_attr=False)
+        if cfg.q_lora_rank:
+            self.q_a_proj = Linear(h, cfg.q_lora_rank, bias_attr=False)
+            self.q_a_layernorm = RMSNorm(cfg.q_lora_rank, cfg.rms_norm_eps)
+            self.q_b_proj = Linear(cfg.q_lora_rank, nh * qk,
+                                   bias_attr=False)
+        else:
+            self.q_proj = Linear(h, nh * qk, bias_attr=False)
         self.kv_a_proj_with_mqa = Linear(h, cfg.latent_dim, bias_attr=False)
         self.kv_a_layernorm = RMSNorm(cfg.kv_lora_rank, cfg.rms_norm_eps)
         self.kv_b_proj = Linear(
@@ -274,7 +291,8 @@ class LatentAttention(Layer):
         self.o_proj = Linear(nh * cfg.v_head_dim, h, bias_attr=False)
 
     def _q_kv(self, y):
-        q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(y)))
+        q = (self.q_b_proj(self.q_a_layernorm(self.q_a_proj(y)))
+             if self.cfg.q_lora_rank else self.q_proj(y))
         return q, self.kv_a_proj_with_mqa(y)
 
     def forward(self, y, position_ids=None):
@@ -289,16 +307,16 @@ class LatentAttention(Layer):
                     position_ids.detach(), name="latent_attention")
         return self.o_proj(out)
 
-    def paged_forward(self, y, positions, pool, slots, pt_tok, cl_tok):
+    def paged_forward(self, y, positions, pool, slots, regions):
         """y [B, S, H] (normed) with B*S packed tokens in row-major
-        order; the other operands are per token (see
-        :func:`absorbed_paged_attention`). Returns (Tensor [B,S,H], pool)."""
+        order; the other operands as :func:`absorbed_paged_attention`
+        takes them. Returns (Tensor [B,S,H], pool)."""
         b, s = y.shape[0], y.shape[1]
         q, kv = self._q_kv(y)
         out, pool = absorbed_paged_attention(
             q._data.reshape(b * s, -1), kv._data.reshape(b * s, -1),
             self.kv_a_layernorm.weight._data, self.kv_b_proj.weight._data,
-            positions.reshape(-1), pool, slots, pt_tok, cl_tok, self.cfg)
+            positions.reshape(-1), pool, slots, regions, self.cfg)
         return self.o_proj(Tensor(out.reshape(b, s, -1))), pool
 
 
@@ -320,7 +338,8 @@ class LatentMoEDecoderLayer(Layer):
                 norm_topk_prob=cfg.norm_topk_prob,
                 shared_width=cfg.n_shared_experts
                 * cfg.moe_intermediate_size,
-                experts_held=cfg.experts_held)
+                experts_held=cfg.experts_held, n_group=cfg.n_group,
+                topk_group=cfg.topk_group)
         else:
             self.mlp = SwiGLU(cfg.hidden_size, cfg.intermediate_size)
 
@@ -343,9 +362,12 @@ class LatentMoEDecoderLayer(Layer):
         (:meth:`DroplessMoE.forward_counted`, over the rows that are
         not padding)."""
         pt_tok, cl_tok, valid = step.per_token
+        # a region of one row a token: each gathers its lane's table
+        regions = ((slice(None), pt_tok, step.positions.reshape(-1),
+                    cl_tok, 1),)
         a, step.pools[self.layer_idx] = self.self_attn.paged_forward(
             self.input_layernorm(x), step.positions,
-            step.pools[self.layer_idx], step.slots, pt_tok, cl_tok)
+            step.pools[self.layer_idx], step.slots, regions)
         h = x + a
         y = self.post_attention_layernorm(h)
         if self.is_moe and step.stats is not None:

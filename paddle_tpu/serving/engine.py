@@ -1585,7 +1585,12 @@ class ServingEngine:
         c, m = self.cache, self.metrics
         tables = tables_gathered(self._ragged_lanes, tcap, k1)
         lanes = int(live.sum())
-        nw, ns = len(c.window_layers), len(c.state_layers)
+        nw = len(c.window_layers)
+        # a state layer's first array says its recurrence: a matrix a
+        # head ([H, d, d]) the delta rule's, a vector a channel the
+        # selective scan's
+        nd = sum(len(c.layout[i].state[0][1]) == 3 for i in c.state_layers)
+        ns = len(c.state_layers) - nd
         held = int(np.sum(b["wpt"][live] != SCRATCH_PAGE)) if nw else 0
         m.attn_pages_gathered.inc(
             tables * (self._pool_readers * self.max_pages_per_seq
@@ -1596,7 +1601,13 @@ class ServingEngine:
         m.ssm_layer_steps.inc(ns)
         m.ssm_lane_scans.inc(ns * lanes)
         m.ssm_rows_scanned.inc(ns * int(b["ql"].sum()))
-        m.ssm_state_resets.inc(int(starts))
+        m.ssm_state_resets.inc(int(starts) if ns else 0)
+        m.delta_layer_steps.inc(nd)
+        m.delta_lane_steps.inc(nd * lanes)
+        # the chunk's rows go through the chunkwise form, the decode
+        # rows through the one-row recurrence (ops/delta_rule.py)
+        if tcap > self._ragged_tok_small:
+            m.delta_rows_chunked.inc(nd * int(b["ql"][-1]))
 
     # -- KV page migration (disaggregated serving, round 14) ---------------
     def export_request(self, req_id, skip_pages=0):

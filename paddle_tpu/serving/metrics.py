@@ -283,6 +283,14 @@ class ServingMetrics:
         self.ssm_rows_scanned = Counter()     # ... x live packed rows
         self.ssm_state_resets = Counter()     # lanes started from a
         #                                       zero state (position 0)
+        # delta-rule (KDA) layers, counted alike: the ssm_* counters
+        # count selective-scan layers only
+        self.delta_layer_steps = Counter()    # delta layers x steps
+        self.delta_lane_steps = Counter()     # ... x live lanes: a
+        #                                       matrix state read and
+        #                                       written
+        self.delta_rows_chunked = Counter()   # ... x the chunk's live
+        #                                       rows (the chunkwise form)
         self.window_pages_held = Counter()    # window pages held, summed
         #                                       over live lanes, a window
         #                                       layer a step

@@ -152,7 +152,10 @@ def test_a_request_served_alone_gets_the_same_stream(built, served):
                            atol=1e-5)
 
 
-def test_absorbed_attention_equals_expanded(built):
+@pytest.mark.parametrize("gather", ["a_token", "a_lane"])
+def test_absorbed_attention_equals_expanded(built, gather):
+    """The same sums whether each row gathers its lane's table (JoyAI's
+    layers) or a lane's rows share one gather (Ling's MLA layer)."""
     model, _ = built
     at = model.layers[1].self_attn
     cfg = model.cfg
@@ -162,10 +165,17 @@ def test_absorbed_attention_equals_expanded(built):
     # the same 24 tokens through a pool of 8 pages of 4, written in order
     pool = jnp.zeros((8, 4, cfg.latent_dim), F32)
     slots = jnp.arange(24, dtype=jnp.int32) + 4          # pages 1..6
-    pt = jnp.broadcast_to(jnp.arange(1, 9, dtype=jnp.int32) % 8, (24, 8))
+    table = jnp.arange(1, 9, dtype=jnp.int32) % 8
     pos = jnp.arange(24, dtype=jnp.int32)
+    if gather == "a_token":
+        regions = ((slice(None), jnp.broadcast_to(table, (24, 8)), pos,
+                    pos + 1, 1),)
+    else:     # one lane of 16 rows, then a lane of the 8 behind
+        regions = ((slice(0, 16), table[None], pos[:1], pos[16:17], 16),
+                   (slice(16, 24), table[None], pos[16:17], jnp.array([24]),
+                    8))
     got, pool = at.paged_forward(P.to_tensor(y), pos[None], pool, slots,
-                                 pt, pos + 1)
+                                 regions)
     assert np.abs(np.asarray(got._data)[0] - want).max() < 2e-6
     assert float(jnp.abs(pool[0]).max()) == 0.0          # scratch untouched
     assert float(jnp.abs(pool[1:7]).min()) > 0.0
@@ -302,8 +312,12 @@ def test_page_shipping_is_refused_by_name(served):
 
 
 def test_a_config_whose_equations_are_not_written_is_refused():
-    with pytest.raises(NotImplementedError, match="n_group"):
-        LatentMoEConfig.tiny(n_group=8, topk_group=4)
+    # group-limited routing is built (PR 35): a grouping that does not
+    # divide the experts, or keeps fewer than top-k of them, is refused
+    with pytest.raises(ValueError, match="n_group"):
+        DroplessMoE(128, 64, 16, 4, n_group=3, topk_group=1)
+    with pytest.raises(ValueError, match="n_group"):
+        DroplessMoE(128, 64, 16, 8, n_group=8, topk_group=2)
     with pytest.raises(NotImplementedError, match="scoring_func"):
         LatentMoEConfig.tiny(scoring_func="softmax")
     with pytest.raises(ValueError, match="experts_held"):
