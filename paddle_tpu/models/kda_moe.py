@@ -42,6 +42,7 @@ again from token 0. What is not built beside a lane state refuses by name
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from dataclasses import dataclass
 
@@ -228,6 +229,8 @@ def group_rms(x, w, groups, eps):
 # -- mixers -----------------------------------------------------------------
 
 class KimiDeltaAttention(Layer):
+    _by_slot = (None, None)
+
     def __init__(self, cfg: KdaMoEConfig):
         super().__init__()
         self.cfg = cfg
@@ -257,10 +260,23 @@ class KimiDeltaAttention(Layer):
     def _w(self, lin):
         return lin.weight._data
 
+    @contextlib.contextmanager
+    def by_slot(self, slots, fresh):
+        """While open, :meth:`run`'s ``state`` is every slot's, in and
+        out: lane ``n`` reads ``state[slots[n]]`` (nought where
+        ``fresh[n]``) and its state is written back there
+        (``delta_rule``'s ``slots`` / ``fresh``)."""
+        self._by_slot = (slots, fresh)
+        try:
+            yield
+        finally:
+            self._by_slot = (None, None)
+
     def run(self, u, tail, state, live=None, n_live=None):
         """u [N, S, H] (normed, an array) from the lanes' ``tail`` [N,
         K-1, 3 H d] and ``state`` [N, heads, d, d] float32 -> (out [N, S,
-        H], new tail, new state)."""
+        H], new tail, new state); every slot's state under
+        :meth:`by_slot`."""
         cfg = self.cfg
         nh, d = cfg.num_attention_heads, cfg.head_dim
         hd = nh * d
@@ -279,7 +295,8 @@ class KimiDeltaAttention(Layer):
              + self.dt_bias._data.astype(F32)).reshape(heads)
         g = cfg.kda_lower_bound * jax.nn.sigmoid(
             jnp.exp(self.A_log._data.astype(F32))[:, None] * f)
-        o, state = delta_rule(q, k, v, g, beta, state, d ** -0.5, live)
+        o, state = delta_rule(q, k, v, g, beta, state, d ** -0.5, live,
+                              *self._by_slot)
         o = group_rms(o.reshape(heads[:-2] + (hd,)), self.o_norm._data,
                       cfg.group_norm_size, cfg.rms_norm_eps)
         gate = jax.nn.sigmoid(jnp.matmul(u, self._w(self.g_proj))
@@ -377,13 +394,13 @@ class KdaMoEDecoderLayer(Layer):
             for rows, lanes, n, s in step.regions():
                 slot = step.lane_slot[lanes]
                 ql, fresh = step.ql[lanes], step.qoff[lanes] == 0
-                # a lane whose first row starts a sequence: nought
-                s0 = jnp.where(fresh[:, None, None, None], 0, state[slot])
+                # a lane whose first row starts a sequence: nought (the
+                # delta rule reads and writes its states by slot)
                 t0 = jnp.where(fresh[:, None, None], 0, tail[slot])
                 live = jnp.arange(s, dtype=jnp.int32)[None] < ql[:, None]
-                out, t1, s1 = self.self_attn.run(
-                    u[rows].reshape(n, s, -1), t0, s0, live, ql)
-                state = state.at[slot].set(s1)
+                with self.self_attn.by_slot(slot, fresh):
+                    out, t1, state = self.self_attn.run(
+                        u[rows].reshape(n, s, -1), t0, state, live, ql)
                 tail = tail.at[slot].set(t1)
                 outs.append(out.reshape(n * s, -1))
         step.states[i] = (state, tail)

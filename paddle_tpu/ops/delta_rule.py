@@ -15,7 +15,8 @@ the state after each lane's last *live* row: a row that is not live
 (padding behind a lane's rows) is given ``g = 0`` and ``beta = 0``, which
 leaves the state as it was, and its ``o`` is not to be read.
 
-Two routes, chosen from the static shapes (:func:`delta_rule`):
+Two routes in plain ``jax.lax`` / ``jnp``, chosen from the static shapes
+(:func:`delta_rule`; a kernel is the third, below):
 
 - :func:`delta_step`, one row a lane (the decode rows): the recurrence
   itself, elementwise products and sums in float32 over each lane's
@@ -36,23 +37,57 @@ Two routes, chosen from the static shapes (:func:`delta_rule`):
 
 Everything is computed in float32 (the products at ``HIGHEST``
 precision, so the state is never rounded to bfloat16 on the way
-through); ``o`` comes back in float32. Plain ``jax.lax`` / ``jnp``: a
-kernel, when one is written, is a third route chosen here from the same
-shapes. What the benchmark's ``kda_scan_roofline`` counts is this
-contract's work whatever computes it (``benchmark/harness/
-flops_kda_moe.py``).
+through); ``o`` comes back in float32. What the benchmark's
+``kda_scan_roofline`` counts is this contract's work whatever computes
+it (``benchmark/harness/flops_kda_moe.py``).
+
+Lanes may instead name their states by slot: given ``slots`` (and
+``fresh``), ``state0`` is the whole array of lane states, lane ``n``
+reads ``state0[slots[n]]`` (nought where ``fresh[n]``) and its state is
+written back there. The two routes above gather and scatter around
+themselves; a third, the kernel ``ops/pallas/_delta_kernel.py``, reads
+and writes each slot in place. It is taken where a lane brings one row,
+on a TPU, at a head width that tiles (a multiple of 128) and a float32
+state; anywhere else :func:`delta_step` runs. :func:`dispatch_stats`
+counts the routes as they are traced.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["delta_rule", "delta_step", "delta_chunk", "SUB", "GATE_FLOOR"]
+__all__ = ["delta_rule", "delta_step", "delta_chunk", "dispatch_stats",
+           "reset_dispatch_stats", "SUB", "GATE_FLOOR"]
 
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
 SUB = 16            # rows a sub-chunk: 16 x GATE_FLOOR = -80 > -88
 GATE_FLOOR = -5.0   # the least log-decay a row may carry (kda_lower_bound)
+
+_DISPATCH = {"kernel": 0, "step": 0, "chunk": 0}
+# Tests set this True to run the kernel in interpret mode off the TPU.
+_FORCE_INTERPRET = False
+
+
+def dispatch_stats():
+    """Counted at TRACE time: calls that took the kernel, the one-row
+    recurrence and the chunkwise form."""
+    return dict(_DISPATCH)
+
+
+def reset_dispatch_stats():
+    for key in _DISPATCH:
+        _DISPATCH[key] = 0
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _kernel_route(q, state0, slots) -> bool:
+    return (slots is not None and q.shape[1] == 1
+            and q.shape[-1] % 128 == 0 and state0.dtype == F32
+            and (_FORCE_INTERPRET or _on_tpu()))
 
 
 def _dead_rows_keep(g, beta, live):
@@ -146,13 +181,35 @@ def delta_chunk(q, k, v, g, beta, state0, scale, live=None):
         return scale * o.reshape(n, c * SUB, h, dv)[:, :s], state
 
 
-def delta_rule(q, k, v, g, beta, state0, scale, live=None):
-    """The route for the shapes: :func:`delta_step` where a lane brings
-    one row, :func:`delta_chunk` otherwise. Shapes as
-    :func:`delta_chunk`'s; returns ``(o [N, S, H, dv] float32, state)``."""
+def delta_rule(q, k, v, g, beta, state0, scale, live=None, slots=None,
+               fresh=None):
+    """The route for the shapes: the kernel or :func:`delta_step` where
+    a lane brings one row, :func:`delta_chunk` otherwise. Shapes as
+    :func:`delta_chunk`'s; returns ``(o [N, S, H, dv] float32, state)``.
+    With ``slots [N]`` int32 and ``fresh [N]`` bool, ``state0`` and the
+    state returned are every slot's, ``[M, H, dk, dv]``."""
+    if _kernel_route(q, state0, slots):
+        from .pallas._delta_kernel import delta_step_slots
+        _DISPATCH["kernel"] += 1
+        lane_live = (jnp.ones(q.shape[0], bool) if live is None
+                     else live[:, 0])
+        state, o = delta_step_slots(
+            q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state0, slots,
+            fresh, lane_live, scale=scale, interpret=_FORCE_INTERPRET)
+        return o[:, None], state
+    whole = state0
+    if slots is not None:
+        # a lane whose first row starts a sequence: nought
+        state0 = jnp.where(fresh[:, None, None, None], 0, whole[slots])
     if q.shape[1] == 1:
+        _DISPATCH["step"] += 1
         gg, bb = _dead_rows_keep(g.astype(F32), beta.astype(F32), live)
         o, state = delta_step(q[:, 0], k[:, 0], v[:, 0], gg[:, 0],
                               bb[:, 0], state0, scale)
-        return o[:, None], state
-    return delta_chunk(q, k, v, g, beta, state0, scale, live)
+        o = o[:, None]
+    else:
+        _DISPATCH["chunk"] += 1
+        o, state = delta_chunk(q, k, v, g, beta, state0, scale, live)
+    if slots is not None:
+        state = whole.at[slots].set(state)
+    return o, state

@@ -8,7 +8,9 @@ Code that asks ``jax.default_backend()`` still sees the CPU here, so the
 cases call the kernels directly, or steer the dispatch from the test
 (``_on_tpu`` patched) — never through an option of the program.
 """
+import json
 import os
+import re
 import sys
 
 import numpy as np
@@ -373,6 +375,87 @@ class TestStepOwnsItsPools:
             *state).compile().memory_analysis().argument_size_in_bytes
         assert held >= sum(a.size * a.dtype.itemsize for a in state) > 0
         assert compiled.memory_analysis().alias_size_in_bytes == held
+
+
+class TestKdaDecodeKernelInTheStep:
+    """The Ling step program (``ServingEngine._step_program``) compiled
+    for the described chip with the TPU route steered (``_on_tpu``
+    patched): tiny widths and four layers (three KDA), but the cell's 64
+    lanes and 32 KDA heads of 128, so that a layer's state (65 slots,
+    136 MB) is one the compiler cannot keep in VMEM, as a deployment's.
+    Each KDA layer's decode lanes are one kernel call that the cell's
+    ``kda_op_match`` names as it stands, its state operand aliased to
+    its first output: no state is copied and no loop gathers one, in
+    either step class. The plain route's decode class is compiled too:
+    its per-lane gather is a ``while`` over those shapes, which is what
+    the kernel takes away."""
+
+    STATE = "f32[65,32,128,128]"
+
+    @pytest.fixture
+    def engine(self):
+        """A fresh engine a test: its step's trace is cached by shapes,
+        and each test steers the route the trace takes."""
+        import paddle_tpu as P
+        from paddle_tpu.models import KdaMoEConfig, KdaMoEForCausalLM
+        from paddle_tpu.serving import ServingEngine
+        P.seed(0)
+        model = KdaMoEForCausalLM(KdaMoEConfig.tiny(
+            head_dim=128, num_attention_heads=32, num_key_value_heads=32))
+        model.eval()
+        return ServingEngine(model, ragged=True, page_size=4, num_pages=32,
+                             max_batch=64, prefill_chunk=8, max_seq_len=64)
+
+    def _compiled(self, eng, one, chunk):
+        from serving_utils import ragged_step_avals
+        from paddle_tpu.ops import delta_rule as dr
+        avals = ragged_step_avals(
+            eng, eng._ragged_tok_mixed if chunk else eng._ragged_tok_small,
+            lambda shape, dt: _sds(tuple(shape), dt, one))
+        dr.reset_dispatch_stats()
+        compiled = eng._step_program().lower(*avals).compile()
+        # the cache state's bytes as the chip holds them (padded tiles)
+        held = jax.jit(lambda *a: a).lower(*jax.tree.leaves(
+            avals[9:12])).compile().memory_analysis().argument_size_in_bytes
+        lines = [ln.strip().removeprefix("ROOT ")
+                 for ln in compiled.as_text().splitlines()]
+        return compiled, held, lines, dr.dispatch_stats()
+
+    def _on_states(self, lines, op):
+        """Instructions ``op`` whose shapes hold a whole layer's states
+        or the decode lanes' gathered ones."""
+        return [ln for ln in lines if re.search(rf"\b{op}\(", ln)
+                and (self.STATE in ln or "f32[64,32,128,128]" in ln)]
+
+    @pytest.mark.parametrize("chunk", [False, True],
+                             ids=["decode_class", "chunk_class"])
+    def test_the_decode_lanes_are_one_kernel_in_place(self, one, engine,
+                                                      chunk, monkeypatch):
+        from paddle_tpu.ops import delta_rule as dr
+        monkeypatch.setattr(dr, "_on_tpu", lambda: True)
+        compiled, held, lines, stats = self._compiled(engine, one, chunk)
+        assert stats == {"kernel": 3, "step": 0, "chunk": 3 * chunk}
+        with open(os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), "benchmark", "traffic",
+                "longchain.json")) as f:
+            match = re.compile(json.load(f)["kda_op_match"])
+        kernels = [ln for ln in lines if ln.startswith("%kda_decode")]
+        assert len(kernels) == 3
+        for ln in kernels:
+            assert ln.split(" = ")[1].startswith("(" + self.STATE)
+            assert match.search(ln)
+            assert "output_to_operand_aliasing={{0}: (8, {})}" in ln
+        for op in ("copy", "copy-start", "while"):
+            assert not self._on_states(lines, op), op
+        assert compiled.memory_analysis().alias_size_in_bytes == held
+
+    def test_the_plain_route_gathers_the_lanes_in_a_loop(self, one, engine,
+                                                         monkeypatch):
+        from paddle_tpu.ops import delta_rule as dr
+        monkeypatch.setattr(dr, "_on_tpu", lambda: False)
+        _, _, lines, stats = self._compiled(engine, one, False)
+        assert stats == {"kernel": 0, "step": 3, "chunk": 0}
+        assert len(self._on_states(lines, "while")) == 3
 
 
 def test_paged_kernel_knob_raises_off_cpu(monkeypatch):

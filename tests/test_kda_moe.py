@@ -33,6 +33,7 @@ from paddle_tpu.incubate import moe as M
 from paddle_tpu.models import KdaMoEConfig, KdaMoEForCausalLM
 from paddle_tpu.models import kda_moe as km
 from paddle_tpu.models.latent_moe import LatentMoEConfig
+from paddle_tpu.ops import delta_rule as dr
 from paddle_tpu.ops.delta_rule import SUB, delta_rule
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.kv_cache import LayerCache
@@ -362,6 +363,27 @@ def test_a_preempted_request_is_recomputed_to_the_same_logits(built):
     assert eng.metrics.preemptions.value > 0
     gap, err = worst_error(w, requests, served)
     assert gap < TOL and err < TOL
+
+
+def test_the_decode_kernel_serves_the_plain_routes_streams(monkeypatch):
+    """At a head width that tiles (2 heads of 128) the decode rows take
+    the kernel, run here in interpret mode, which reads and writes each
+    lane's state by its slot: the same requests as through the plain
+    route give the same tokens, and log-probabilities to rounding."""
+    model, _ = build({**CFG, "head_dim": 128, "num_attention_heads": 2,
+                      "num_key_value_heads": 2})
+    requests = [(prompt(n, seed=3), m) for n, m in
+                [(30, 8), (5, 10), (17, 6), (2, 9)]]
+    dr.reset_dispatch_stats()
+    _, want = serve(model, requests)
+    assert dr.dispatch_stats()["kernel"] == 0
+    monkeypatch.setattr(dr, "_FORCE_INTERPRET", True)
+    _, got = serve(model, requests)
+    assert dr.dispatch_stats()["kernel"] > 0
+    for i in range(len(requests)):
+        assert [t for t, _ in got[i]] == [t for t, _ in want[i]]
+        assert max(abs(a - b) for (_, a), (_, b) in zip(got[i], want[i])) \
+            < 1e-5
 
 
 # -- planted: what the tolerance has to catch ---------------------------------------
