@@ -7,12 +7,12 @@ see SURVEY.md §5.1).
 
 TPU-native: device timeline comes from `jax.profiler` (XPlane → perfetto/
 TensorBoard — the CUPTI-equivalent); host spans from
-jax.profiler.TraceAnnotation + a lightweight in-process event table that
-powers `summary()`.
+jax.profiler.TraceAnnotation + a fixed in-process ring that powers
+`summary()`, chrome export and the window query `span_totals()`.
 """
 from __future__ import annotations
 
-import contextlib
+import itertools
 import json
 import os
 import threading
@@ -24,7 +24,7 @@ import jax
 
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
            "make_scheduler", "export_chrome_tracing",
-           "load_profiler_result", "events_dropped"]
+           "load_profiler_result", "events_dropped", "span_totals"]
 
 
 class ProfilerTarget(Enum):
@@ -62,44 +62,77 @@ def make_scheduler(closed=0, ready=0, record=1, repeat=0, skip_first=0):
     return scheduler
 
 
-# The host event table is shared across threads (the serving engine's
-# concurrent loop threads all emit spans): appends take _events_lock,
-# the nesting stack is THREAD-LOCAL (a span begun on thread A must
-# never be popped by thread B), and each event carries its emitting
-# thread's ident as the chrome `tid` so concurrent timelines render as
-# separate lanes instead of colliding on tid 0.  The table is bounded
-# (PADDLE_TPU_PROFILE_MAX_EVENTS, default 1e6): overflow is counted,
-# not stored — a runaway span loop degrades the profile, never memory.
-_events: list[dict] = []
-_events_lock = threading.Lock()
-_events_dropped = 0
+# The host span store: ONE fixed ring of ``_RING_SIZE`` (2^18) entries,
+# each ``(name, thread ident, start_ns, dur_ns)`` on
+# ``time.perf_counter_ns()`` -- the clock of ``time.perf_counter()``,
+# which the serving engine and its callers stamp with.  A writer takes
+# its slot from ``itertools.count`` and stores one tuple: both steps are
+# single operations under the GIL, so the append path holds no lock and
+# builds no per-span dict, and concurrent writers never share a slot.
+# The ring only overwrites its oldest entries: a long-running server
+# keeps its latest 2^18 spans, never goes blind, never grows.
+#
+# Size: a full ring is ~136 B an entry (the tuple and its two fresh
+# ints; the name is the caller's string, the ident one object a thread)
+# plus 8 B of list slot, about 36 MiB (measured with tracemalloc).  The
+# serving loop writes about 10 spans a step; the longest drain after a
+# benchmark window (150 s at about 27 steps/s, or 60 s at up to about
+# 120 steps/s) writes under 80 k, so a 6 s window's spans outlive it
+# with more than three times that to spare.  An idle front-end loop
+# writes one ``serving.lock_wait`` a poll (about 1 k/s): the ring then
+# holds over four minutes of it.
+_RING_SIZE = 1 << 18
+_MASK = _RING_SIZE - 1
+_ring: list = [None] * _RING_SIZE
+_seq = itertools.count()
+_mark = 0                       # the ring's position at Profiler.start()
 _tls = threading.local()
-
-_MAX_EVENTS_ENV = "PADDLE_TPU_PROFILE_MAX_EVENTS"
-
-
-def _max_events():
-    try:
-        return max(1, int(os.environ.get(_MAX_EVENTS_ENV, "1000000")))
-    except ValueError:
-        return 1000000
+_clock_ns = time.perf_counter_ns
+_TraceAnnotation = jax.profiler.TraceAnnotation
 
 
-def _thread_stack():
-    stack = getattr(_tls, "stack", None)
-    if stack is None:
-        stack = _tls.stack = []
-    return stack
+def _written():
+    """Spans written so far (``itertools.count`` shows its next value
+    only in its repr)."""
+    return int(repr(_seq)[6:-1])
+
+
+def _entries(lo):
+    """The entries written at or after position ``lo`` that the ring
+    still holds, oldest first."""
+    n = _written()
+    got = (_ring[i & _MASK] for i in range(max(lo, n - _RING_SIZE), n))
+    return [e for e in got if e is not None]
 
 
 def events_dropped():
-    """Spans shed by the event-table cap since the last start()."""
-    return _events_dropped
+    """Spans overwritten since the last Profiler.start() (the ring holds
+    the newest ``2^18``)."""
+    return max(0, _written() - _mark - _RING_SIZE)
+
+
+def span_totals(t0, t1, thread=None):
+    """``{name: (count, seconds)}`` of the spans that began in
+    ``[t0, t1)`` -- seconds on ``time.perf_counter`` -- and that the ring
+    still holds; with ``thread`` (an ident, ``threading.get_ident()``),
+    only that thread's.  The window query a scrape or a benchmark reads
+    the engine's step phases with."""
+    lo, hi = int(t0 * 1e9), int(t1 * 1e9)
+    out = {}
+    for name, tid, start, dur in _entries(0):
+        if lo <= start < hi and (thread is None or tid == thread):
+            count, ns = out.get(name, (0, 0))
+            out[name] = (count + 1, ns + dur)
+    return {name: (count, ns / 1e9) for name, (count, ns) in out.items()}
 
 
 class RecordEvent:
-    """Host-side span; nests (per thread); feeds summary() and chrome
-    export.  Safe to begin/end concurrently from several threads."""
+    """Host-side span: one ``jax.profiler.TraceAnnotation`` (the span on
+    the profiler's clock, beside the device's ops) and one ring entry (on
+    ``perf_counter``, for summary(), chrome export and span_totals()).
+    Spans nest by their intervals; any thread may record."""
+
+    __slots__ = ("name", "_t0", "_ann")
 
     def __init__(self, name, event_type=None):
         self.name = name
@@ -107,38 +140,27 @@ class RecordEvent:
         self._ann = None
 
     def begin(self):
-        self._t0 = time.perf_counter_ns()
         try:
-            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann = _TraceAnnotation(self.name)
             self._ann.__enter__()
         except Exception:
             self._ann = None
-        _thread_stack().append(self)
-
-    def end(self):
-        global _events_dropped
-        t1 = time.perf_counter_ns()
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-        stack = _thread_stack()
-        if stack and stack[-1] is self:
-            stack.pop()
-        ev = {"name": self.name, "ts": self._t0 / 1e3,
-              "dur": (t1 - self._t0) / 1e3, "ph": "X",
-              "pid": os.getpid(), "tid": threading.get_ident()}
-        with _events_lock:
-            if len(_events) >= _max_events():
-                _events_dropped += 1
-            else:
-                _events.append(ev)
-
-    def __enter__(self):
-        self.begin()
+        self._t0 = _clock_ns()
         return self
 
-    def __exit__(self, *a):
-        self.end()
-        return False
+    def end(self, *exc):
+        t1 = _clock_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        try:
+            tid = _tls.tid
+        except AttributeError:         # one ident object a thread
+            tid = _tls.tid = threading.get_ident()
+        _ring[next(_seq) & _MASK] = (self.name, tid, self._t0,
+                                     t1 - self._t0)
+
+    # the with-statement's two calls are the same two methods
+    __enter__, __exit__ = begin, end
 
 
 class Profiler:
@@ -160,10 +182,8 @@ class Profiler:
         self._t_last = None
 
     def start(self):
-        global _events_dropped
-        with _events_lock:
-            _events.clear()
-            _events_dropped = 0
+        global _mark
+        _mark = _written()
         self._state = self._scheduler(self._step)
         self._maybe_toggle()
         self._t_last = time.perf_counter()
@@ -211,20 +231,20 @@ class Profiler:
         os.makedirs(dir_name, exist_ok=True)
         path = os.path.join(dir_name,
                             (worker_name or "worker") + ".json")
-        with _events_lock:
-            snapshot = list(_events)
+        pid = os.getpid()
+        events = [{"name": name, "ts": start / 1e3, "dur": dur / 1e3,
+                   "ph": "X", "pid": pid, "tid": tid}
+                  for name, tid, start, dur in _entries(_mark)]
         with open(path, "w") as f:
-            json.dump({"traceEvents": snapshot}, f)
+            json.dump({"traceEvents": events}, f)
         return path
 
     def summary(self, sorted_by=None, op_detail=True, thread_sep=False,
                 time_unit="ms"):
         agg = defaultdict(lambda: [0.0, 0])
-        with _events_lock:
-            snapshot = list(_events)
-        for e in snapshot:
-            agg[e["name"]][0] += e["dur"] / 1e3
-            agg[e["name"]][1] += 1
+        for name, _, _, dur in _entries(_mark):
+            agg[name][0] += dur / 1e6
+            agg[name][1] += 1
         lines = [f"{'Name':<40}{'Calls':>8}{'Total(ms)':>12}{'Avg(ms)':>12}",
                  "-" * 72]
         for name, (total, calls) in sorted(agg.items(),

@@ -107,6 +107,7 @@ import time
 
 import numpy as np
 
+from ..profiler import RecordEvent
 from .chaos import ChaosConfig, ChaosInjector
 from .distill import distill_buffer_from_env
 from .kv_cache import (SCRATCH_PAGE, GeometryMismatch, LayerCache,
@@ -388,6 +389,7 @@ class ServingEngine:
         self._ragged_tok_small = max_batch * (self.spec_k + 1)
         self._ragged_tok_mixed = self._ragged_tok_small + prefill_chunk
         self._program_classes = set()  # static shape keys dispatched
+        self._phase_span = None        # the step's open phase (_phase)
         self.metrics = ServingMetrics()
         # always-on span timeline + flight recorder (round 16): every
         # mutation happens from the thread that drives the engine —
@@ -555,21 +557,37 @@ class ServingEngine:
 
     def step(self):
         """One scheduler iteration. Returns a list of event dicts
-        ({"type": "token"|"finish", "req_id", ...})."""
-        self._maybe_inject_fault()
-        was_training = [m for m in (self.model, self.draft)
-                        if m is not None
-                        and getattr(m, "training", False)]
-        for m in was_training:
-            m.eval()
-        try:
-            return self._step_inner()
-        finally:
+        ({"type": "token"|"finish", "req_id", ...}).
+
+        One ``serving.step`` span a step, tiled by one span a phase
+        (``_phase``): schedule, pack, dispatch, overlap, fetch, emit,
+        upkeep -- never one a lane or a token (docs/SERVING.md "Where a
+        step's host time goes")."""
+        with RecordEvent("serving.step"):
+            self._maybe_inject_fault()
+            was_training = [m for m in (self.model, self.draft)
+                            if m is not None
+                            and getattr(m, "training", False)]
             for m in was_training:
-                m.train()
+                m.eval()
+            try:
+                return self._step_inner()
+            finally:
+                self._phase()
+                for m in was_training:
+                    m.train()
+
+    def _phase(self, name=None):
+        """End the step's open phase span and begin ``name``'s (no name:
+        end it). A phase runs to the next, so the spans tile the step;
+        ``step()`` ends the last one, whatever raised."""
+        if self._phase_span is not None:
+            self._phase_span.end()
+        self._phase_span = RecordEvent(name).begin() if name else None
 
     def _step_inner(self):
         now = self._now()
+        self._phase("serving.schedule")
         out = self.scheduler.schedule(now)
         if self.trace.enabled:
             # composition FIRST, duration at the end: a loop failure
@@ -591,6 +609,7 @@ class ServingEngine:
             self._record_finish(r, events)
         self.sweep_held_deadlines(now)
         self._ragged_step(out, events)
+        self._phase("serving.upkeep")
         if not out.decode and out.prefill is None and not out.expired \
                 and self.scheduler.waiting \
                 and not self.scheduler.live_requests():
@@ -1231,6 +1250,7 @@ class ServingEngine:
         function. The draft-proposal scan stays its own dispatch
         (different model, disposable K/V); draft catchup prefills ride
         ahead of it."""
+        self._phase("serving.pack")
         t0 = self._now()
         k = self.spec_k
         k1 = k + 1
@@ -1454,6 +1474,8 @@ class ServingEngine:
              b["seeds"], b["steps"]),
             {k: b[k] for k in ("lane_slot", "wpt", "wbase", "wslots")}
             if self.cache.mixed else {})
+        # host work the device's step hides: the program is enqueued
+        self._phase("serving.overlap")
         self._logits_row = pf_off if pf_off is not None else 0
         # what the padded tables cost: page-table entries a layer's
         # attention gathered in this step's class (counted where the
@@ -1479,6 +1501,8 @@ class ServingEngine:
                 len(spec_active) + len(plain_active))
         if pf is not None:
             self.metrics.prefill_chunks.inc()
+        # the one phase that waits on the device
+        self._phase("serving.fetch")
         host = self._host_sampling()
         toks = lps = logits = None
         if host:
@@ -1489,6 +1513,7 @@ class ServingEngine:
             self.metrics.fetch_bytes.inc(toks.nbytes + lps.nbytes)
             self.metrics.step_fetches.inc()
         experts_hit = self._record_moe_counts()
+        self._phase("serving.emit")
         # 8. host-side per-lane processing, in event order: verify
         # lanes, plain lanes, then the prefill completion
         accepted = 0
@@ -2055,6 +2080,7 @@ class ServingEngine:
     def _run_ragged_step(self, ids, positions, pt, cl, ql, qoff,
                          slot_map, samp, lane_extras):
         import jax.numpy as jnp
+        self._phase("serving.dispatch")
         self._tp_kernel_guard()
         warrs = [t._data for t in self.model._gen_state_tensors()]
         # what the program is handed and gives back: the pools and, of

@@ -37,6 +37,7 @@ backpressure.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import os
@@ -46,6 +47,7 @@ import time
 
 import numpy as np
 
+from ..profiler import RecordEvent
 from .engine import FaultInjected
 
 _log = logging.getLogger("paddle_tpu.serving")
@@ -244,7 +246,7 @@ class ServingFrontend:
         ValueError for malformed requests."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         n = int(kw.get("n", 1))
-        with self.lock:
+        with self.lock, RecordEvent("serving.admit"):
             if self._state != "ok":
                 raise Unavailable(f"front-end is {self._state}")
             self._check_capacity(prompt, int(max_new_tokens), n,
@@ -587,11 +589,23 @@ class ServingFrontend:
             for r in stream.all_ids():
                 self._streams.pop(r, None)
 
+    @contextlib.contextmanager
+    def _loop_lock(self):
+        """The lock, held by the loop thread: its wait to acquire it is
+        the ``serving.lock_wait`` span (a submitter's ``serving.admit``,
+        a scrape or a cancel holding it meanwhile)."""
+        with RecordEvent("serving.lock_wait"):
+            self.lock.acquire()
+        try:
+            yield
+        finally:
+            self.lock.release()
+
     def _loop(self):
         eng = self.engine
         try:
             while not self._stop.is_set():
-                with self.lock:
+                with self._loop_lock():
                     if self._state == "failed":
                         return  # externally killed (fail()); stop cold
                     idle = eng.scheduler.all_done()

@@ -1,11 +1,14 @@
-"""paddle_tpu.profiler — first test coverage for the profiler package
-(ISSUE 9 satellite): scheduler windows, RecordEvent nesting + chrome
-export roundtrip, summary() aggregation, timer-only step stats, and the
-round-16 thread-safety fix (per-thread tid, locked/capped event table).
-CPU-mesh only; nothing here touches a device beyond jax.profiler's
-host-side TraceAnnotation."""
+"""paddle_tpu.profiler — scheduler windows, RecordEvent nesting + chrome
+export roundtrip, summary() aggregation, timer-only step stats, the
+per-thread tids, and the span store: a fixed ring written without a
+lock, its overwrite count since Profiler.start(), and the window query
+span_totals(). CPU-mesh only; nothing here touches a device beyond
+jax.profiler's host-side TraceAnnotation."""
 import json
+import os
+import sys
 import threading
+import time
 
 import pytest
 
@@ -75,20 +78,21 @@ class TestRecordEvent:
         ev.begin()
         ev.end()
         p.stop()
-        with prof._events_lock:
-            names = [e["name"] for e in prof._events]
+        names = [e[0] for e in prof._entries(prof._mark)]
         assert "manual" in names
 
     def test_multithread_tids_do_not_collide(self, tmp_path):
-        """Round-16 fix: concurrent threads used to interleave on a
-        shared module-global stack and all export as tid 0; now each
-        thread's spans carry its own ident and the table append is
-        locked (no lost updates)."""
+        """Concurrent threads used to interleave on a shared
+        module-global stack and all export as tid 0; each thread's spans
+        carry its own ident, and the ring's lock-free append loses none
+        (each writer takes a slot of its own)."""
         p = Profiler(timer_only=True)
         p.start()
-        n_threads, n_spans = 4, 50
+        # more writers than cores, switched as often as the interpreter
+        # allows: a lost or shared slot would lose a span
+        n_threads, n_spans = (os.cpu_count() or 4) + 2, 200
         # OS thread idents are recycled once a thread exits — hold all
-        # four alive until every span landed so the lanes are distinct
+        # alive until every span landed so the lanes are distinct
         done = threading.Barrier(n_threads)
 
         def work(i):
@@ -99,14 +103,20 @@ class TestRecordEvent:
 
         threads = [threading.Thread(target=work, args=(i,))
                    for i in range(n_threads)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(60)
+        switch = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+        finally:
+            sys.setswitchinterval(switch)
+        assert not any(t.is_alive() for t in threads)
         p.stop()
         path = p.export_chrome_tracing(str(tmp_path), "mt")
         evs = load_profiler_result(path)["traceEvents"]
-        assert len(evs) == n_threads * n_spans  # locked: none lost
+        assert len(evs) == n_threads * n_spans  # none lost
         tids = {}
         for e in evs:
             tids.setdefault(e["name"], set()).add(e["tid"])
@@ -116,22 +126,96 @@ class TestRecordEvent:
         assert len(set().union(*tids.values())) == n_threads
 
     def test_event_table_cap_counts_overflow(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_PROFILE_MAX_EVENTS", "10")
+        """The ring keeps the NEWEST spans: past its size the oldest are
+        overwritten and counted since start()'s mark, never the newest
+        shed (a long-running server stays able to see)."""
+        _small_ring(monkeypatch, 16)
         p = Profiler(timer_only=True)
         p.start()
-        for _ in range(25):
-            with RecordEvent("burst"):
+        for i in range(25):
+            with RecordEvent(f"burst{i}"):
                 pass
         p.stop()
-        with prof._events_lock:
-            n = len(prof._events)
-        assert n == 10
-        assert prof.events_dropped() == 15
-        # start() resets the drop counter with the table
+        held = [e[0] for e in prof._entries(prof._mark)]
+        assert held == [f"burst{i}" for i in range(9, 25)]
+        assert prof.events_dropped() == 9
+        # start() moves the mark: nothing overwritten since
         p2 = Profiler(timer_only=True)
         p2.start()
         p2.stop()
         assert prof.events_dropped() == 0
+        assert prof._entries(prof._mark) == []
+
+    def test_ring_entries_are_tuples_not_dicts(self):
+        with RecordEvent("shape"):
+            pass
+        entry = prof._ring[(prof._written() - 1) & prof._MASK]
+        name, tid, start, dur = entry
+        assert type(entry) is tuple and name == "shape"
+        assert tid == threading.get_ident()
+        assert isinstance(start, int) and 0 <= dur < 10 ** 9
+
+
+def _small_ring(monkeypatch, size):
+    monkeypatch.setattr(prof, "_RING_SIZE", size)
+    monkeypatch.setattr(prof, "_MASK", size - 1)
+    monkeypatch.setattr(prof, "_ring", [None] * size)
+
+
+def _write(name, start_s, dur_s, tid=None):
+    """One ring entry by hand, on the perf_counter clock in seconds."""
+    prof._ring[next(prof._seq) & prof._MASK] = (
+        name, threading.get_ident() if tid is None else tid,
+        int(start_s * 1e9), int(dur_s * 1e9))
+
+
+class TestSpanTotals:
+    # far from any span a real clock reading stamps
+    BASE = time.perf_counter() + 1e6
+
+    def test_window_counts_the_spans_that_began_in_it(self):
+        b = self.BASE
+        _write("w.a", b - 0.5, 1.0)        # straddles t0: began before
+        _write("w.a", b + 1.0, 0.25)
+        _write("w.a", b + 1.5, 0.5)
+        _write("w.b", b + 1.9, 0.5)        # straddles t1: began inside
+        _write("w.b", b + 2.0, 0.1)        # begins at t1: outside
+        got = prof.span_totals(b, b + 2.0)
+        assert set(got) == {"w.a", "w.b"}
+        assert got["w.a"][0] == 2
+        assert got["w.a"][1] == pytest.approx(0.75)
+        assert got["w.b"][0] == 1
+        assert got["w.b"][1] == pytest.approx(0.5)
+        assert prof.span_totals(b - 10.0, b - 5.0) == {}
+
+    def test_thread_filter(self):
+        b = self.BASE + 100.0
+        _write("t.x", b + 0.1, 0.01, tid=11)
+        _write("t.x", b + 0.2, 0.02, tid=22)
+        _write("t.x", b + 0.3, 0.04, tid=22)
+        assert prof.span_totals(b, b + 1.0)["t.x"][0] == 3
+        one = prof.span_totals(b, b + 1.0, thread=22)["t.x"]
+        assert one[0] == 2 and one[1] == pytest.approx(0.06)
+        assert prof.span_totals(b, b + 1.0, thread=33) == {}
+
+    def test_recorded_spans_on_the_perf_counter_clock(self):
+        t0 = time.perf_counter()
+        with RecordEvent("clock.outer"):
+            with RecordEvent("clock.inner"):
+                time.sleep(0.01)
+        t1 = time.perf_counter()
+        got = prof.span_totals(t0, t1, thread=threading.get_ident())
+        assert got["clock.outer"][0] == got["clock.inner"][0] == 1
+        assert 0.01 <= got["clock.inner"][1] <= got["clock.outer"][1] \
+            <= t1 - t0
+
+    def test_overwritten_spans_leave_the_window(self, monkeypatch):
+        _small_ring(monkeypatch, 8)
+        b = self.BASE + 200.0
+        for i in range(12):
+            _write("o.x", b + i, 0.5)
+        got = prof.span_totals(b, b + 100.0)
+        assert got["o.x"][0] == 8          # the newest eight
 
 
 class TestProfilerSummary:
