@@ -379,6 +379,8 @@ class ServingEngine:
                 "removed in PR 29; the token-packed step is the engine")
         self._ragged_fn = None        # one jit fn; <= 2 token shapes
         self._ragged_bufs = {}        # per-capacity persistent buffers
+        self._ragged_tokens = None    # the last step's token output
+        self._state_lists = {}        # "target"/"draft": its weights' Tensors
         # static geometry: L lanes always (max_batch decode/verify + 1
         # prefill), k1 = speculative_k + 1 rows a decode/verify lane;
         # token capacity is one of TWO shapes — a step with no prefill
@@ -737,12 +739,13 @@ class ServingEngine:
         quiesce.
 
         Weights are ARGUMENTS of every compiled step (``warrs`` /
-        ``dwarrs`` are rebuilt from ``_gen_state_tensors`` per
-        dispatch), so swapping ``t._data`` here takes effect on the
-        very next step with NO recompile and no jit-cache
-        invalidation.  All-or-nothing: the full payload is validated
-        (count + shape per tensor) before the first write, so a torn
-        push (``distill_push_torn``) leaves the old version serving.
+        ``dwarrs`` are read per dispatch from the ``Tensor`` objects
+        that ``_state_tensors`` found once), so swapping ``t._data``
+        here takes effect on the very next step with NO recompile, no
+        jit-cache invalidation and no list to rebuild.  All-or-nothing:
+        the full payload is validated (count + shape per tensor) before
+        the first write, so a torn push (``distill_push_torn``) leaves
+        the old version serving.
 
         Target swaps flush the prefix cache — every cached page holds
         K/V computed under the OLD weights — which also detaches and
@@ -759,7 +762,7 @@ class ServingEngine:
         model = self.model if which == "target" else self.draft
         if model is None:
             raise ValueError("engine has no draft model")
-        tensors = model._gen_state_tensors()
+        tensors = self._state_tensors(which)
         if len(arrays) != len(tensors):
             self.metrics.weight_swap_rejects.inc()
             raise ValueError(
@@ -1137,7 +1140,6 @@ class ServingEngine:
         rectangular chunk, for the K/V it writes (no head, no
         sampler: nothing of a catchup is ever emitted)."""
         import jax
-        import jax.numpy as jnp
         if self._draft_fn is None:
             # no TP context: the draft program never pins TP layouts —
             # a distinct draft's weights are replicated (byte-identical
@@ -1151,12 +1153,12 @@ class ServingEngine:
                                   self._draft_core, self._draft_window),
                 donate_argnums=(6, 7))
         dc = self._draft_cache
-        dwarrs = [t._data for t in self.draft._gen_state_tensors()]
+        dwarrs = [t._data for t in self._state_tensors("draft")]
         k_ops, v_ops = dc.program_operands()
+        # the host arrays go to the call as they stand (its C++
+        # dispatch transfers them); each is made anew for this call
         k_pages, v_pages = self._draft_fn(
-            dwarrs, jnp.asarray(ids), jnp.asarray(positions),
-            jnp.asarray(pt), jnp.asarray(cl), jnp.asarray(slot_map),
-            k_ops, v_ops)
+            dwarrs, ids, positions, pt, cl, slot_map, k_ops, v_ops)
         dc.store_operands(k_pages, v_pages)
         self._count_dispatch(("draft_step", ids.shape))
 
@@ -1168,21 +1170,17 @@ class ServingEngine:
         trick — it lands d_k's K/V so a full-accept round leaves no
         hole; the token itself is discarded)."""
         import jax
-        import jax.numpy as jnp
         if self._propose_fn is None:
             self._propose_fn = jax.jit(
                 functools.partial(_spec_draft_pure, self.draft,
                                   self._draft_core, self._draft_window),
                 static_argnums=(0,), donate_argnums=(8, 9))
         dc = self._draft_cache
-        dwarrs = [t._data for t in self.draft._gen_state_tensors()]
+        dwarrs = [t._data for t in self._state_tensors("draft")]
         k_ops, v_ops = dc.program_operands()
         props, k_pages, v_pages = self._propose_fn(
-            bool(sample_capable), dwarrs, jnp.asarray(ids0),
-            jnp.asarray(pos0), jnp.asarray(pt), jnp.asarray(cl0),
-            jnp.asarray(slot_mat),
-            tuple(jnp.asarray(a) for a in samp),
-            k_ops, v_ops)
+            bool(sample_capable), dwarrs, ids0, pos0, pt, cl0, slot_mat,
+            samp, k_ops, v_ops)
         dc.store_operands(k_pages, v_pages)
         self._count_dispatch(("draft_propose", slot_mat.shape,
                               bool(sample_capable)))
@@ -1367,6 +1365,11 @@ class ServingEngine:
                 b["wbase"] = np.zeros(nl, np.int32)
                 b["wslots"] = np.zeros(tcap, np.int32)
         else:
+            # the last step's call was handed these arrays as they
+            # stand: wait for its program where no fetch did
+            if self._ragged_tokens is not None:
+                self._ragged_tokens.block_until_ready()
+                self._ragged_tokens = None
             # full padding reset: lane composition changes every step
             # (padded lanes keep context 1 / scratch pages / neutral
             # sampling — the NaN-free contract)
@@ -2077,25 +2080,44 @@ class ServingEngine:
                 donate_argnums=(9, 10, 11))     # k_pages, v_pages, extra
         return self._ragged_fn
 
+    def _state_tensors(self, which):
+        """The ``Tensor`` objects whose arrays a program of ``which``
+        ("target" or "draft") is handed as its weights, found once a
+        model (``weight_list_builds``): ``_gen_state_tensors`` walks
+        the module tree and names every parameter, and its answer does
+        not change. The objects are kept, not their arrays:
+        ``set_weights`` writes ``t._data`` in place, so the next
+        dispatch reads the new arrays."""
+        tensors = self._state_lists.get(which)
+        if tensors is None:
+            model = self.model if which == "target" else self.draft
+            tensors = self._state_lists[which] = \
+                model._gen_state_tensors()
+            self.metrics.weight_list_builds.inc()
+        return tensors
+
     def _run_ragged_step(self, ids, positions, pt, cl, ql, qoff,
                          slot_map, samp, lane_extras):
-        import jax.numpy as jnp
         self._phase("serving.dispatch")
         self._tp_kernel_guard()
-        warrs = [t._data for t in self.model._gen_state_tensors()]
+        warrs = [t._data for t in self._state_tensors("target")]
         # what the program is handed and gives back: the pools and, of
         # a mixed cache, the window pools and lane states (``extra``:
         # empty where every layer owns a full pool); the lanes' slots
         # and window tables are the step's own host arrays
         state = (*self.cache.program_operands(),
                  self.cache.extra_operands())
+        # the packed host arrays go to the call as they stand: its C++
+        # dispatch transfers them, and may read them until the program
+        # has run (on a CPU it reads them in place). They are
+        # ``_ragged_bufs``, which the next step's pack writes over only
+        # after this step's fetch has waited for the device, or after
+        # ``_ragged_tokens`` has (a step that raised before its fetch)
         tok, lp, logits, k_pages, v_pages, moe_counts, extra = \
             self._step_program()(
-                warrs, jnp.asarray(ids), jnp.asarray(positions),
-                jnp.asarray(pt), jnp.asarray(cl), jnp.asarray(ql),
-                jnp.asarray(qoff), jnp.asarray(slot_map),
-                tuple(jnp.asarray(a) for a in samp), *state,
-                {k: jnp.asarray(a) for k, a in lane_extras.items()})
+                warrs, ids, positions, pt, cl, ql, qoff, slot_map, samp,
+                *state, lane_extras)
+        self._ragged_tokens = tok
         self.cache.store_operands(k_pages, v_pages)
         self.cache.store_extra(extra)
         self.metrics.pool_bytes_donated.set(_bytes_handed_over(state))

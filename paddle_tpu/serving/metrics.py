@@ -205,6 +205,11 @@ class ServingMetrics:
         self.step_dispatches = Counter()      # device dispatches issued
         self.step_fetches = Counter()         # host<-device fetches
         self.step_program_classes = Gauge()   # distinct compiled classes
+        # times the engine walked a model's module tree for the
+        # tensors its programs are handed as weights (once for the
+        # target, once for a draft); 1 - this / step_dispatches is the
+        # share of dispatches that reused the list
+        self.weight_list_builds = Counter()
         # over step_dispatches: the target steps whose batch held a
         # sampling row with a binding top-k or top-p (the sampler's
         # sort ran), counted on the host from the arrays it packs

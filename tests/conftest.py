@@ -10,7 +10,9 @@ reached only by `python chip_smoke.py` through the builder's chip tool.
 import glob
 import importlib.util
 import os
+import shutil
 import signal
+import tempfile
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
@@ -21,6 +23,28 @@ import jax  # noqa: E402
 import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+
+# Many tests build the same small engines and models, and each build
+# compiles its programs anew (a jit a model instance). A persistent
+# compile cache a test process turns the repeats into reads. Where
+# JAX_COMPILATION_CACHE_DIR is set JAX reads it itself; otherwise the
+# cache is a fresh directory of this process alone (xdist's workers are
+# separate processes, and an entry is written non-atomically, so a
+# shared directory could hand a reader half a file), removed when the
+# process's session ends, and keeping every compile: the repeats are
+# small programs too.
+_CACHE_DIR = None
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _CACHE_DIR = tempfile.mkdtemp(prefix="jax-test-cache-")
+    jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+_CACHE_OWNER = os.getpid()
+
+
+def pytest_unconfigure(config):
+    if _CACHE_DIR is not None and os.getpid() == _CACHE_OWNER:
+        shutil.rmtree(_CACHE_DIR, ignore_errors=True)
+
 
 # Oracle deps the transplant-parity suites importorskip on. Under a
 # certification run their absence must FAIL, not silently skip

@@ -482,6 +482,18 @@ class TestEngineE2E:
         want = np.asarray(m.generate(P.to_tensor(prompt[None]),
                                      max_new_tokens=4)._data)[0]
         np.testing.assert_array_equal(res[r2]["tokens"], want)
+        # a swap through the blessed site between two steps: the next
+        # step runs on the new weights, from the list found once
+        other = tiny_model(seed=7)
+        eng.set_weights("target", [np.asarray(t._data) for t in
+                                   other._gen_state_tensors()], 2)
+        r3 = eng.add_request(prompt, max_new_tokens=4)
+        res = eng.run()
+        want = np.asarray(other.generate(P.to_tensor(prompt[None]),
+                                         max_new_tokens=4)._data)[0]
+        np.testing.assert_array_equal(res[r3]["tokens"], want)
+        assert not np.array_equal(res[r3]["tokens"], res[r2]["tokens"])
+        assert eng.metrics.weight_list_builds.value == 1
 
     def test_run_failure_releases_pages_and_engine_is_reusable(self):
         """Regression (round 9): a run() that raises used to leave the
