@@ -11,6 +11,7 @@ from .latent_moe import (LatentMoEConfig,  # noqa: F401
                          LatentMoEForCausalLM)
 from .sambay import SambaYConfig, SambaYForCausalLM  # noqa: F401
 from .kda_moe import KdaMoEConfig, KdaMoEForCausalLM  # noqa: F401
+from .minicpm_sala import MiniCPMSalaConfig, MiniCPMSalaForCausalLM  # noqa: F401,E501
 from .t5 import (T5Config, T5ForConditionalGeneration,  # noqa: F401
                  T5Model)
 from .whisper import (WhisperConfig, WhisperModel,  # noqa: F401

@@ -381,7 +381,7 @@ class KdaMoEDecoderLayer(Layer):
         y = self.post_attention_layernorm(h)
         if self.is_moe and step.stats is not None:
             out, counts = self.mlp.forward_counted(y, step.valid)
-            step.stats.append(counts)
+            step.stats.append(("moe", counts))
         else:
             out = self.mlp(y)
         return h + out

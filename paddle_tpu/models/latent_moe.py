@@ -372,7 +372,7 @@ class LatentMoEDecoderLayer(Layer):
         y = self.post_attention_layernorm(h)
         if self.is_moe and step.stats is not None:
             out, counts = self.mlp.forward_counted(y, valid)
-            step.stats.append(counts)
+            step.stats.append(("moe", counts))
         else:
             out = self.mlp(y)
         return h + out

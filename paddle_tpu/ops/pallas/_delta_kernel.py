@@ -28,6 +28,11 @@ row a head); those a head reads along the state's rows (``exp(g)``,
 ``k``, ``q``) are transposed in VMEM, a block's heads at once, so that
 a head's column broadcasts across the state's lanes.
 
+With ``rule="lightning"`` the same kernel runs Lightning Attention's
+recurrence (``ops/lightning_attn.py``): no correction, ``S = exp(g) * S
++ k v^T``, ``o = scale * S^T q``, the decay a head broadcast along its
+key channels; the call is then named ``lightning_decode``.
+
 The output order is fixed: ``(state, o)``. A trace names the call by its
 first output, ``f32[slots, H, d, d]``, the same shape as every other
 operation on the state (``benchmark/traffic/longchain.json``'s
@@ -62,7 +67,7 @@ def heads_per_block(h: int, d: int) -> int:
 
 
 def _kernel(slot_ref, fresh_ref, live_ref, q_ref, k_ref, g_ref, v_ref,
-            b_ref, s_ref, s_out, o_ref, *, hb, scale):
+            b_ref, s_ref, s_out, o_ref, *, hb, scale, delta):
     n = pl.program_id(1)
 
     @pl.when(live_ref[n] != 0)
@@ -73,8 +78,10 @@ def _kernel(slot_ref, fresh_ref, live_ref, q_ref, k_ref, g_ref, v_ref,
         for j in range(hb):
             kj, qj = k[:, j:j + 1], q[:, j:j + 1]
             s = jnp.where(fresh, 0.0, s_ref[0, j]) * decay[:, j:j + 1]
-            kv = jnp.sum(s * kj, axis=0, keepdims=True)         # s'^T k
-            u = b_ref[0, j:j + 1] * (v_ref[0, j:j + 1] - kv)
+            u = v_ref[0, j:j + 1]
+            if delta:
+                kv = jnp.sum(s * kj, axis=0, keepdims=True)     # s'^T k
+                u = b_ref[0, j:j + 1] * (u - kv)
             s = s + kj * u
             s_out[0, j] = s
             o_ref[0, j:j + 1] = scale * jnp.sum(s * qj, axis=0,
@@ -87,12 +94,12 @@ def _kernel(slot_ref, fresh_ref, live_ref, q_ref, k_ref, g_ref, v_ref,
 
 
 def delta_step_slots(q, k, v, g, beta, state, slot, fresh, live, *, scale,
-                     interpret=False):
+                     interpret=False, rule="delta"):
     """q, k, g, v ``[N, H, d]``, beta ``[N, H]``, state ``[M, H, d, d]``
     float32 (every slot), slot ``[N]`` int32, fresh / live ``[N]`` bool.
     Returns ``(state [M, H, d, d], o [N, H, d] float32)``: lane ``n``'s
     state after its row in ``state[slot[n]]``, every other slot as it
-    was."""
+    was. ``rule="lightning"``: no correction (``beta`` is not read)."""
     n, h, d = q.shape
     hb = heads_per_block(h, d)
     beta = jnp.broadcast_to(beta.astype(F32)[..., None], q.shape)
@@ -102,7 +109,8 @@ def delta_step_slots(q, k, v, g, beta, state, slot, fresh, live, *, scale,
     state_block = pl.BlockSpec((1, hb, d, d), at_slot)
     block_bytes = hb * d * d * 4
     return pl.pallas_call(
-        functools.partial(_kernel, hb=hb, scale=scale),
+        functools.partial(_kernel, hb=hb, scale=scale,
+                          delta=rule == "delta"),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(h // hb, n),
             in_specs=[vec_block] * 5 + [state_block],
@@ -114,9 +122,10 @@ def delta_step_slots(q, k, v, g, beta, state, slot, fresh, live, *, scale,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=4 * block_bytes + 16 * 2 ** 20),
         cost_estimate=pl.CostEstimate(
-            flops=8 * n * h * d * d, transcendentals=n * h * d,
+            flops=(8 if rule == "delta" else 5) * n * h * d * d,
+            transcendentals=n * h * d,
             bytes_accessed=2 * n * block_bytes * (h // hb)),
         interpret=interpret,
-        name="kda_decode",
+        name="kda_decode" if rule == "delta" else f"{rule}_decode",
     )(slot.astype(jnp.int32), fresh.astype(jnp.int32),
       live.astype(jnp.int32), *vecs, state)

@@ -55,7 +55,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["paged_attention", "paged_attention_ref",
-           "ragged_paged_attention", "quantize_q8", "PagedStep"]
+           "ragged_paged_attention", "selected_paged_attention",
+           "block_masked_paged_attention", "quantize_q8", "PagedStep"]
 
 
 def quantize_q8(x):
@@ -197,7 +198,12 @@ class PagedStep:
     [1, T]``, ``slots [T]`` / ``wslots [T]`` the flat slot its entry is
     written to in a full / window pool, and, built on first use,
     ``per_token`` = ``(pt_tok [T, P], cl_tok [T], valid [T])``.
-    ``stats``, a list, receives sparse-expert layers' routing counts."""
+    ``stats``, a list, receives a layer's device counts as ``(kind,
+    int32 array)``: sparse-expert routing ("moe", ``incubate/moe.py::
+    routing_counts``), block selection ("sparse").
+    ``indexer_pools[i]``: the compressed keys of layer ``i``'s block
+    selection, addressed by the full pools' page ids
+    (``LayerCache(indexer=...)``)."""
 
     def __init__(self, positions, slots, pt, cl, ql, qoff, k1, pools,
                  extra=None, stats=None):
@@ -210,6 +216,7 @@ class PagedStep:
         extra = extra or {}
         self.window_pools = dict(extra.get("window_pools", {}))
         self.states = dict(extra.get("states", {}))
+        self.indexer_pools = dict(extra.get("indexer_pools", {}))
         self.lane_slot = extra.get("lane_slot")
         self.wslots = extra.get("wslots")
         self.wpt, self.wbase = extra.get("wpt"), extra.get("wbase")
@@ -304,6 +311,99 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table,
     # softmax, which the select discards.)
     return jnp.where(_rows_live(query_lens, t, k1)[:, None, None],
                      out, 0)
+
+
+def selected_paged_attention(q, pool, table, taken, q_pos, *, scale):
+    """Attention of one query row a lane over the pages its own KV head
+    selected, through :func:`paged_attention` with a table a (lane, KV
+    head).
+
+    q [B, H, D]; pool [NP, PS, 2 KV D], a token's entry its keys then its
+    values, a KV head after another; table [B, KV, J] int32 the pages a
+    head selected in sequence order, ``taken`` [B, KV, J] bool the
+    entries used (a prefix); q_pos [B] int32 the row's position, which
+    lies in the last page taken. So a head's table is a lane whose first
+    ``(m - 1) * PS + q_pos % PS + 1`` keys the row sees, ``m`` the pages
+    taken. A head's pages are gathered as whole entries and its columns
+    sliced after, then attended as a pool of their own: :func:`paged_
+    attention` reads keys and values as pools ``[NP, PS, KV, D]``, and on
+    the TPU a view of this pool's column ranges as such is a pool-sized
+    relayout copy (1.1 GB a layer and step at the MiniCPM-SALA cell's
+    size). Returns [B, H, D] in q.dtype."""
+    b, nh, d = q.shape
+    _, ps, width = pool.shape
+    nkv = width // (2 * d)
+    j = table.shape[-1]
+    cl = ((jnp.sum(taken, -1) - 1) * ps
+          + (q_pos % ps)[:, None] + 1).astype(jnp.int32)        # [B, KV]
+    lanes = jnp.arange(b * j, dtype=jnp.int32).reshape(b, j)
+    qg = q.reshape(b, 1, nkv, nh // nkv, d)
+    outs = []
+    for h in range(nkv):
+        got = pool[jnp.where(taken[:, h], table[:, h], 0)]   # [B, J, PS, W]
+        k, v = (got[..., c * d:(c + 1) * d].reshape(b * j, ps, 1, d)
+                for c in (h, nkv + h))
+        outs.append(paged_attention(qg[:, :, h], k, v, lanes, cl[:, h],
+                                    cl[:, h] - 1, scale=scale)[:, 0])
+    return jnp.concatenate(outs, 1)
+
+
+# pages a step of block_masked_paged_attention's stream reads
+_MASKED_PAGES_A_STEP = 8
+
+
+def block_masked_paged_attention(q, pool, table, block_mask, q_pos,
+                                 context_len, *, scale):
+    """Attention of one lane's rows (a prefill chunk) over its pages, each
+    (row, KV head) seeing only the blocks ``block_mask`` gives it: the
+    lane's table streamed ``_MASKED_PAGES_A_STEP`` pages at a time with
+    an online softmax, up to the pages ``context_len`` covers.
+
+    q [S, H, D]; pool [NP, PS, 2 KV D] as :func:`selected_paged_attention`
+    reads it; table [P] int32 the lane's pages in order; block_mask [S,
+    KV, P] bool; q_pos [S] int32; context_len a scalar, the keys the lane
+    holds. Returns [S, H, D] in q.dtype; a row that sees no key gives
+    nought."""
+    s, nh, d = q.shape
+    _, ps, full = pool.shape
+    nkv = full // (2 * d)
+    g, p, n = nh // nkv, table.shape[0], _MASKED_PAGES_A_STEP
+    pad = (-p) % n
+    table = jnp.pad(table, (0, pad))
+    block_mask = jnp.pad(block_mask, ((0, 0), (0, 0), (0, pad)))
+    qg = q.reshape(s, nkv, g, d).astype(jnp.float32) * scale
+    width = n * ps
+
+    def body(i, carry):
+        m, l, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(table, i * n, n)
+        kv = pool[pages].reshape(width, 2, nkv, d)
+        sc = jnp.einsum("skgd,tkd->kgst", qg,
+                        kv[:, 0].astype(jnp.float32))
+        kpos = i * width + jnp.arange(width, dtype=jnp.int32)
+        sel = jnp.repeat(jax.lax.dynamic_slice_in_dim(
+            block_mask, i * n, n, axis=2), ps, axis=2)      # [S, KV, w]
+        ok = (jnp.transpose(sel, (1, 0, 2))
+              & (kpos[None, None] <= q_pos[None, :, None])
+              & (kpos < context_len)[None, None])           # [KV, S, w]
+        sc = jnp.where(ok[:, None], sc, -jnp.inf)
+        m2 = jnp.maximum(m, sc.max(-1))
+        alive = jnp.isfinite(m2)
+        alpha = jnp.where(alive, jnp.exp(m - m2), 1.0)
+        pexp = jnp.where(alive[..., None], jnp.exp(sc - m2[..., None]), 0.0)
+        l2 = l * alpha + pexp.sum(-1)
+        acc2 = acc * alpha[..., None] + jnp.einsum(
+            "kgst,tkd->kgsd", pexp, kv[:, 1].astype(jnp.float32))
+        return m2, l2, acc2
+
+    m0 = jnp.full((nkv, g, s), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((nkv, g, s), jnp.float32)
+    a0 = jnp.zeros((nkv, g, s, d), jnp.float32)
+    steps = (context_len + width - 1) // width
+    _, l, acc = jax.lax.fori_loop(0, steps, body, (m0, l0, a0))
+    out = acc / jnp.maximum(l, 1e-20)[..., None]             # [KV,g,S,D]
+    return jnp.transpose(out, (2, 0, 1, 3)).reshape(s, nh, d).astype(
+        q.dtype)
 
 
 def paged_attention_ref(q, k_pages, v_pages, page_table, context_lens,

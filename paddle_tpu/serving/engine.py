@@ -405,9 +405,9 @@ class ServingEngine:
         self.metrics.cache_bytes_per_token.set(self.cache.bytes_per_token)
         self.metrics.state_bytes_per_lane.set(
             self.cache.state_bytes_per_lane)
-        # routing counts of sparse-expert layers, summed on the device
-        # by the ragged step and fetched with its tokens
-        self._moe_counts_dev = None
+        # the layers' device counts (expert routing, block selection),
+        # summed by the ragged step and fetched with its tokens
+        self._step_counts_dev = None
         self.eos = eos_token_id
         self.window = getattr(cfg, "sliding_window", None) or None
         self._draft_fn = None         # draft catchup prefill (trunk only)
@@ -1515,7 +1515,7 @@ class ServingEngine:
             lps = np.asarray(lp_d, np.float32)
             self.metrics.fetch_bytes.inc(toks.nbytes + lps.nbytes)
             self.metrics.step_fetches.inc()
-        experts_hit = self._record_moe_counts()
+        experts_hit = self._record_step_counts()
         self._phase("serving.emit")
         # 8. host-side per-lane processing, in event order: verify
         # lanes, plain lanes, then the prefill completion
@@ -1615,10 +1615,12 @@ class ServingEngine:
         lanes = int(live.sum())
         nw = len(c.window_layers)
         # a state layer's first array says its recurrence: a matrix a
-        # head ([H, d, d]) the delta rule's, a vector a channel the
-        # selective scan's
-        nd = sum(len(c.layout[i].state[0][1]) == 3 for i in c.state_layers)
-        ns = len(c.state_layers) - nd
+        # head ([H, d, d]) the delta rule's, or Lightning Attention's
+        # where it is named so, a vector a channel the selective scan's
+        first = [c.layout[i].state[0] for i in c.state_layers]
+        nl = sum(name == "lightning" for name, _, _ in first)
+        nd = sum(len(shape) == 3 for _, shape, _ in first) - nl
+        ns = len(c.state_layers) - nd - nl
         held = int(np.sum(b["wpt"][live] != SCRATCH_PAGE)) if nw else 0
         m.attn_pages_gathered.inc(
             tables * (self._pool_readers * self.max_pages_per_seq
@@ -1632,10 +1634,47 @@ class ServingEngine:
         m.ssm_state_resets.inc(int(starts) if ns else 0)
         m.delta_layer_steps.inc(nd)
         m.delta_lane_steps.inc(nd * lanes)
+        m.lightning_layer_steps.inc(nl)
+        m.lightning_lane_steps.inc(nl * lanes)
         # the chunk's rows go through the chunkwise form, the decode
-        # rows through the one-row recurrence (ops/delta_rule.py)
+        # rows through the one-row recurrence (ops/delta_rule.py,
+        # ops/lightning_attn.py)
         if tcap > self._ragged_tok_small:
             m.delta_rows_chunked.inc(nd * int(b["ql"][-1]))
+            m.lightning_rows_chunked.inc(nl * int(b["ql"][-1]))
+        if c.indexer_layers:
+            self._count_block_selection(b, live, tcap)
+
+    def _count_block_selection(self, b, live, tcap):
+        """The block-selecting layers' host counts of a step, from the
+        rows it packed (what the layers selected comes from the device,
+        ``record_step_counts``): compressed keys scored (the layer's
+        ``kernels_scored``), pages streamed, a decode row's table (its
+        selection, padded to ``blocks_a_row``) and the chunk's every
+        page, and the compressed keys read, every one its lane holds."""
+        c, m = self.cache, self.metrics
+        layers = [self._core.layers[i] for i in c.indexer_layers]
+        ps = c.page_size
+        entries, nkv, _ = c.layout[c.indexer_layers[0]].indexer
+        rows = np.nonzero(live[:-1])[0]
+        pos = [b["qoff"][rows].astype(np.int64)]
+        chunk = tcap > self._ragged_tok_small and b["ql"][-1] > 0
+        if chunk:
+            pos.append(b["qoff"][-1] + np.arange(int(b["ql"][-1])))
+        pos = np.concatenate(pos)
+        n = len(layers)
+        held = pos // ps + 1
+        m.sparse_kernels_scored.inc(
+            n * int(layers[0].kernels_scored(pos).sum()))
+        n_dec = len(rows)
+        streamed = n_dec * min(layers[0].cfg.blocks_a_row,
+                               self.max_pages_per_seq)
+        pages = int(held[:n_dec].sum())
+        if chunk:
+            streamed += int(held[-1])
+            pages += int(held[-1])
+        m.sparse_pages_streamed.inc(n * nkv * streamed)
+        m.sparse_kernels_read.inc(n * nkv * entries * pages)
 
     # -- KV page migration (disaggregated serving, round 14) ---------------
     def export_request(self, req_id, skip_pages=0):
@@ -1972,18 +2011,20 @@ class ServingEngine:
         self.metrics.step_fetches.inc()
         return out
 
-    def _record_moe_counts(self):
-        """The last ragged step's routing counts into the metrics; the
-        16 bytes come with the step's tokens (the program has finished
-        by then: no further wait). Returns the experts hit, None for a
-        model without sparse experts."""
-        if self._moe_counts_dev is None:
+    def _record_step_counts(self):
+        """The last ragged step's device counts (routing, block
+        selection) into the metrics; their few bytes come with the
+        step's tokens (the program has finished by then: no further
+        wait). Returns the experts hit, None for a model without sparse
+        experts."""
+        if not self._step_counts_dev:
             return None
-        counts = np.asarray(self._moe_counts_dev)
-        self._moe_counts_dev = None
-        self.metrics.fetch_bytes.inc(counts.nbytes)
-        self.metrics.record_moe_counts(counts)
-        return int(counts[1])
+        counts = {k: np.asarray(v) for k, v in
+                  self._step_counts_dev.items()}
+        self._step_counts_dev = None
+        self.metrics.fetch_bytes.inc(sum(v.nbytes for v in counts.values()))
+        self.metrics.record_step_counts(counts)
+        return int(counts["moe"][1]) if "moe" in counts else None
 
     def _sync_prefix_metrics(self):
         c, m = self.cache, self.metrics
@@ -2113,7 +2154,7 @@ class ServingEngine:
         # ``_ragged_bufs``, which the next step's pack writes over only
         # after this step's fetch has waited for the device, or after
         # ``_ragged_tokens`` has (a step that raised before its fetch)
-        tok, lp, logits, k_pages, v_pages, moe_counts, extra = \
+        tok, lp, logits, k_pages, v_pages, counts, extra = \
             self._step_program()(
                 warrs, ids, positions, pt, cl, ql, qoff, slot_map, samp,
                 *state, lane_extras)
@@ -2122,7 +2163,7 @@ class ServingEngine:
         self.cache.store_extra(extra)
         self.metrics.pool_bytes_donated.set(_bytes_handed_over(state))
         self._logits_dev = logits          # [T, V], fetched on demand
-        self._moe_counts_dev = moe_counts  # fetched with the tokens
+        self._step_counts_dev = counts     # fetched with the tokens
         self._count_dispatch(("ragged", ids.shape[1]))
         self._count_sort(samp)
         return tok, lp
@@ -2205,8 +2246,10 @@ def _paged_forward(core, window, ids, positions, pt, cl, slot_map,
     in layer order, one entry a token (``v_pages`` is empty); ``extra`` the window pools, the lane states and the lanes'
     slots and window tables (``PagedKVCache.extra_operands`` and the
     step's buffers), empty where every layer owns a full pool.
-    ``stats``, a list, receives such layers' routing counts. Such a
-    model has no draft form, so its layers see the packed layout only.
+    ``stats``, a list, receives such layers' device counts as ``(kind,
+    int32 array)``: expert routing ("moe"), block selection
+    ("sparse"). Such a model has no draft form, so its layers see the
+    packed layout only.
     LLaMA-shaped layers run the code below (two paged forwards until D1
     gives the block one definition)."""
     from ..core.autograd import no_grad
@@ -2337,6 +2380,7 @@ def _layers_own_forward(core, x, positions, flat_slots, pt, cl, ragged,
     full = [i for i, lc in enumerate(layout) if lc.pool == "full"]
     wins = [i for i, lc in enumerate(layout) if lc.pool == "window"]
     stts = [i for i, lc in enumerate(layout) if lc.state]
+    idxs = [i for i, lc in enumerate(layout) if lc.indexer]
     ql, qoff, k1 = ragged
     if v_pages:
         raise NotImplementedError(
@@ -2347,14 +2391,17 @@ def _layers_own_forward(core, x, positions, flat_slots, pt, cl, ragged,
         pools=dict(zip(full, k_pages)),
         extra=dict(extra,
                    window_pools=dict(zip(wins, extra.get("window", ()))),
-                   states=dict(zip(stts, extra.get("state", ())))),
+                   states=dict(zip(stts, extra.get("state", ()))),
+                   indexer_pools=dict(zip(idxs,
+                                          extra.get("indexer", ())))),
         stats=stats)
     for layer in core.layers:
         x = layer.paged_forward(x, step)
     new_extra = {}
-    if wins or stts:
+    if wins or stts or idxs:
         new_extra = {"window": [step.window_pools[i] for i in wins],
-                     "state": [tuple(step.states[i]) for i in stts]}
+                     "state": [tuple(step.states[i]) for i in stts],
+                     "indexer": [step.indexer_pools[i] for i in idxs]}
     new_k = [step.pools[i] for i in full]
     return core.norm(x)._data, new_k, [], new_extra
 
@@ -2425,9 +2472,13 @@ def _ragged_step_body(model, core, window, tp, ids, positions, pt, cl,
     x, new_k, new_v, new_extra = _paged_forward(
         core, window, ids, positions, pt, cl, slot_map, k_pages, v_pages,
         ragged=(ql, qoff, k1), tp=tp, stats=stats, extra=extra)
-    # sparse-expert layers' routing counts of this step, summed over
-    # layers: int32 [4] (MOE_COUNTS), None for a model without them
-    moe_counts = sum(stats[1:], stats[0]) if stats else None
+    # the layers' device counts of this step by kind, summed over the
+    # layers: sparse-expert routing ("moe", int32 [4]: MOE_COUNTS) and
+    # block selection ("sparse", [3]: SPARSE_COUNTS); empty for a model
+    # without either
+    counts = {}
+    for kind, n in stats:
+        counts[kind] = counts[kind] + n if kind in counts else n
     from .sampling import fused_sample
     do_sample, temperature, top_k, top_p, seeds, steps = samp
     with no_grad():
@@ -2441,7 +2492,7 @@ def _ragged_step_body(model, core, window, tp, ids, positions, pt, cl,
     tokens, logprobs = fused_sample(
         logits, do_sample, temperature, top_k, top_p, seeds, steps,
         sample_capable=True)
-    return tokens, logprobs, logits, new_k, new_v, moe_counts, new_extra
+    return tokens, logprobs, logits, new_k, new_v, counts, new_extra
 
 
 # -- the fused draft-proposal scan (speculative decoding, round 12) --------

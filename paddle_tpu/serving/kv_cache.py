@@ -96,6 +96,12 @@ class LayerCache:
       ``[max_lanes + 1, *shape]``, slot 0 the scratch lane.
     - ``reads``: the index of the layer whose full pool this one
       attends; it owns nothing.
+    - ``indexer`` (beside ``pool="full"``): ``(entries, n_kv_heads,
+      head_dim)``, a pool of ``entries`` vectors a page and KV head
+      addressed by the full pool's page ids: what a layer keeps to
+      select pages with (a block-sparse layer's compressed keys, one a
+      kernel window that starts in the page). Held ``[num_pages,
+      entries, n_kv_heads, head_dim]``, page 0 the scratch page.
     - all defaults: the layer keeps nothing for a sequence.
     """
     pool: str | None = None
@@ -105,6 +111,7 @@ class LayerCache:
     window: int = 0
     state: tuple = ()
     reads: int | None = None
+    indexer: tuple = ()
 
 
 class OutOfPages(RuntimeError):
@@ -303,12 +310,15 @@ class PagedKVCache:
                               if lc.pool == "window"]
         self.state_layers = [i for i, lc in enumerate(self.layout)
                              if lc.state]
+        self.indexer_layers = [i for i, lc in enumerate(self.layout)
+                               if lc.indexer]
         if len(self.full_layers) != n:
             raise ValueError(
                 f"the layout has {len(self.full_layers)} full pools, the "
                 f"cache was built with {n}")
-        self.mixed = bool(self.window_layers or self.state_layers)
-        self.w_pages, self.lane_state = [], []
+        self.mixed = bool(self.window_layers or self.state_layers
+                          or self.indexer_layers)
+        self.w_pages, self.lane_state, self.indexer = [], [], []
         self.window = 0
         self.window_pages_per_lane = 0
         self.state_bytes_per_lane = 0
@@ -323,8 +333,8 @@ class PagedKVCache:
                  "needs the lane state at the boundary")):
             if refused:
                 raise NotImplementedError(
-                    "a cache with window pools or lane state beside its "
-                    f"pages is not built for {what}")
+                    "a cache with window pools, lane state or an indexer "
+                    f"beside its pages is not built for {what}")
         if not max_lanes or not prefill_chunk:
             raise ValueError("a mixed layout is sized from max_lanes and "
                              "prefill_chunk")
@@ -353,6 +363,13 @@ class PagedKVCache:
             self.state_bytes_per_lane += (
                 len(self.window_layers) * self.window_pages_per_lane
                 * ps * whd * self.dtype.itemsize)
+        for i in self.indexer_layers:
+            entries, nkv, hd = self.layout[i].indexer
+            self.indexer.append(jnp.zeros(
+                (self.num_pages, entries, nkv, hd), self.dtype))
+            # the indexer is a cost a page, not a lane
+            self.bytes_total += (self.num_pages * entries * nkv * hd
+                                 * self.dtype.itemsize)
         for i in self.state_layers:
             arrs = []
             for _, shape, dt in self.layout[i].state:
@@ -368,9 +385,9 @@ class PagedKVCache:
     def _refuse_mixed(self, what):
         if self.mixed:
             raise NotImplementedError(
-                f"{what} is not built for a cache with window pools or "
-                "lane state beside its pages: its payload is pages of "
-                "one pool geometry")
+                f"{what} is not built for a cache with window pools, "
+                "lane state or an indexer beside its pages: its payload "
+                "is pages of one pool geometry")
 
     def can_hold_lanes(self, n=1):
         """Whether ``n`` more sequences fit the part of the cache that
@@ -428,17 +445,19 @@ class PagedKVCache:
 
     def extra_operands(self):
         """What a step program takes beside :meth:`program_operands`:
-        the window pools and the lane states, by layer (an empty dict
-        where every layer owns a full pool)."""
+        the window pools, the lane states and the indexer pools, by
+        layer (an empty dict where every layer owns a full pool)."""
         if not self.mixed:
             return {}
         return {"window": list(self.w_pages),
-                "state": list(self.lane_state)}
+                "state": list(self.lane_state),
+                "indexer": list(self.indexer)}
 
     def store_extra(self, new):
         if self.mixed:
             self.w_pages = list(new["window"])
             self.lane_state = [tuple(s) for s in new["state"]]
+            self.indexer = list(new["indexer"])
 
     def attach_tier(self, tier):
         """Bind a :class:`~.kvtier.KVTier` so prefix-cache evictions
@@ -729,7 +748,8 @@ class PagedKVCache:
         import jax
         import jax.numpy as jnp
         arrays = jax.tree.leaves(
-            (self._all_pools(), self.w_pages, self.lane_state))
+            (self._all_pools(), self.w_pages, self.lane_state,
+             self.indexer))
         if not any(a.is_deleted() for a in arrays):
             return None
         if self._tables:
@@ -747,6 +767,7 @@ class PagedKVCache:
         self.w_pages = [fresh(a) for a in self.w_pages]
         self.lane_state = [tuple(fresh(a) for a in s)
                            for s in self.lane_state]
+        self.indexer = [fresh(a) for a in self.indexer]
         lost = len(self._cached)
         self._free.extend(self._cached)
         self._cached = {}

@@ -296,6 +296,25 @@ class ServingMetrics:
         #                                       written
         self.delta_rows_chunked = Counter()   # ... x the chunk's live
         #                                       rows (the chunkwise form)
+        # Lightning Attention layers, counted alike
+        self.lightning_layer_steps = Counter()
+        self.lightning_lane_steps = Counter()
+        self.lightning_rows_chunked = Counter()
+        # block-selecting (sparse) attention layers, counted on the
+        # device from the selection the step made, summed over the
+        # layers and KV heads: blocks a row attended, the blocks its
+        # lane held, and the distinct pages a lane's rows selected (a
+        # decode row's own selection, the union of the chunk's rows')
+        self.sparse_blocks_read = Counter()
+        self.sparse_blocks_live = Counter()
+        self.sparse_pages_selected = Counter()
+        # ... and on the host, from the rows' positions: compressed keys
+        # scored (a row that selects, a layer); pages streamed (a decode
+        # row's selection, the chunk's every page, a KV head and layer)
+        # and indexer entries read
+        self.sparse_kernels_scored = Counter()
+        self.sparse_pages_streamed = Counter()
+        self.sparse_kernels_read = Counter()
         self.window_pages_held = Counter()    # window pages held, summed
         #                                       over live lanes, a window
         #                                       layer a step
@@ -305,10 +324,17 @@ class ServingMetrics:
     # the order incubate/moe.py::routing_counts packs its int32 [4] in
     MOE_COUNTS = ("moe_assignments", "moe_experts_hit",
                   "moe_expert_load_max", "moe_layer_steps")
+    # the order models/minicpm_sala.py::SparseAttention packs its [3] in
+    SPARSE_COUNTS = ("sparse_blocks_read", "sparse_blocks_live",
+                     "sparse_pages_selected")
 
-    def record_moe_counts(self, counts):
-        for name, n in zip(self.MOE_COUNTS, counts):
-            getattr(self, name).inc(int(n))
+    def record_step_counts(self, counts):
+        """A ragged step's device counts, ``{"moe": int32 [4],
+        "sparse": int32 [3]}`` (either may be absent)."""
+        names = {"moe": self.MOE_COUNTS, "sparse": self.SPARSE_COUNTS}
+        for kind, ns in counts.items():
+            for name, n in zip(names[kind], ns):
+                getattr(self, name).inc(int(n))
 
     def export(self):
         return {name: m.export() for name, m in vars(self).items()}

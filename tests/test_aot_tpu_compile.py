@@ -458,6 +458,59 @@ class TestKdaDecodeKernelInTheStep:
         assert len(self._on_states(lines, "while")) == 3
 
 
+class TestSalaStep:
+    """MiniCPM-SALA's serving step at its head widths (32 query heads and
+    2 KV heads of 128, lightning 32 x 128, pages of 64), compiled for the
+    chip: the lightning layers' decode rows are the delta rule's kernel
+    with ``rule="lightning"``, one a layer, in place; and neither the
+    sparse layers' pool nor their indexer is laid out anew (a reshape of
+    the pool to ``[pages, 64, 2, 2, 128]`` cost a pool-sized copy a
+    layer a step)."""
+
+    POOL = "bf16[64,64,512]"
+
+    @pytest.fixture
+    def engine(self):
+        import paddle_tpu as P
+        from paddle_tpu.models import MiniCPMSalaConfig, MiniCPMSalaForCausalLM
+        from paddle_tpu.serving import ServingEngine
+        P.seed(0)
+        mix = ["lightning-attn", "minicpm4", "lightning-attn",
+               "lightning-attn", "minicpm4"]
+        model = MiniCPMSalaForCausalLM(MiniCPMSalaConfig(
+            vocab_size=512, hidden_size=256, intermediate_size=512,
+            num_hidden_layers=4, mixer_types=mix, first_layer=1,
+            dim_model_base=64, dtype="bfloat16"))
+        model.eval()
+        return ServingEngine(model, ragged=True, page_size=64, num_pages=64,
+                             max_batch=4, prefill_chunk=64, max_seq_len=1024)
+
+    @pytest.mark.parametrize("chunk", [False, True],
+                             ids=["decode_class", "chunk_class"])
+    def test_the_lightning_lanes_are_one_kernel_and_no_pool_moves(
+            self, one, engine, chunk, monkeypatch):
+        from serving_utils import ragged_step_avals
+        from paddle_tpu.ops import delta_rule as dr
+        monkeypatch.setattr(dr, "_on_tpu", lambda: True)
+        avals = ragged_step_avals(
+            engine, engine._ragged_tok_mixed if chunk
+            else engine._ragged_tok_small,
+            lambda shape, dt: _sds(tuple(shape), dt, one))
+        dr.reset_dispatch_stats()
+        compiled = engine._step_program().lower(*avals).compile()
+        assert dr.dispatch_stats() == {"kernel": 2, "step": 0,
+                                       "chunk": 2 * chunk}
+        lines = [ln.strip().removeprefix("ROOT ")
+                 for ln in compiled.as_text().splitlines()]
+        kernels = [ln for ln in lines if ln.startswith("%lightning_decode")]
+        assert len(kernels) == 2
+        for ln in kernels:
+            assert "output_to_operand_aliasing={{0}: (8, {})}" in ln
+        # (a pool this small may be staged into fast memory: copy-start)
+        assert not [ln for ln in lines if re.search(r"\bcopy\(", ln)
+                    and (self.POOL in ln or "bf16[64,4,2,128]" in ln)]
+
+
 def test_paged_kernel_knob_raises_off_cpu(monkeypatch):
     """PADDLE_TPU_PAGED_KERNEL=1 would run an interpreted kernel on a
     chip: anywhere but the cpu backend it raises."""
